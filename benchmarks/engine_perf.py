@@ -190,7 +190,7 @@ def structural_sweep_throughput(n_points: int = 8, n_replicas: int = 256,
         res = sw.run()
         cold = time.perf_counter() - t0
         c1 = vectorized.compile_cache_size()
-        compiles = None if c0 is None else c1 - c0
+        compiles = c1 - c0
         t0 = time.perf_counter()
         res = sw.run()
         warm = time.perf_counter() - t0
@@ -346,7 +346,7 @@ def empirical_sweep_throughput(n_points: int = 8, n_replicas: int = 256,
         "failure_distribution": base.failure_distribution,
         "distribution_kwargs": dict(base.distribution_kwargs),
         "n_segments": len(base.distribution_kwargs["rates"]),
-        "sweep_compiles": None if c0 is None else c1 - c0,
+        "sweep_compiles": c1 - c0,
         **out,
     }
 
@@ -408,7 +408,7 @@ def correlated_sweep_throughput(n_points: int = 8, n_replicas: int = 256,
                      "racks_per_pod": base.fault_domains.racks_per_pod,
                      "pod_shock_rate": base.fault_domains.pod_shock_rate},
         "campaign_events": len(base.campaign.events),
-        "sweep_compiles": None if c0 is None else c1 - c0,
+        "sweep_compiles": c1 - c0,
         **out,
     }
 
@@ -455,7 +455,7 @@ def checkpoint_sweep_throughput(n_points: int = 8, n_replicas: int = 256,
     c1 = vectorized.compile_cache_size()
     return {
         "checkpoint_cost": base.checkpoint_cost,
-        "sweep_compiles": None if c0 is None else c1 - c0,
+        "sweep_compiles": c1 - c0,
         **out,
     }
 
@@ -479,7 +479,7 @@ def checkpoint_smoke(n_replicas: int = 24) -> Dict[str, object]:
     c0 = vectorized.compile_cache_size()
     run_replications_batch(grid, n_replicas, engine="ctmc")
     c1 = vectorized.compile_cache_size()
-    compiles = None if c0 is None else c1 - c0
+    compiles = c1 - c0
     res = optimize_checkpoint_interval(
         base.replace(checkpoint_interval=20.0), n_replicas=16,
         n_grid=4, refine_iters=2, engine="ctmc")
@@ -490,10 +490,7 @@ def checkpoint_smoke(n_replicas: int = 24) -> Dict[str, object]:
                          "objective": res.objective,
                          "young_daly": res.young_daly,
                          "n_evals": res.n_evals}}
-    if compiles is None:
-        out["note"] = ("jit cache introspection unavailable on this jax; "
-                       "checkpoint-grid guard skipped")
-    elif compiles != 1:
+    if compiles != 1:
         raise SystemExit(
             f"compile-count regression: traced checkpoint grid compiled "
             f"{compiles} XLA programs, expected exactly 1")
@@ -602,7 +599,7 @@ def multijob_sweep_throughput(n_points: int = 8, n_replicas: int = 256,
         "ctmc_compile_wall_s": compile_s,
         "speedup_x": event_s / ctmc_s,
         "speedup_x_incl_compile": event_s / compile_s,
-        "sweep_compiles": None if c0 is None else c1 - c0,
+        "sweep_compiles": c1 - c0,
         "max_abs_z": max(abs(p["z"]) for p in points),
         "points": points,
     }
@@ -626,13 +623,10 @@ def repair_smoke(n_replicas: int = 24) -> Dict[str, object]:
     c0 = vectorized.compile_cache_size()
     run_replications_batch(grid, n_replicas, engine="ctmc", max_steps=192)
     c1 = vectorized.compile_cache_size()
-    compiles = None if c0 is None else c1 - c0
+    compiles = c1 - c0
     out = {"n_points": len(grid), "n_replicas": n_replicas,
            "compiles": compiles}
-    if compiles is None:
-        out["note"] = ("jit cache introspection unavailable on this jax; "
-                       "repair-grid guard skipped")
-    elif compiles != 1:
+    if compiles != 1:
         raise SystemExit(
             f"compile-count regression: repair-parameter grid compiled "
             f"{compiles} XLA programs, expected exactly 1")
@@ -669,7 +663,7 @@ def bucketed_sweep_throughput(n_replicas: int = 256) -> Dict[str, object]:
                                    bucketed=bucketed)
             walls.append(time.perf_counter() - t0)
         c1 = vectorized.compile_cache_size()
-        compiles = None if c0 is None else c1 - c0
+        compiles = c1 - c0
         return walls, compiles
 
     b_walls, b_compiles = timed(True)
@@ -703,13 +697,10 @@ def bucketing_smoke(n_replicas: int = 24) -> Dict[str, object]:
     run_replications_batch(grid_b, n_replicas - 7, engine="ctmc",
                            max_steps=256)
     c1 = vectorized.compile_cache_size()
-    compiles = None if c0 is None else c1 - c0
+    compiles = c1 - c0
     out = {"sweep_shapes": [[3, n_replicas], [4, n_replicas - 7]],
            "compiles": compiles}
-    if compiles is None:
-        out["note"] = ("jit cache introspection unavailable on this jax; "
-                       "bucketing guard skipped")
-    elif compiles != 1:
+    if compiles != 1:
         raise SystemExit(
             f"bucketing regression: two same-bucket sweeps compiled "
             f"{compiles} XLA programs, expected exactly 1")
@@ -740,15 +731,12 @@ def structural_smoke(n_points: int = 4, n_replicas: int = 32,
     res = sweep.run()
     wall = time.perf_counter() - t0
     c1 = vectorized.compile_cache_size()
-    compiles = None if c0 is None else c1 - c0
+    compiles = c1 - c0
     out = {"n_points": n_points, "n_replicas": n_replicas,
            "wall_s": wall, "compiles": compiles,
            "total_time_means": [p.stats["total_time"].mean
                                 for p in res.points]}
-    if compiles is None:
-        out["note"] = ("jit cache introspection unavailable on this jax; "
-                       "compile-count guard skipped")
-    elif compiles != 1:
+    if compiles != 1:
         raise SystemExit(
             f"compile-count regression: structural {n_points}-point sweep "
             f"compiled {compiles} XLA programs, expected exactly 1 per "
@@ -775,14 +763,11 @@ def multijob_smoke(n_replicas: int = 24) -> Dict[str, object]:
     c0 = vectorized_multijob.compile_cache_size()
     reps = run_multijob_batch(grid, n_replicas, engine="ctmc", base_seed=0)
     c1 = vectorized_multijob.compile_cache_size()
-    compiles = None if c0 is None else c1 - c0
+    compiles = c1 - c0
     out = {"n_points": len(grid), "n_replicas": n_replicas,
            "n_jobs": len(jobs), "compiles": compiles,
            "makespan_means": [r.fleet["makespan"].mean for r in reps]}
-    if compiles is None:
-        out["note"] = ("jit cache introspection unavailable on this jax; "
-                       "multi-job guard skipped")
-    elif compiles != 1:
+    if compiles != 1:
         raise SystemExit(
             f"compile-count regression: mixed-size multi-job capacity "
             f"grid compiled {compiles} XLA programs, expected exactly 1")
@@ -840,11 +825,15 @@ def _sharded_child(n_dev: int, n_points: int = 4,
 
 
 def sharded_weak_scaling(device_counts=(1, 2, 4)) -> Dict[str, object]:
-    """Weak-scaling curve of the replica-sharded CTMC sweep.
+    """Weak-scaling curve of the replica-sharded CTMC sweep — a CPU-only
+    correctness curve, not a speed figure.
 
     Spawns one child interpreter per mesh size with
+    ``JAX_PLATFORMS=cpu`` and
     ``XLA_FLAGS=--xla_force_host_platform_device_count=D`` (the forced
-    host-device recipe of docs/scaling.md) and grows the replica count
+    host-device recipe of docs/scaling.md): the children never ask for
+    an accelerator, which the parent process may already hold.  It
+    grows the replica count
     with the mesh so per-device work stays constant.  Reports per-point
     throughput, ``weak_scaling_efficiency`` (throughput at D devices
     over the 1-device mesh), the sharded-vs-unsharded retention at mesh
@@ -862,6 +851,7 @@ def sharded_weak_scaling(device_counts=(1, 2, 4)) -> Dict[str, object]:
     points = []
     for d in device_counts:
         env = dict(os.environ)
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={d}"
         out = subprocess.run(
             [_sys.executable, os.path.abspath(__file__),
@@ -881,7 +871,7 @@ def sharded_weak_scaling(device_counts=(1, 2, 4)) -> Dict[str, object]:
         "min_weak_scaling_efficiency": min(
             p["weak_scaling_efficiency"] for p in points),
         "mesh1_bitident": points[0]["mesh1_bitident"],
-        "sweep_compiles": max(p["sweep_compiles"] or 0 for p in points),
+        "sweep_compiles": max(p["sweep_compiles"] for p in points),
     }
 
 
@@ -915,6 +905,10 @@ def write_sweep_artifact(sw: Dict[str, object],
 if __name__ == "__main__":   # standalone: sweep benchmarks or CI smoke
     import json
     import sys
+
+    from repro import compile_cache
+
+    compile_cache.enable()
 
     if "--sharded-child" in sys.argv:
         d = int(sys.argv[sys.argv.index("--sharded-child") + 1])

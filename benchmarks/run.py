@@ -32,6 +32,9 @@ def _row(name: str, us_per_call: float, derived: str) -> None:
 
 def main() -> None:
     from benchmarks import engine_perf, paper_tables
+    from repro import compile_cache
+
+    compile_cache.enable()
 
     n_rep = 64 if FAST else 256
 

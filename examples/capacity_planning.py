@@ -31,6 +31,7 @@ compiled grid through the repair-slot lane.
 
 import argparse
 
+from repro import compile_cache
 from repro.core import (MINUTES_PER_DAY, OneWaySweep, Params,
                         repair_shop_occupancy, spare_capacity_bound)
 
@@ -57,6 +58,7 @@ parser.add_argument("--tune", choices=("off", "on"), default="on",
                          "interval via golden-section on the fast path, "
                          "cross-checked against Young/Daly")
 args = parser.parse_args()
+compile_cache.enable()
 
 N_REP = 64 if args.fast else 256
 POOLS = [4112, 4128, 4160, 4192, 4256]
@@ -277,8 +279,7 @@ if args.jobs == "on":
                        values_b=[3, 4], n_replications=n_rep_mj,
                        base_params=mj_cluster, engine="auto").run()
     compiles_after = vectorized_multijob.compile_cache_size()
-    compiles = (None if compiles_before is None or compiles_after is None
-                else compiles_after - compiles_before)
+    compiles = compiles_after - compiles_before
     print(f"{'spares':>7} {'shop':>5} {'engine':>7} {'makespan h':>11} "
           f"{'stalls':>7} {'queued':>7} {'job0 h':>7} {'job2 h':>7}")
     for p in mj.points:
@@ -291,7 +292,7 @@ if args.jobs == "on":
               f"{p.stats['job2_total_time'].mean / 60:>7.1f}")
     assert all(p.engine == "ctmc" for p in mj.points), \
         "multi-job grid should ride the compartment engine via auto"
-    assert compiles in (None, 0, 1), \
+    assert compiles in (0, 1), \
         f"mixed-size capacity grid should be ONE program, got {compiles}"
     print("\nThe fleet view prices what single-job sweeps cannot: spares "
           "and repair servers are shared, so the small job's stalls are "

@@ -3,8 +3,11 @@
     PYTHONPATH=src python examples/quickstart.py
 """
 
+from repro import compile_cache
 from repro.core import (MINUTES_PER_DAY, OneWaySweep, Params, aggregate,
                         simulate)
+
+compile_cache.enable()
 
 # ---------------------------------------------------------------------------
 # 1. one configuration, a few replications

@@ -15,6 +15,7 @@ import argparse
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import (MINUTES_PER_DAY, Params, resolve_engine,
                         run_replications, simulate)
 from repro.core.vectorized import supports
@@ -24,6 +25,7 @@ parser.add_argument("--fast", action="store_true")
 parser.add_argument("--engine", choices=("auto", "event", "ctmc"),
                     default="auto")
 args = parser.parse_args()
+compile_cache.enable()
 N = 96 if args.fast else 384
 
 BASE = Params(job_size=1024, working_pool_size=1056, spare_pool_size=128,

@@ -316,10 +316,7 @@ def run_full(fresh: dict, baseline: dict, rel_tolerance: float) -> None:
           f"weak-scaling curve reaches {val} forced host devices (>= 4)")
     for key, want in FULL_COMPILE_GATES.items():
         val = _lookup(fresh, key)
-        # None = jit-cache introspection unavailable on this jax: the
-        # count cannot be measured, which is not a regression
-        _gate(f"full.{key}", val is None or val == want,
-              f"{val} == {want} (None = unmeasurable, tolerated)")
+        _gate(f"full.{key}", val == want, f"{val} == {want}")
     for sec in ("", "structural.", "nonexp.", "repair_dist.",
                 "empirical.", "correlated.", "multijob.", "checkpoint."):
         key = f"{sec}max_abs_z"

@@ -66,7 +66,7 @@ class HistogramSpec:
         as ``HistogramSpec(low=0.01, high=1.0)``.
 
     Selecting a channel subset compiles the others *out* of the CTMC
-    scan state (smaller carry, fewer scatter lanes), not just out of the
+    scan state (smaller carry, fewer lanes to update), not just out of the
     reports; an empty tuple disables the accumulator like
     ``Params(histogram=None)``.
 

@@ -160,13 +160,12 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec
 
 from repro.kernels import ops
 from repro.parallel import sharding as rsharding
 from . import faultdomains, hazards
-from .histograms import HIST_CHANNELS
+from .histograms import DEFAULT_CHANNELS, HIST_CHANNELS
 from .params import Params
 
 COMPUTE, OVERHEAD, STALL, DONE = 0, 1, 2, 3
@@ -419,8 +418,8 @@ def _initial_state_batch(pts, R: int, max_runs: int,
     if sel:
         # only the channels the spec selects are carried through the
         # scan — unselected channels are compiled out of the state
-        # entirely (smaller carry + one fewer scatter lane).  The grid
-        # shares the first point's bin layout.
+        # entirely (a smaller carry, less to update each step).  The
+        # grid shares the first point's bin layout.
         state["hist"] = jnp.zeros((B, len(sel), spec.n_counts),
                                   jnp.float32)
         state["hist_edges"] = jnp.asarray(spec.edges(), jnp.float32)
@@ -505,7 +504,7 @@ def _repair_slots_for(pts, rkind: str) -> int:
     grids of similar scale share one compiled program — but never past
     the physical bound (every server in repair at once), where overflow
     is impossible and extra width is pure per-step cost: the slot
-    min/argmin/scatter ops are the lane's whole overhead.
+    min/argmin/masked-write ops are the lane's whole overhead.
     ``Params.repair_slots > 0`` overrides per point.
     """
     if rkind == "exponential":
@@ -545,6 +544,20 @@ def _onehot(c: jnp.ndarray) -> jnp.ndarray:
     return jax.nn.one_hot(c, 4, dtype=jnp.float32)
 
 
+def row_hit(col: jnp.ndarray, width: int) -> jnp.ndarray:
+    """Boolean one-hot of per-replica column indices: ``(...,)`` ->
+    ``(..., width)``.
+
+    A step writes at most one column of each replica's buffers (ring
+    slot, histogram bin, repair slot, job).  Written as
+    ``buf.at[rows, col]`` XLA's TPU backend sorts every index and then
+    scatters in a serial loop, which at 2^18 replica rows made one step
+    take longer than the rest of the scan; a select against this mask
+    is one dense vector pass, bit-identical to the scatter.
+    """
+    return jnp.arange(width) == col[..., None]
+
+
 # ---------------------------------------------------------------------------
 # one transition
 # ---------------------------------------------------------------------------
@@ -561,7 +574,7 @@ def _n_uniforms(kind: str, rkind: str = "exponential") -> int:
 def _step(s: Dict[str, jnp.ndarray], key_t: jax.Array, pv: jnp.ndarray,
           impl: Optional[str], kind: str = "exponential",
           rkind: str = "exponential",
-          hist_channels: tuple = HIST_CHANNELS,
+          hist_channels: tuple = DEFAULT_CHANNELS,
           scen=None, n_seg: int = 0,
           n_rseg: int = 0) -> Dict[str, jnp.ndarray]:
     R = s["t"].shape[0]
@@ -574,7 +587,7 @@ def _step(s: Dict[str, jnp.ndarray], key_t: jax.Array, pv: jnp.ndarray,
 def _step_u(s: Dict[str, jnp.ndarray], u: jnp.ndarray, pv: jnp.ndarray,
             impl: Optional[str], kind: str = "exponential",
             rkind: str = "exponential",
-            hist_channels: tuple = HIST_CHANNELS,
+            hist_channels: tuple = DEFAULT_CHANNELS,
             scen=None, n_seg: int = 0,
             n_rseg: int = 0) -> Dict[str, jnp.ndarray]:
     """One CTMC transition for a batch of replicas.
@@ -1104,11 +1117,9 @@ def _step_u(s: Dict[str, jnp.ndarray], u: jnp.ndarray, pv: jnp.ndarray,
     run_val = s["cur_run"] + progress
     max_runs = s["run_durations"].shape[1]
     if max_runs:    # static shape: max_runs=0 compiles the buffer out
-        rows = jnp.arange(run_val.shape[0])
-        slot = jnp.mod(s["n_runs"], max_runs)
-        kept = s["run_durations"][rows, slot]
-        ns["run_durations"] = s["run_durations"].at[rows, slot].set(
-            jnp.where(record, run_val, kept))
+        hit = row_hit(jnp.mod(s["n_runs"], max_runs), max_runs)
+        ns["run_durations"] = jnp.where(hit & record[:, None],
+                                        run_val[:, None], s["run_durations"])
     ns["n_runs"] = s["n_runs"] + record.astype(jnp.int32)
     ns["cur_run"] = jnp.where(record, 0.0, run_val)
 
@@ -1257,8 +1268,8 @@ def _step_u(s: Dict[str, jnp.ndarray], u: jnp.ndarray, pv: jnp.ndarray,
         ns["n_preemptions"] = ns["n_preemptions"] \
             + jnp.where(struck, t_fs, 0.0)
         if D_dom:
-            ns["domain_shocks"] = s["domain_shocks"].at[brows, dom].add(
-                is_shock.astype(jnp.float32))
+            ns["domain_shocks"] = s["domain_shocks"] + (
+                row_hit(dom, D_dom) & is_shock[:, None]).astype(jnp.float32)
         if Lc:
             ns["camp_idx"] = s["camp_idx"] + is_camp.astype(jnp.int32)
         if has_maint:
@@ -1288,13 +1299,11 @@ def _step_u(s: Dict[str, jnp.ndarray], u: jnp.ndarray, pv: jnp.ndarray,
     if rkind != "exponential":
         rsampler = hazards.REPAIR_SAMPLERS[rkind]
         adt = rep_rem.dtype
-        srows = jnp.arange(rep_rem.shape[0])
         rem = jnp.where(active[:, None], rep_rem - dt.astype(adt)[:, None],
                         rep_rem)
         # completion (won_slot) and entry (first free slot) are mutually
-        # exclusive per step — a single event ended it — so one fused
-        # scatter per slot array covers both; the per-step slot cost is
-        # this min/argmin/scatter traffic, so fusing matters
+        # exclusive per step — a single event ended it — so one masked
+        # write per slot array covers both
         free = jnp.isinf(rem)
         any_free = free.any(-1)
         fslot = jnp.argmax(free, axis=-1)
@@ -1320,16 +1329,15 @@ def _step_u(s: Dict[str, jnp.ndarray], u: jnp.ndarray, pv: jnp.ndarray,
         else:
             q_dur = rsampler.quantile(
                 u_dur, jnp.where(escalate, rz[1], rz[0]), rz[2]).astype(adt)
-        idx = jnp.where(is_rep, won_slot, fslot)
-        cur_rem = rem[srows, idx]
-        cur_stage = s["repair_stage"][srows, idx]
-        ns["repair_rem"] = rem.at[srows, idx].set(
-            jnp.where(finishes, jnp.inf,
-                      jnp.where(escalate | entered, q_dur, cur_rem)))
-        ns["repair_stage"] = s["repair_stage"].at[srows, idx].set(
-            jnp.where(escalate, 1, jnp.where(entered, 0, cur_stage)))
-        ns["repair_cls"] = s["repair_cls"].at[srows, idx].set(
-            jnp.where(entered, rm_cls, s["repair_cls"][srows, idx]))
+        hit = row_hit(jnp.where(is_rep, won_slot, fslot), rem.shape[1])
+        esc_h, ent_h = hit & escalate[:, None], hit & entered[:, None]
+        ns["repair_rem"] = jnp.where(
+            hit & finishes[:, None], jnp.inf,
+            jnp.where(esc_h | ent_h, q_dur[:, None], rem))
+        ns["repair_stage"] = jnp.where(
+            esc_h, 1, jnp.where(ent_h, 0, s["repair_stage"]))
+        ns["repair_cls"] = jnp.where(ent_h, rm_cls[:, None],
+                                     s["repair_cls"])
         # a full lane: the incoming server stays in the shop forever
         # (bookkeeping-consistent but wrong); surfaced as a metric and a
         # RuntimeWarning downstream — raise Params.repair_slots
@@ -1357,10 +1365,11 @@ def _step_u(s: Dict[str, jnp.ndarray], u: jnp.ndarray, pv: jnp.ndarray,
             downtime = jnp.where(sh_resolves, shock_timer, downtime)
             acquire_wait = jnp.where(sh_resolves, shock_timer - recovery,
                                      acquire_wait)
-        # one fused searchsorted + scatter-add across the selected
-        # channels (static ``hist_channels``, HIST_CHANNELS order) —
-        # per-channel scatters multiply the per-step accumulator cost,
-        # and unselected channels are compiled out entirely
+        # one fused bin search + masked add across the selected channels
+        # (static ``hist_channels``, HIST_CHANNELS order); unselected
+        # channels are compiled out entirely.  The search compares
+        # against every edge: the default binary search lowers to a
+        # gather loop on the TPU
         channel_vals = {"run_duration": (run_val, record),
                         "recovery": (downtime, ended),
                         "waiting": (acquire_wait, ended),
@@ -1374,11 +1383,10 @@ def _step_u(s: Dict[str, jnp.ndarray], u: jnp.ndarray, pv: jnp.ndarray,
                          axis=1)
         masks = jnp.stack([channel_vals[ch][1] for ch in hist_channels],
                           axis=1)                       # (B, n_sel)
-        idx = jnp.searchsorted(s["hist_edges"], vals, side="right")
-        rows = jnp.arange(vals.shape[0])[:, None]
-        chan = jnp.arange(vals.shape[1])[None, :]
-        ns["hist"] = s["hist"].at[rows, chan, idx].add(
-            masks.astype(jnp.float32))
+        idx = jnp.searchsorted(s["hist_edges"], vals, side="right",
+                               method="compare_all")
+        hit = row_hit(idx, s["hist"].shape[-1]) & masks[..., None]
+        ns["hist"] = s["hist"] + hit.astype(jnp.float32)
     return ns
 
 
@@ -1506,8 +1514,8 @@ def _chunk_loop(pv: jnp.ndarray, key: jax.Array, P: int, R: int,
             not_done &= jnp.any(state["phase"] != DONE)
         return not_done
 
-    _, state = jax.lax.while_loop(cond, chunk_body,
-                                  (jnp.int32(0), init_state))
+    n_run, state = jax.lax.while_loop(cond, chunk_body,
+                                      (jnp.int32(0), init_state))
     if rem:
         # partial final chunk so an explicit max_steps is honored exactly.
         # Finished replicas are inert, so under early_exit skipping the
@@ -1523,6 +1531,9 @@ def _chunk_loop(pv: jnp.ndarray, key: jax.Array, P: int, R: int,
     state["completed"] = (state["phase"] == DONE).astype(jnp.float32)
     state["total_time"] = jnp.where(state["phase"] == DONE,
                                     state["total_time"], state["t"])
+    #: full chunks the early-exit loop executed (the remainder chunk
+    #: not counted): ``chunks_run * chunk`` steps ran for every row
+    state["chunks_run"] = n_run
     return state
 
 
@@ -1602,49 +1613,45 @@ def _run_chunked_sharded(pv: jnp.ndarray, keys: jax.Array, P: int, R: int,
                           keys_s[0], P, R_loc, chunk, n_chunks_s, rem,
                           impl, early_exit, kind, rkind, hist_channels,
                           scen, flat, n_seg, n_rseg)
-        for k in unbatched_s:
+        for k in tuple(unbatched_s) + ("chunks_run",):
             out.pop(k)
         return {k: v.reshape((P, R_loc) + v.shape[1:])
                 for k, v in out.items()}
 
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(PartitionSpec(rsharding.REPLICA_AXIS), pv_spec,
                   PartitionSpec(),
                   {k: PartitionSpec() for k in unbatched},
                   rsharding.replica_state_specs(state)),
-        out_specs=out_specs, check_rep=False,
+        out_specs=out_specs, check_vma=False,
     )(keys, pv2, n_chunks, unbatched, state)
     out = {k: v.reshape((P * R,) + v.shape[2:]) for k, v in out.items()}
     out.update(unbatched)
     return out
 
 
-def compile_cache_size() -> Optional[int]:
+def compile_cache_size() -> int:
     """Compiled-program cache entries of the chunked-scan driver.
 
     One entry per distinct static signature = one XLA compilation; the
     structural-sweep smoke (scripts/ci.sh) and benchmarks diff this
     around a sweep to assert the padded path's one-compile invariant.
-    Relies on jax's private ``PjitFunction._cache_size``; returns None
-    when a jax upgrade removes that internal — callers must treat None
-    as "cannot measure", not as a regression.
+    Reads jax's ``PjitFunction._cache_size``: a jax without it fails
+    here, loudly, rather than letting a compile guard pass unchecked.
     """
-    fn = getattr(_run_chunked, "_cache_size", None)
-    return fn() if callable(fn) else None
+    return _run_chunked._cache_size()
 
 
-def shard_compile_cache_size() -> Optional[int]:
+def shard_compile_cache_size() -> int:
     """Compiled-program cache entries of the *sharded* chunked driver.
 
     The sharded weak-scaling benchmark diffs this around repeated sweeps
     to assert the sharded path keeps the one-compile invariant (the mesh
     object is part of the static signature, so re-running at the same
-    device count reuses one program).  Same None-means-unmeasurable
-    contract as :func:`compile_cache_size`.
+    device count reuses one program).
     """
-    fn = getattr(_run_chunked_sharded, "_cache_size", None)
-    return fn() if callable(fn) else None
+    return _run_chunked_sharded._cache_size()
 
 
 def _resolve_shards(shards, pts) -> int:
@@ -1773,7 +1780,7 @@ def simulate_ctmc(params: Params, n_replicas: int = 1024, seed: int = 0,
     return _extract(out, channels=channels)
 
 
-def simulate_ctmc_sweep(params_list, n_replicas: int = 1024, seed: int = 0,
+def simulate_ctmc_sweep(params_list, n_replicas: int = 1024, seed=0,
                         max_steps: Optional[int] = None,
                         impl: Optional[str] = None,
                         chunk_steps: Optional[int] = None,
@@ -1830,7 +1837,41 @@ def simulate_ctmc_sweep(params_list, n_replicas: int = 1024, seed: int = 0,
     a static compile switch, a grid mixing backends splits into one
     batch per backend.
 
+    ``seed`` is an int or a PRNG key array — a row of
+    :func:`repro.parallel.sharding.shard_keys` reproduces one shard of
+    a sharded sweep on its own.
+
     Returns a list of ``{metric: np.ndarray (R,)}`` dicts in input order.
+    """
+    params_list = list(params_list)
+    results: list = [None] * len(params_list)
+    for idxs, R_run, run, args, kw in sweep_programs(
+            params_list, n_replicas, seed, max_steps, impl, chunk_steps,
+            early_exit, padded, bucketed, max_runs, shards):
+        out = run(*args, **kw)
+        channels = _hist_channels(params_list)   # params_list is non-empty
+        for j, i in enumerate(idxs):
+            rows = (slice(j * R_run, j * R_run + n_replicas)
+                    if R_run == n_replicas
+                    else np.arange(n_replicas) + j * R_run)
+            results[i] = _extract(out, rows, channels)
+    return results
+
+
+def sweep_programs(params_list, n_replicas: int = 1024, seed=0,
+                   max_steps: Optional[int] = None,
+                   impl: Optional[str] = None,
+                   chunk_steps: Optional[int] = None,
+                   early_exit: bool = True, padded: bool = True,
+                   bucketed: bool = True, max_runs: Optional[int] = None,
+                   shards: Optional[int] = None) -> list:
+    """The compiled programs :func:`simulate_ctmc_sweep` runs, unrun.
+
+    One ``(idxs, R_run, run, args, kwargs)`` entry per compile group:
+    ``run(*args, **kwargs)`` is the jitted chunk driver call for the
+    points ``idxs`` of ``params_list``, at ``R_run`` replica rows per
+    point.  ``run.lower(*args, **kwargs).compile()`` gives the program
+    itself — its text, its memory analysis — without running it.
     """
     params_list = list(params_list)
     for p in params_list:
@@ -1886,7 +1927,9 @@ def simulate_ctmc_sweep(params_list, n_replicas: int = 1024, seed: int = 0,
 
     bucket = padded and bucketed
     channels = _hist_channels(params_list)
-    results: list = [None] * len(params_list)
+    key = (seed if isinstance(seed, jax.Array)
+           else jax.random.PRNGKey(seed))
+    programs = []
     for (kind, rkind, _adt, scen, n_seg, n_rseg, skey, impl_eff), idxs in \
             groups.items():
         pts = [params_list[i] for i in idxs]
@@ -1914,18 +1957,15 @@ def simulate_ctmc_sweep(params_list, n_replicas: int = 1024, seed: int = 0,
                                           scen)
         if (P_run, R_run) != (P, R):
             init_state = _bucket_pad_state(init_state, P, R, P_run, R_run)
-        key = jax.random.PRNGKey(seed)
         run_args = (P_run, R_run, chunk, jnp.int32(steps // chunk),
                     steps % chunk, impl_eff, early_exit, skey, kind,
                     rkind, channels, scen, init_state, n_seg, n_rseg)
         if shards:
-            out = _run_chunked_sharded(
-                pv_flat, rsharding.shard_keys(key, shards), *run_args,
-                mesh=_shard_mesh(shards, R_run))
+            programs.append((
+                idxs, R_run, _run_chunked_sharded,
+                (pv_flat, rsharding.shard_keys(key, shards)) + run_args,
+                {"mesh": _shard_mesh(shards, R_run)}))
         else:
-            out = _run_chunked(pv_flat, key, *run_args)
-        for j, i in enumerate(idxs):
-            rows = (slice(j * R_run, j * R_run + R) if R_run == R
-                    else np.arange(R) + j * R_run)
-            results[i] = _extract(out, rows, channels)
-    return results
+            programs.append((idxs, R_run, _run_chunked,
+                             (pv_flat, key) + run_args, {}))
+    return programs

@@ -74,7 +74,7 @@ from . import hazards
 from .multijob import JobSpec
 from .params import Params
 from .vectorized import (COMPUTE, DONE, OVERHEAD, STALL, _next_pow2,
-                         _selected_channels, default_max_steps)
+                         _selected_channels, default_max_steps, row_hit)
 from .vectorized import DEFAULT_CHUNK_STEPS
 
 #: per-job scalar metrics carried as (B, J) lanes — the per-job
@@ -275,6 +275,14 @@ def _onehot4(c: jnp.ndarray) -> jnp.ndarray:
     return jax.nn.one_hot(c, 4, dtype=jnp.float32)
 
 
+def _add_at_job(buf: jnp.ndarray, j1b: jnp.ndarray,
+                upd: jnp.ndarray) -> jnp.ndarray:
+    """``buf`` (B, J, 4) plus ``upd`` (B, 4) on the job that ``j1b``
+    (B, J) selects: ``buf.at[rows, j].add(upd)`` as a dense select (see
+    :func:`repro.core.vectorized.row_hit`)."""
+    return buf + jnp.where(j1b[..., None], upd[:, None, :], 0.0)
+
+
 # ---------------------------------------------------------------------------
 # one transition
 # ---------------------------------------------------------------------------
@@ -377,12 +385,10 @@ def _mj_step_u(s: Dict[str, jnp.ndarray], u: jnp.ndarray, pv: jnp.ndarray,
     run_val = s["cur_run"] + progress
     max_runs = s["run_durations"].shape[2]
     if max_runs:
-        slot = jnp.mod(s["n_runs"], max_runs)                  # (B, J)
-        kept = jnp.take_along_axis(s["run_durations"], slot[..., None],
-                                   axis=2)[..., 0]
-        new = jnp.where(record, run_val, kept)
-        ns["run_durations"] = s["run_durations"].at[
-            rows[:, None], jobs_ax[None, :], slot].set(new)
+        hit = row_hit(jnp.mod(s["n_runs"], max_runs), max_runs)  # (B,J,M)
+        ns["run_durations"] = jnp.where(hit & record[..., None],
+                                        run_val[..., None],
+                                        s["run_durations"])
     ns["n_runs"] = s["n_runs"] + record.astype(jnp.int32)
     ns["cur_run"] = jnp.where(record, 0.0, run_val)
 
@@ -414,7 +420,7 @@ def _mj_step_u(s: Dict[str, jnp.ndarray], u: jnp.ndarray, pv: jnp.ndarray,
 
     rm1h = jnp.where(wrong[:, None], pick1h[:, 0], _onehot4(cls)) \
         * diagnosed[:, None]                                   # (B, 4)
-    ns["run"] = s["run"].at[rows, ej].add(-rm1h)
+    ns["run"] = _add_at_job(s["run"], ej1b, -rm1h)
 
     # shop entry: a free service slot starts the automated stage at
     # once; a full shop parks the server in the queue lane (by owner)
@@ -423,8 +429,8 @@ def _mj_step_u(s: Dict[str, jnp.ndarray], u: jnp.ndarray, pv: jnp.ndarray,
     has_slot = shop_active < cap_eff
     enters = diagnosed & has_slot
     queues = diagnosed & ~has_slot
-    ns["auto"] = s["auto"].at[rows, ej].add(rm1h * enters[:, None])
-    ns["q"] = s["q"].at[rows, ej].add(rm1h * queues[:, None])
+    ns["auto"] = _add_at_job(s["auto"], ej1b, rm1h * enters[:, None])
+    ns["q"] = _add_at_job(s["q"], ej1b, rm1h * queues[:, None])
     ns["n_shop_queued"] = s["n_shop_queued"] + f32(queues)
 
     # replacement waterfall: own standbys -> shared working -> shared
@@ -440,10 +446,10 @@ def _mj_step_u(s: Dict[str, jnp.ndarray], u: jnp.ndarray, pv: jnp.ndarray,
     take = (pick1h[:, 1] * use_sb[:, None]
             + pick1h[:, 2] * use_fw[:, None]
             + pick1h[:, 3] * use_fs[:, None])
-    ns["sb"] = s["sb"].at[rows, ej].add(-pick1h[:, 1] * use_sb[:, None])
+    ns["sb"] = _add_at_job(s["sb"], ej1b, -pick1h[:, 1] * use_sb[:, None])
     ns["fw"] = s["fw"] - pick1h[:, 2] * use_fw[:, None]
     ns["fs"] = s["fs"] - pick1h[:, 3] * use_fs[:, None]
-    ns["run"] = ns["run"].at[rows, ej].add(take)
+    ns["run"] = _add_at_job(ns["run"], ej1b, take)
     ns["n_standby_swaps"] = s["n_standby_swaps"] + f32(use_sb[:, None] & ej1b)
     ns["n_host_selections"] = s["n_host_selections"] \
         + f32((use_fw | use_fs)[:, None] & ej1b)
@@ -464,11 +470,11 @@ def _mj_step_u(s: Dict[str, jnp.ndarray], u: jnp.ndarray, pv: jnp.ndarray,
 
     # ---- repair completions ---------------------------------------------
     rep1h = _onehot4(cls)
-    ns["auto"] = ns["auto"].at[rows, ej].add(-rep1h * is_auto[:, None])
+    ns["auto"] = _add_at_job(ns["auto"], ej1b, -rep1h * is_auto[:, None])
     ns["n_auto_repairs"] = s["n_auto_repairs"] + f32(is_auto)
     escalate = is_auto & (u_esc >= p_auto)
-    ns["man"] = s["man"].at[rows, ej].add(
-        rep1h * escalate[:, None] - rep1h * is_man[:, None])
+    ns["man"] = _add_at_job(
+        s["man"], ej1b, rep1h * escalate[:, None] - rep1h * is_man[:, None])
     ns["n_manual_repairs"] = s["n_manual_repairs"] + f32(is_man)
 
     finishes = (is_auto & ~escalate) | is_man
@@ -490,8 +496,7 @@ def _mj_step_u(s: Dict[str, jnp.ndarray], u: jnp.ndarray, pv: jnp.ndarray,
     k1b = jax.nn.one_hot(k_star, J, dtype=jnp.float32) > 0.5
     to_stalled_j = to_stalled[:, None] & k1b
     surcharge = to_stalled & (k_star != ej)
-    ns["run"] = ns["run"].at[rows, k_star].add(
-        out1h * to_stalled[:, None])
+    ns["run"] = _add_at_job(ns["run"], k1b, out1h * to_stalled[:, None])
     unstall_timer = recovery + jnp.where(surcharge, host_sel, 0.0)
     ns["phase"] = jnp.where(to_stalled_j, OVERHEAD, ns["phase"])
     ns["timer"] = jnp.where(to_stalled_j, unstall_timer[:, None],
@@ -510,7 +515,7 @@ def _mj_step_u(s: Dict[str, jnp.ndarray], u: jnp.ndarray, pv: jnp.ndarray,
     to_sb = finishes & ~to_stalled & owner_active \
         & (sb_owner_tot < warm_of(ej))
     to_pool = finishes & ~to_stalled & ~to_sb
-    ns["sb"] = ns["sb"].at[rows, ej].add(out1h * to_sb[:, None])
+    ns["sb"] = _add_at_job(ns["sb"], ej1b, out1h * to_sb[:, None])
     ns["fw"] = ns["fw"] + out1h * (to_pool & ~spare_origin)[:, None]
     ns["fs"] = ns["fs"] + out1h * (to_pool & spare_origin)[:, None]
 
@@ -522,8 +527,9 @@ def _mj_step_u(s: Dict[str, jnp.ndarray], u: jnp.ndarray, pv: jnp.ndarray,
     pick_q = _pick_cat(q_flat, u_adm)
     qj = (pick_q // 4).astype(jnp.int32)
     qc1h = _onehot4(pick_q % 4) * admit[:, None]
-    ns["q"] = ns["q"].at[rows, qj].add(-qc1h)
-    ns["auto"] = ns["auto"].at[rows, qj].add(qc1h)
+    qj1b = row_hit(qj, J)
+    ns["q"] = _add_at_job(ns["q"], qj1b, -qc1h)
+    ns["auto"] = _add_at_job(ns["auto"], qj1b, qc1h)
 
     # ---- histogram bookkeeping for failure/unstall paths ---------------
     # per step each job records at most one recovery/waiting event:
@@ -544,10 +550,9 @@ def _mj_step_u(s: Dict[str, jnp.ndarray], u: jnp.ndarray, pv: jnp.ndarray,
     ci = jnp.argmax(is_complete, axis=-1)                      # (B,)
     rel = (ns["run"][rows, ci] + ns["sb"][rows, ci]) \
         * any_complete[:, None]                                # (B, 4)
-    ns["run"] = ns["run"].at[rows, ci].multiply(
-        jnp.where(any_complete, 0.0, 1.0)[:, None])
-    ns["sb"] = ns["sb"].at[rows, ci].multiply(
-        jnp.where(any_complete, 0.0, 1.0)[:, None])
+    released = (row_hit(ci, J) & any_complete[:, None])[..., None]
+    ns["run"] = jnp.where(released, 0.0, ns["run"])
+    ns["sb"] = jnp.where(released, 0.0, ns["sb"])
 
     # released servers go to starving jobs first (earliest stall first,
     # one each — the release-watcher semantics), always paying the
@@ -568,7 +573,7 @@ def _mj_step_u(s: Dict[str, jnp.ndarray], u: jnp.ndarray, pv: jnp.ndarray,
         u_r = jnp.mod(u_rel + r * _PHI, 1.0)
         p1h = _onehot4(_pick_cat(rel_rem, u_r)) * can[:, None]
         rel_rem = rel_rem - p1h
-        ns["run"] = ns["run"].at[rows, k_r].add(p1h)
+        ns["run"] = _add_at_job(ns["run"], kr1b, p1h)
         rel_wait = t_new - ns["stall_start"][rows, k_r]
         ns["phase"] = jnp.where(can_j, OVERHEAD, ns["phase"])
         ns["timer"] = jnp.where(can_j, rel_timer[:, None], ns["timer"])
@@ -596,11 +601,10 @@ def _mj_step_u(s: Dict[str, jnp.ndarray], u: jnp.ndarray, pv: jnp.ndarray,
                          axis=2)                               # (B, J, S)
         masks = jnp.stack([channel_vals[ch][1] for ch in hist_channels],
                           axis=2)
-        idx = jnp.searchsorted(s["hist_edges"], vals, side="right")
-        ns["hist"] = s["hist"].at[
-            rows[:, None, None], jobs_ax[None, :, None],
-            jnp.arange(len(hist_channels))[None, None, :], idx].add(
-            masks.astype(jnp.float32))
+        idx = jnp.searchsorted(s["hist_edges"], vals, side="right",
+                               method="compare_all")
+        hit = row_hit(idx, s["hist"].shape[-1]) & masks[..., None]
+        ns["hist"] = s["hist"] + hit.astype(jnp.float32)
 
     # ---- conservation invariant ----------------------------------------
     tot = (ns["run"].sum((-2, -1)) + ns["sb"].sum((-2, -1))
@@ -732,7 +736,6 @@ def _mj_run_chunked_sharded(pv: jnp.ndarray, keys: jax.Array, P: int,
     concatenation is the cross-device merge.  A 1-device mesh is
     bit-identical to :func:`_mj_run_chunked`.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
 
     from repro.parallel import sharding as rsharding
@@ -758,31 +761,28 @@ def _mj_run_chunked_sharded(pv: jnp.ndarray, keys: jax.Array, P: int,
         return {k: v.reshape((P, R_loc) + v.shape[1:])
                 for k, v in out.items()}
 
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(PartitionSpec(rsharding.REPLICA_AXIS), rspec,
                   PartitionSpec(),
                   {k: PartitionSpec() for k in unbatched},
                   rsharding.replica_state_specs(state)),
-        out_specs=out_specs, check_rep=False,
+        out_specs=out_specs, check_vma=False,
     )(keys, pv2, n_chunks, unbatched, state)
     out = {k: v.reshape((P * R,) + v.shape[2:]) for k, v in out.items()}
     out.update(unbatched)
     return out
 
 
-def compile_cache_size() -> Optional[int]:
+def compile_cache_size() -> int:
     """Compiled-program cache entries of the multi-job chunked driver
-    (None when jax's private cache introspection is unavailable)."""
-    fn = getattr(_mj_run_chunked, "_cache_size", None)
-    return fn() if callable(fn) else None
+    (same contract as :func:`repro.core.vectorized.compile_cache_size`)."""
+    return _mj_run_chunked._cache_size()
 
 
-def shard_compile_cache_size() -> Optional[int]:
-    """Compiled-program cache entries of the *sharded* multi-job driver
-    (same contract as :func:`compile_cache_size`)."""
-    fn = getattr(_mj_run_chunked_sharded, "_cache_size", None)
-    return fn() if callable(fn) else None
+def shard_compile_cache_size() -> int:
+    """Compiled-program cache entries of the *sharded* multi-job driver."""
+    return _mj_run_chunked_sharded._cache_size()
 
 
 def _unsupported_error(cluster: Params, jobs) -> ValueError:

@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams as _CompilerParams
-
 
 def _mamba_kernel(x_ref, dt_ref, At_ref, B_ref, C_ref, h0_ref,
                   y_ref, hf_ref, h_scratch, *, chunk: int, n_chunks: int):
@@ -100,7 +98,7 @@ def selective_scan_fwd(x: jax.Array, dt: jax.Array, A: jax.Array,
             jax.ShapeDtypeStruct((Bsz, N, di), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((N, block_d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, At, Bmat, Cmat, h0)

@@ -23,13 +23,19 @@ import jax
 import jax.numpy as jnp
 
 from . import ref
-from .des_step import event_race_fwd
+from .des_step import LANES, event_race_fwd
 from .flash_attention import flash_attention_fwd
 from .mamba_scan import selective_scan_fwd
 
 
+def _backend() -> str:
+    """The backend this process compiles for.  A test that compiles for
+    a described, unattached TPU patches this to ``"tpu"``."""
+    return jax.default_backend()
+
+
 def _default_impl() -> str:
-    return "pallas" if jax.default_backend() == "tpu" else "ref"
+    return "pallas" if _backend() == "tpu" else "ref"
 
 
 # ---------------------------------------------------------------------------
@@ -166,19 +172,20 @@ def _round_up(n: int, m: int) -> int:
 
 def event_race(rates: jax.Array, residuals: jax.Array, u_time: jax.Array,
                u_pick: jax.Array, *, impl: Optional[str] = None,
-               block_r: int = 1024) -> Tuple[jax.Array, jax.Array]:
+               block_r: int = 8192) -> Tuple[jax.Array, jax.Array]:
     """Next-event race; see des_step.py. No gradients (simulation only).
 
     ``impl``: None auto-selects (``"pallas"`` on TPU, ``"ref"``
     elsewhere); ``"ref"`` is the always-available pure-jnp path;
     ``"pallas"`` requires a TPU backend and raises otherwise (use
     ``"pallas_interpret"`` — the kernel body executed op-by-op on CPU —
-    for validation).  The kernel path pads the replica axis to whole
-    sublane-aligned blocks and the K lanes to multiples of 8 with inert
-    values (see des_step.py); padding is sliced off before returning,
-    so every (R, K_exp, K_det) shape runs the kernel — there is no
-    silent shape fallback.  Zero-width lane blocks are invalid on every
-    backend (the reference cannot reduce them either) and raise.
+    for validation).  The kernel path lays the K lanes out as a leading
+    axis over ``(rows, 128)`` replica tiles and pads the replica axis to
+    whole blocks of at most ``block_r`` replicas with inert values (see
+    des_step.py); padding is sliced off before returning, so every
+    (R, K_exp, K_det) shape runs the kernel — there is no silent shape
+    fallback.  Zero-width lane blocks are invalid on every backend (the
+    reference cannot reduce them either) and raise.
 
     With all rates zero the deterministic side wins and the event index
     is ``K_exp + argmin(residuals)`` — identical across backends:
@@ -196,10 +203,10 @@ def event_race(rates: jax.Array, residuals: jax.Array, u_time: jax.Array,
     impl = impl or _default_impl()
     if impl == "ref":
         return ref.event_race_ref(rates, residuals, u_time, u_pick)
-    if impl == "pallas" and jax.default_backend() != "tpu":
+    if impl == "pallas" and _backend() != "tpu":
         raise ValueError(
             f"event_race impl='pallas' requires a TPU backend (default "
-            f"backend here is {jax.default_backend()!r}); use "
+            f"backend here is {_backend()!r}); use "
             f"impl='pallas_interpret' for CPU validation or impl='ref' "
             f"for the pure-jnp path (docs/scaling.md)")
     if impl not in ("pallas", "pallas_interpret"):
@@ -214,17 +221,19 @@ def event_race(rates: jax.Array, residuals: jax.Array, u_time: jax.Array,
             f"deterministic lane (got K_exp={k_exp}, K_det={k_det}); a "
             f"zero-width lane block has no next event to race — disable "
             f"the empty side with zero rates / +inf residuals instead")
-    # pad K lanes to sublane multiples with inert values, the replica
-    # axis to a whole number of blocks with inert rows (see des_step.py)
-    ke_pad, kd_pad = _round_up(k_exp, 8), _round_up(k_det, 8)
-    block = min(block_r, _round_up(R, 8))
-    r_pad = _round_up(R, block)
-    rates_p = jnp.pad(rates, ((0, r_pad - R), (0, ke_pad - k_exp)))
-    resid_p = jnp.pad(residuals, ((0, r_pad - R), (0, kd_pad - k_det)),
-                      constant_values=jnp.inf)
-    u2 = jnp.stack([u_time, u_pick], axis=-1)           # (R, 2)
-    u2 = jnp.pad(u2, ((0, r_pad - R), (0, 0)), constant_values=0.5)
-    dt, event = event_race_fwd(rates_p, resid_p, u2, k_exp=k_exp,
-                               k_det=k_det, block_r=block,
-                               interpret=impl == "pallas_interpret")
-    return dt[:R], event[:R]
+    # lane-major layout: (K, R) padded with inert replicas (zero rates,
+    # +inf residuals) to whole blocks of `rows` x 128, folded into tiles
+    rows = min(_round_up(max(block_r // LANES, 1), 8),
+               _round_up(-(-R // LANES), 8))
+    r_pad = _round_up(R, rows * LANES)
+
+    def lane_major(x, fill):
+        x = jnp.pad(x.astype(jnp.float32).T, ((0, 0), (0, r_pad - R)),
+                    constant_values=fill)
+        return x.reshape(x.shape[0], r_pad // LANES, LANES)
+
+    dt, event = event_race_fwd(
+        lane_major(rates, 0.0), lane_major(residuals, jnp.inf),
+        lane_major(jnp.stack([u_time, u_pick], axis=-1), 0.5),
+        block_rows=rows, interpret=impl == "pallas_interpret")
+    return dt.reshape(r_pad)[:R], event.reshape(r_pad)[:R]

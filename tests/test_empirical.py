@@ -274,8 +274,6 @@ def test_hazard_fitted_from_timestamped_log_runs_on_ctmc(tmp_path):
 def test_edge_and_rate_grid_compiles_once():
     from repro.core import vectorized
 
-    if vectorized.compile_cache_size() is None:
-        pytest.skip("jit cache introspection unavailable on this jax")
     short = dict(BASE, job_length=0.25 * DAY)
     grid = [Params(failure_distribution="empirical",
                    distribution_kwargs={"edges": [0.3 + 0.1 * i, 2.0 + i],

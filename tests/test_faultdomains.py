@@ -282,8 +282,6 @@ def test_maintenance_pauses_repairs_resume_with_remaining():
 def test_shock_rate_grid_compiles_once():
     from repro.core import vectorized
 
-    if vectorized.compile_cache_size() is None:
-        pytest.skip("jit cache introspection unavailable on this jax")
     base = SCENARIO.replace(job_length=500.0,
                             max_run_records=17)   # module-unique shape
     grid = [base.replace(fault_domains=FaultTopology(

@@ -183,6 +183,8 @@ def test_selective_scan_step_matches_scan():
     # padded paths: replica axis not a block multiple, K lanes far off
     # the sublane width, degenerate single-lane races
     (100, 3, 1), (8, 1, 1), (130, 9, 5), (96, 23, 7),
+    # the correlated-scenario and multi-job race widths
+    (300, 36, 4), (2100, 48, 6),
 ])
 def test_event_race_matches_ref(R, Ke, Kd):
     rng = np.random.default_rng(R)

@@ -209,8 +209,6 @@ def test_mixed_family_grid_runs_in_input_order():
 def test_weibull_k_is_traced_one_compile_per_bucket():
     from repro.core import vectorized
 
-    if vectorized.compile_cache_size() is None:
-        pytest.skip("jit cache introspection unavailable on this jax")
     short = dict(BASE, job_length=0.25 * DAY)
     base = Params(failure_distribution="weibull",
                   distribution_kwargs={"k": 1.5},

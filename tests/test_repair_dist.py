@@ -190,8 +190,6 @@ def test_weibull_k1_repairs_reduce_to_exponential():
 def test_repair_parameter_grid_compiles_once():
     from repro.core import vectorized
 
-    if vectorized.compile_cache_size() is None:
-        pytest.skip("jit cache introspection unavailable on this jax")
     short = dict(BASE, job_length=0.25 * DAY)
     base = Params(repair_distribution="weibull",
                   distribution_kwargs={"k": 0.7},

@@ -274,6 +274,4 @@ def test_sharded_sweep_one_compile_per_signature():
                             small_params(spare_pool_size=2)],
                            n_replicas=32, seed=2, max_steps=128, shards=4)
     after = vz.shard_compile_cache_size()
-    if before is None or after is None:
-        pytest.skip("jax cache introspection unavailable")
     assert after == before, "same static signature must not recompile"
