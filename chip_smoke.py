@@ -142,7 +142,7 @@ def grid_phase(kind: str) -> None:
     check(impl == "pallas", f"default race impl is {impl!r}, not 'pallas'")
     grid = fig2a_grid()
     [(_, R_run, run, args, kw)] = vz.sweep_programs(grid, REPLICAS, SEED)
-    P_run, chunk = args[2], args[4]
+    P_run = args[2]
 
     t0 = time.perf_counter()
     compiled = run.lower(*args, **kw).compile()
@@ -170,11 +170,11 @@ def grid_phase(kind: str) -> None:
     out = jax.block_until_ready(run(*args, **kw))
     device_s = time.perf_counter() - t0
     warm_compiles = vz.compile_cache_size() - c0
-    chunks = int(out["chunks_run"])
+    chunks, steps = int(out["chunks_run"]), int(out["steps_run"])
     del out
     log(f"[measured on {kind}] grid first_call_s={first_s:.3f} "
         f"warm_study_s={warm_s:.3f} warm_program_s={device_s:.3f} "
-        f"(block_until_ready) chunks_run={chunks} steps_run={chunks * chunk} "
+        f"(block_until_ready) chunks_run={chunks} steps_run={steps} "
         f"compiles_in_warm_window={warm_compiles}")
     check(warm_compiles == 0,
           f"the warm window compiled {warm_compiles} programs")

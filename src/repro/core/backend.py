@@ -36,7 +36,7 @@ from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
-from . import vectorized, vectorized_multijob
+from . import tracing, vectorized, vectorized_multijob
 from .histograms import Histogram
 from .metrics import (RunResult, Stat, aggregate, aggregate_arrays,
                       aggregate_multijob_arrays, histograms_from_arrays,
@@ -86,26 +86,32 @@ class Replications:
     histograms: Dict[str, Histogram] = field(default_factory=dict)
 
 
-def _from_arrays(arrays: Dict[str, np.ndarray], n: int) -> Replications:
-    incomplete = int(n - arrays["completed"].sum())
-    if incomplete:
-        warnings.warn(
-            f"{incomplete}/{n} CTMC replicas hit the step budget before "
-            "finishing the job; means are biased low — raise max_steps "
-            "(truncation is surfaced as the 'n_incomplete' metric and the "
-            "'completed' fraction in stats and sweep CSVs)",
-            RuntimeWarning, stacklevel=3)
-    overflows = int(arrays.get("n_repair_overflow", np.zeros(1)).sum())
-    if overflows:
-        warnings.warn(
-            f"{overflows} diagnosed failure(s) found the repair-slot lane "
-            "full (the server never leaves the shop; results are biased) "
-            "— raise Params.repair_slots",
-            RuntimeWarning, stacklevel=3)
-    hists = histograms_from_arrays(arrays)
-    return Replications(engine="ctmc", n=n,
-                        stats=aggregate_arrays(arrays, histograms=hists),
-                        arrays=arrays, histograms=hists)
+def _from_arrays(arrays: Dict[str, np.ndarray], n: int,
+                 point: int = 0) -> Replications:
+    """Host statistics of one point's per-replica arrays."""
+    with tracing.span(tracing.AGGREGATE, point=point):
+        incomplete = int(n - arrays["completed"].sum())
+        if incomplete:
+            warnings.warn(
+                f"{incomplete}/{n} CTMC replicas hit the step budget "
+                "before finishing the job; means are biased low — raise "
+                "max_steps (truncation is surfaced as the 'n_incomplete' "
+                "metric and the 'completed' fraction in stats and sweep "
+                "CSVs)",
+                RuntimeWarning, stacklevel=3)
+        overflows = int(arrays.get("n_repair_overflow",
+                                   np.zeros(1)).sum())
+        if overflows:
+            warnings.warn(
+                f"{overflows} diagnosed failure(s) found the repair-slot "
+                "lane full (the server never leaves the shop; results are "
+                "biased) — raise Params.repair_slots",
+                RuntimeWarning, stacklevel=3)
+        hists = histograms_from_arrays(arrays)
+        return Replications(
+            engine="ctmc", n=n,
+            stats=aggregate_arrays(arrays, histograms=hists),
+            arrays=arrays, histograms=hists)
 
 
 def _from_results(results: List[RunResult], n: int,
@@ -124,9 +130,11 @@ def run_replications(params: Params, n: int, engine: str = "auto",
     chosen = resolve_engine(params, engine)
     if chosen == "ctmc":
         seed = params.seed if base_seed is None else base_seed
-        arrays = vectorized.simulate_ctmc(params, n_replicas=n, seed=seed,
-                                          impl=impl, max_steps=max_steps)
-        return _from_arrays(arrays, n)
+        with tracing.study(points=1, replicas=n):
+            arrays = vectorized.simulate_ctmc(params, n_replicas=n,
+                                              seed=seed, impl=impl,
+                                              max_steps=max_steps)
+            return _from_arrays(arrays, n)
     results = simulate(params, n, base_seed=base_seed)
     return _from_results(results, n, params)
 
@@ -183,12 +191,13 @@ def run_replications_batch(params_list: Sequence[Params], n: int,
                 progress(i)
         seed = (params_list[ctmc_idx[0]].seed if base_seed is None
                 else base_seed)
-        arrays_list = vectorized.simulate_ctmc_sweep(
-            [params_list[i] for i in ctmc_idx], n_replicas=n, seed=seed,
-            impl=impl, max_steps=max_steps, padded=padded,
-            bucketed=bucketed)
-        for i, arrays in zip(ctmc_idx, arrays_list):
-            out[i] = _from_arrays(arrays, n)
+        with tracing.study(points=len(ctmc_idx), replicas=n):
+            arrays_list = vectorized.simulate_ctmc_sweep(
+                [params_list[i] for i in ctmc_idx], n_replicas=n,
+                seed=seed, impl=impl, max_steps=max_steps, padded=padded,
+                bucketed=bucketed)
+            for i, arrays in zip(ctmc_idx, arrays_list):
+                out[i] = _from_arrays(arrays, n, point=i)
 
     for i, c in enumerate(chosen):
         if c == "event":

@@ -164,7 +164,7 @@ from jax.sharding import PartitionSpec
 
 from repro.kernels import ops
 from repro.parallel import sharding as rsharding
-from . import faultdomains, hazards
+from . import faultdomains, hazards, tracing
 from .histograms import DEFAULT_CHANNELS, HIST_CHANNELS
 from .params import Params
 
@@ -834,9 +834,12 @@ def _step_u(s: Dict[str, jnp.ndarray], u: jnp.ndarray, pv: jnp.ndarray,
         coff = 1
     roff = 0
     if rkind != "exponential":
-        rep_rem = s["repair_rem"]
-        resid_cols.append(jnp.where(
-            active, rep_rem.min(-1).astype(jnp.float32), jnp.inf))
+        # the repair-slot lane's work is scoped here, where its
+        # completions are decoded, and in its own section below
+        with jax.named_scope(tracing.REPAIR_LANE):
+            rep_rem = s["repair_rem"]
+            resid_cols.append(jnp.where(
+                active, rep_rem.min(-1).astype(jnp.float32), jnp.inf))
         roff = 1
     resid_cols += [
         jnp.where(computing, s["work_left"], jnp.inf),
@@ -855,7 +858,9 @@ def _step_u(s: Dict[str, jnp.ndarray], u: jnp.ndarray, pv: jnp.ndarray,
         jnp.maximum(ckpt - s["ckpt_work"], 0.0), jnp.inf))
     residuals = jnp.stack(resid_cols, axis=-1)
 
-    dt, ev = ops.event_race(rates, residuals, u_time, u_pick, impl=impl)
+    with jax.named_scope(tracing.RACE):
+        dt, ev = ops.event_race(rates, residuals, u_time, u_pick,
+                                impl=impl)
     dt = jnp.where(active & jnp.isfinite(dt), dt, 0.0)
 
     cls = (ev % 4).astype(jnp.int32)
@@ -915,14 +920,15 @@ def _step_u(s: Dict[str, jnp.ndarray], u: jnp.ndarray, pv: jnp.ndarray,
         # a slot repair completed: the winning slot's stage and class
         # drive the same downstream completion logic the exponential
         # channels feed (channels 8..16 are rateless here)
-        rows = jnp.arange(rep_rem.shape[0])
-        won_slot = jnp.argmin(rep_rem, axis=-1)
-        is_rep = active & (ev == kx + coff)
-        done_stage = s["repair_stage"][rows, won_slot]
-        cls = jnp.where(is_rep, s["repair_cls"][rows, won_slot],
-                        cls).astype(jnp.int32)
-        is_auto = is_rep & (done_stage == 0)
-        is_man = is_rep & (done_stage == 1)
+        with jax.named_scope(tracing.REPAIR_LANE):
+            rows = jnp.arange(rep_rem.shape[0])
+            won_slot = jnp.argmin(rep_rem, axis=-1)
+            is_rep = active & (ev == kx + coff)
+            done_stage = s["repair_stage"][rows, won_slot]
+            cls = jnp.where(is_rep, s["repair_cls"][rows, won_slot],
+                            cls).astype(jnp.int32)
+            is_auto = is_rep & (done_stage == 0)
+            is_man = is_rep & (done_stage == 1)
     is_complete = active & (ev == kx + coff + roff)
     is_timer = active & (ev == kx + coff + roff + 1)
     # checkpoint-write event: the last residual column (after the
@@ -1107,21 +1113,23 @@ def _step_u(s: Dict[str, jnp.ndarray], u: jnp.ndarray, pv: jnp.ndarray,
     # overflow overwrites the oldest record; the overwrite count surfaces
     # downstream as the run_duration_truncated stat, and per-replica
     # means stay exact via sum(records) = useful + lost - cur_run.
-    record = is_fail | is_complete
-    if scen is not None:
-        # a shock gutting the running set ends the in-flight compute
-        # interval exactly like a failure would — including when it
-        # lands mid-checkpoint-write (the compute interval is still the
-        # one the interrupted write belongs to)
-        record = record | (sh_affects & (computing | in_ckpt_flag))
-    run_val = s["cur_run"] + progress
-    max_runs = s["run_durations"].shape[1]
-    if max_runs:    # static shape: max_runs=0 compiles the buffer out
-        hit = row_hit(jnp.mod(s["n_runs"], max_runs), max_runs)
-        ns["run_durations"] = jnp.where(hit & record[:, None],
-                                        run_val[:, None], s["run_durations"])
-    ns["n_runs"] = s["n_runs"] + record.astype(jnp.int32)
-    ns["cur_run"] = jnp.where(record, 0.0, run_val)
+    with jax.named_scope(tracing.RING):
+        record = is_fail | is_complete
+        if scen is not None:
+            # a shock gutting the running set ends the in-flight compute
+            # interval exactly like a failure would — including when it
+            # lands mid-checkpoint-write (the compute interval is still the
+            # one the interrupted write belongs to)
+            record = record | (sh_affects & (computing | in_ckpt_flag))
+        run_val = s["cur_run"] + progress
+        max_runs = s["run_durations"].shape[1]
+        if max_runs:    # static shape: max_runs=0 compiles the buffer out
+            hit = row_hit(jnp.mod(s["n_runs"], max_runs), max_runs)
+            ns["run_durations"] = jnp.where(hit & record[:, None],
+                                            run_val[:, None],
+                                            s["run_durations"])
+        ns["n_runs"] = s["n_runs"] + record.astype(jnp.int32)
+        ns["cur_run"] = jnp.where(record, 0.0, run_val)
 
     # ---- phase age (hazard clock) ---------------------------------------
     # advances only through COMPUTE time (phantoms included) and resets
@@ -1296,53 +1304,55 @@ def _step_u(s: Dict[str, jnp.ndarray], u: jnp.ndarray, pv: jnp.ndarray,
     # RepairShop samples them — through the shared HazardSampler
     # machinery.  Entry and completion are mutually exclusive in one
     # step (single event), so one duration lane (u_dur) serves both.
-    if rkind != "exponential":
-        rsampler = hazards.REPAIR_SAMPLERS[rkind]
-        adt = rep_rem.dtype
-        rem = jnp.where(active[:, None], rep_rem - dt.astype(adt)[:, None],
-                        rep_rem)
-        # completion (won_slot) and entry (first free slot) are mutually
-        # exclusive per step — a single event ended it — so one masked
-        # write per slot array covers both
-        free = jnp.isinf(rem)
-        any_free = free.any(-1)
-        fslot = jnp.argmax(free, axis=-1)
-        entered = diagnosed & any_free
-        rm_cls = jnp.where(wrong, picks[:, 0], cls).astype(jnp.int32)
-        # entry and escalation are mutually exclusive, so one quantile
-        # evaluation with the stage-selected scale column serves both
-        # (a second ndtri/pow per step is pure waste in the hot scan)
-        if rkind == "empirical":
-            # stage-select whole (edges, rates) blocks, then one
-            # segment-inversion quantile; broadcast shared rows to the
-            # batch so jnp.where can mix stages per replica
-            B = run.shape[0]
+    with jax.named_scope(tracing.REPAIR_LANE):
+        if rkind != "exponential":
+            rsampler = hazards.REPAIR_SAMPLERS[rkind]
+            adt = rep_rem.dtype
+            rem = jnp.where(active[:, None],
+                            rep_rem - dt.astype(adt)[:, None], rep_rem)
+            # completion (won_slot) and entry (first free slot) are mutually
+            # exclusive per step — a single event ended it — so one masked
+            # write per slot array covers both
+            free = jnp.isinf(rem)
+            any_free = free.any(-1)
+            fslot = jnp.argmax(free, axis=-1)
+            entered = diagnosed & any_free
+            rm_cls = jnp.where(wrong, picks[:, 0], cls).astype(jnp.int32)
+            # entry and escalation are mutually exclusive, so one quantile
+            # evaluation with the stage-selected scale column serves both
+            # (a second ndtri/pow per step is pure waste in the hot scan)
+            if rkind == "empirical":
+                # stage-select whole (edges, rates) blocks, then one
+                # segment-inversion quantile; broadcast shared rows to the
+                # batch so jnp.where can mix stages per replica
+                B = run.shape[0]
 
-            def _brow(x):
-                return x if x.ndim == 2 else jnp.broadcast_to(
-                    x, (B,) + x.shape)
+                def _brow(x):
+                    return x if x.ndim == 2 else jnp.broadcast_to(
+                        x, (B,) + x.shape)
 
-            esc2 = escalate[:, None]
-            q_dur = rsampler.quantile(
-                u_dur, jnp.where(esc2, _brow(r_me), _brow(r_ae)),
-                jnp.where(esc2, _brow(r_mr), _brow(r_ar))).astype(adt)
-        else:
-            q_dur = rsampler.quantile(
-                u_dur, jnp.where(escalate, rz[1], rz[0]), rz[2]).astype(adt)
-        hit = row_hit(jnp.where(is_rep, won_slot, fslot), rem.shape[1])
-        esc_h, ent_h = hit & escalate[:, None], hit & entered[:, None]
-        ns["repair_rem"] = jnp.where(
-            hit & finishes[:, None], jnp.inf,
-            jnp.where(esc_h | ent_h, q_dur[:, None], rem))
-        ns["repair_stage"] = jnp.where(
-            esc_h, 1, jnp.where(ent_h, 0, s["repair_stage"]))
-        ns["repair_cls"] = jnp.where(ent_h, rm_cls[:, None],
-                                     s["repair_cls"])
-        # a full lane: the incoming server stays in the shop forever
-        # (bookkeeping-consistent but wrong); surfaced as a metric and a
-        # RuntimeWarning downstream — raise Params.repair_slots
-        ns["n_repair_overflow"] = s["n_repair_overflow"] \
-            + (diagnosed & ~any_free).astype(jnp.float32)
+                esc2 = escalate[:, None]
+                q_dur = rsampler.quantile(
+                    u_dur, jnp.where(esc2, _brow(r_me), _brow(r_ae)),
+                    jnp.where(esc2, _brow(r_mr), _brow(r_ar))).astype(adt)
+            else:
+                q_dur = rsampler.quantile(
+                    u_dur, jnp.where(escalate, rz[1], rz[0]),
+                    rz[2]).astype(adt)
+            hit = row_hit(jnp.where(is_rep, won_slot, fslot), rem.shape[1])
+            esc_h, ent_h = hit & escalate[:, None], hit & entered[:, None]
+            ns["repair_rem"] = jnp.where(
+                hit & finishes[:, None], jnp.inf,
+                jnp.where(esc_h | ent_h, q_dur[:, None], rem))
+            ns["repair_stage"] = jnp.where(
+                esc_h, 1, jnp.where(ent_h, 0, s["repair_stage"]))
+            ns["repair_cls"] = jnp.where(ent_h, rm_cls[:, None],
+                                         s["repair_cls"])
+            # a full lane: the incoming server stays in the shop forever
+            # (bookkeeping-consistent but wrong); surfaced as a metric and a
+            # RuntimeWarning downstream — raise Params.repair_slots
+            ns["n_repair_overflow"] = s["n_repair_overflow"] \
+                + (diagnosed & ~any_free).astype(jnp.float32)
 
     # ---- streaming histograms -------------------------------------------
     # O(bins) distribution accumulators with no run-count bound (the ring
@@ -1353,40 +1363,42 @@ def _step_u(s: Dict[str, jnp.ndarray], u: jnp.ndarray, pv: jnp.ndarray,
     # failure records when the repaired server restarts the job, so the
     # stall interval is included — matching the event engine's
     # failure-to-restart timing.
-    if "hist" in s:
-        stall_wait = ns["t"] - s["stall_start"]
-        ended = resolves | unstall
-        downtime = jnp.where(resolves, fail_timer, stall_wait + recovery)
-        acquire_wait = jnp.where(resolves, fail_timer - recovery, stall_wait)
-        if scen is not None:
-            # a shock resolved through the waterfall records its planned
-            # downtime at the resolve instant, like a plain failure
-            ended = ended | sh_resolves
-            downtime = jnp.where(sh_resolves, shock_timer, downtime)
-            acquire_wait = jnp.where(sh_resolves, shock_timer - recovery,
-                                     acquire_wait)
-        # one fused bin search + masked add across the selected channels
-        # (static ``hist_channels``, HIST_CHANNELS order); unselected
-        # channels are compiled out entirely.  The search compares
-        # against every edge: the default binary search lowers to a
-        # gather loop on the TPU
-        channel_vals = {"run_duration": (run_val, record),
-                        "recovery": (downtime, ended),
-                        "waiting": (acquire_wait, ended),
-                        # one record per finished job: the realized
-                        # useful-work fraction of its wall clock (pair
-                        # with a (0.01, 1.0) bin range)
-                        "goodput": (ns["useful_work"]
-                                    / jnp.maximum(ns["t"], 1e-9),
-                                    is_complete)}
-        vals = jnp.stack([channel_vals[ch][0] for ch in hist_channels],
-                         axis=1)
-        masks = jnp.stack([channel_vals[ch][1] for ch in hist_channels],
-                          axis=1)                       # (B, n_sel)
-        idx = jnp.searchsorted(s["hist_edges"], vals, side="right",
-                               method="compare_all")
-        hit = row_hit(idx, s["hist"].shape[-1]) & masks[..., None]
-        ns["hist"] = s["hist"] + hit.astype(jnp.float32)
+    with jax.named_scope(tracing.HIST):
+        if "hist" in s:
+            stall_wait = ns["t"] - s["stall_start"]
+            ended = resolves | unstall
+            downtime = jnp.where(resolves, fail_timer, stall_wait + recovery)
+            acquire_wait = jnp.where(resolves, fail_timer - recovery,
+                                     stall_wait)
+            if scen is not None:
+                # a shock resolved through the waterfall records its planned
+                # downtime at the resolve instant, like a plain failure
+                ended = ended | sh_resolves
+                downtime = jnp.where(sh_resolves, shock_timer, downtime)
+                acquire_wait = jnp.where(sh_resolves, shock_timer - recovery,
+                                         acquire_wait)
+            # one fused bin search + masked add across the selected channels
+            # (static ``hist_channels``, HIST_CHANNELS order); unselected
+            # channels are compiled out entirely.  The search compares
+            # against every edge: the default binary search lowers to a
+            # gather loop on the TPU
+            channel_vals = {"run_duration": (run_val, record),
+                            "recovery": (downtime, ended),
+                            "waiting": (acquire_wait, ended),
+                            # one record per finished job: the realized
+                            # useful-work fraction of its wall clock (pair
+                            # with a (0.01, 1.0) bin range)
+                            "goodput": (ns["useful_work"]
+                                        / jnp.maximum(ns["t"], 1e-9),
+                                        is_complete)}
+            vals = jnp.stack([channel_vals[ch][0] for ch in hist_channels],
+                             axis=1)
+            masks = jnp.stack([channel_vals[ch][1] for ch in hist_channels],
+                              axis=1)                       # (B, n_sel)
+            idx = jnp.searchsorted(s["hist_edges"], vals, side="right",
+                                   method="compare_all")
+            hit = row_hit(idx, s["hist"].shape[-1]) & masks[..., None]
+            ns["hist"] = s["hist"] + hit.astype(jnp.float32)
     return ns
 
 
@@ -1483,57 +1495,73 @@ def _chunk_loop(pv: jnp.ndarray, key: jax.Array, P: int, R: int,
     same-seed-per-replication policy), and a bucket-padded run draws the
     identical stream for its real replica columns.
     """
-    R_draw = _next_pow2(R)
+    # every op of the program sits under the chunk scope (loop body,
+    # exit check, remainder chunk and the outputs' final fix-up)
+    with jax.named_scope(tracing.CHUNK):
+        R_draw = _next_pow2(R)
 
-    def scan_body(state, u):
-        if P > 1:
-            u = jnp.tile(u, (P, 1))
-        return _step_u(state, u, pv, impl, kind, rkind, hist_channels,
-                       scen, n_seg, n_rseg), None
+        def scan_body(state, u):
+            if P > 1:
+                with jax.named_scope(tracing.CRN_TILE):
+                    u = jnp.tile(u, (P, 1))
+            return _step_u(state, u, pv, impl, kind, rkind, hist_channels,
+                           scen, n_seg, n_rseg), None
 
-    def run_chunk(state, i, n_steps):
-        # one batched threefry call per chunk (a per-step split + draw is
-        # the dominant scan cost on CPU); the non-exponential hazard /
-        # repair families draw extra uniform lanes per step
-        us = jax.random.uniform(jax.random.fold_in(key, i),
-                                (n_steps, R_draw, _n_uniforms(kind, rkind)),
-                                dtype=jnp.float32, minval=1e-12, maxval=1.0)
-        if R_draw != R:
-            us = us[:, :R]
-        state, _ = jax.lax.scan(scan_body, state, us)
-        return state
+        def run_chunk(state, i, n_steps):
+            # one batched threefry call per chunk (a per-step split + draw is
+            # the dominant scan cost on CPU); the non-exponential hazard /
+            # repair families draw extra uniform lanes per step
+            with jax.named_scope(tracing.DRAW):
+                us = jax.random.uniform(
+                    jax.random.fold_in(key, i),
+                    (n_steps, R_draw, _n_uniforms(kind, rkind)),
+                    dtype=jnp.float32, minval=1e-12, maxval=1.0)
+                if R_draw != R:
+                    us = us[:, :R]
+            state, _ = jax.lax.scan(scan_body, state, us)
+            return state
 
-    def chunk_body(carry):
-        i, state = carry
-        return i + 1, run_chunk(state, i, chunk)
+        def chunk_body(carry):
+            i, active_rows, state = carry
+            # rows still running as the chunk starts (padding rows start
+            # DONE and never count)
+            active_rows += jnp.sum(state["phase"] != DONE, dtype=jnp.int32)
+            return i + 1, active_rows, run_chunk(state, i, chunk)
 
-    def cond(carry):
-        i, state = carry
-        not_done = i < n_chunks
-        if early_exit:
-            not_done &= jnp.any(state["phase"] != DONE)
-        return not_done
+        def cond(carry):
+            i, _, state = carry
+            not_done = i < n_chunks
+            if early_exit:
+                not_done &= jnp.any(state["phase"] != DONE)
+            return not_done
 
-    n_run, state = jax.lax.while_loop(cond, chunk_body,
-                                      (jnp.int32(0), init_state))
-    if rem:
-        # partial final chunk so an explicit max_steps is honored exactly.
-        # Finished replicas are inert, so under early_exit skipping the
-        # remainder once everything is DONE is bit-identical and free.
-        def do_rem(s):
-            return run_chunk(s, n_chunks, rem)
+        n_run, active_rows, state = jax.lax.while_loop(
+            cond, chunk_body, (jnp.int32(0), jnp.int32(0), init_state))
+        steps = n_run * chunk
+        if rem:
+            # partial final chunk so an explicit max_steps is honored exactly.
+            # Finished replicas are inert, so under early_exit skipping the
+            # remainder once everything is DONE is bit-identical and free.
+            def do_rem(s):
+                return run_chunk(s, n_chunks, rem)
 
-        if early_exit:
-            state = jax.lax.cond(jnp.any(state["phase"] != DONE),
-                                 do_rem, lambda s: s, state)
-        else:
-            state = do_rem(state)
-    state["completed"] = (state["phase"] == DONE).astype(jnp.float32)
-    state["total_time"] = jnp.where(state["phase"] == DONE,
-                                    state["total_time"], state["t"])
-    #: full chunks the early-exit loop executed (the remainder chunk
-    #: not counted): ``chunks_run * chunk`` steps ran for every row
-    state["chunks_run"] = n_run
+            if early_exit:
+                rem_runs = jnp.any(state["phase"] != DONE)
+                state = jax.lax.cond(rem_runs, do_rem, lambda s: s, state)
+                steps += jnp.where(rem_runs, rem, 0)
+            else:
+                state = do_rem(state)
+                steps += rem
+        state["completed"] = (state["phase"] == DONE).astype(jnp.float32)
+        state["total_time"] = jnp.where(state["phase"] == DONE,
+                                        state["total_time"], state["t"])
+        # the counters (tracing.COUNTERS), never part of the simulated
+        # outputs: full chunks the early-exit loop executed (the remainder
+        # chunk not counted), the sum over those chunks of the rows active
+        # as each started, and every step run, remainder included
+        state["chunks_run"] = n_run
+        state["active_row_chunks"] = active_rows
+        state["steps_run"] = steps
     return state
 
 
@@ -1581,7 +1609,8 @@ def _run_chunked_sharded(pv: jnp.ndarray, keys: jax.Array, P: int, R: int,
     (metric scalars, histogram accumulators, run-record ring buffers)
     is per-replica, so reassembling the replica axis recovers the exact
     flat ``(P*R, ...)`` layout.  Unbatched leaves (``hist_edges``) ride
-    along replicated.
+    along replicated; the counters (:data:`tracing.COUNTERS`) come back
+    as ``(n_shards,)`` arrays, one value per shard.
 
     With a 1-device mesh ``keys[0]`` is the unsplit base key and the
     body sees exactly the arguments :func:`_run_chunked` would, so the
@@ -1602,6 +1631,9 @@ def _run_chunked_sharded(pv: jnp.ndarray, keys: jax.Array, P: int, R: int,
     pv2 = pv.reshape((P, R, pv.shape[-1])) if pv_batched else pv
     pv_spec = rspec if pv_batched else PartitionSpec()
     out_specs = {k: rspec for k in list(state) + ["completed"]}
+    # the counters come back one per shard: shards exit independently
+    out_specs.update({k: PartitionSpec(rsharding.REPLICA_AXIS)
+                      for k in tracing.COUNTERS})
 
     def body(keys_s, pv_s, n_chunks_s, unbatched_s, state_s):
         flat = {k: v.reshape((P * R_loc,) + v.shape[2:])
@@ -1613,10 +1645,11 @@ def _run_chunked_sharded(pv: jnp.ndarray, keys: jax.Array, P: int, R: int,
                           keys_s[0], P, R_loc, chunk, n_chunks_s, rem,
                           impl, early_exit, kind, rkind, hist_channels,
                           scen, flat, n_seg, n_rseg)
-        for k in tuple(unbatched_s) + ("chunks_run",):
+        for k in unbatched_s:
             out.pop(k)
-        return {k: v.reshape((P, R_loc) + v.shape[1:])
-                for k, v in out.items()}
+        counters = {k: out.pop(k).reshape(1) for k in tracing.COUNTERS}
+        return {**{k: v.reshape((P, R_loc) + v.shape[1:])
+                   for k, v in out.items()}, **counters}
 
     out = jax.shard_map(
         body, mesh=mesh,
@@ -1626,7 +1659,8 @@ def _run_chunked_sharded(pv: jnp.ndarray, keys: jax.Array, P: int, R: int,
                   rsharding.replica_state_specs(state)),
         out_specs=out_specs, check_vma=False,
     )(keys, pv2, n_chunks, unbatched, state)
-    out = {k: v.reshape((P * R,) + v.shape[2:]) for k, v in out.items()}
+    out = {k: v if k in tracing.COUNTERS
+           else v.reshape((P * R,) + v.shape[2:]) for k, v in out.items()}
     out.update(unbatched)
     return out
 
@@ -1704,6 +1738,16 @@ _EXTRA_OUTPUTS = ("completed", "run_durations", "n_runs", "cur_run",
                   "domain_shocks")
 
 
+def _wait(run, args, kw):
+    """Run a compiled chunk loop until its outputs are ready; returns
+    the state and the counters fetched to the host, as span arguments
+    (:func:`tracing.counter_args`)."""
+    with tracing.span(tracing.WAIT):
+        out = jax.block_until_ready(run(*args, **kw))
+        counters = {k: np.asarray(out.pop(k)) for k in tracing.COUNTERS}
+    return out, tracing.counter_args(counters)
+
+
 def _extract(state, sl=slice(None), channels=()) -> Dict[str, np.ndarray]:
     out = {k: np.asarray(v[sl]) for k, v in state.items()
            if k in _METRICS + _EXTRA_OUTPUTS}
@@ -1754,30 +1798,35 @@ def simulate_ctmc(params: Params, n_replicas: int = 1024, seed: int = 0,
     ``params.event_race_impl``) selects the event-race kernel backend.
     See docs/scaling.md for both knobs.
     """
-    if not supports(params):
-        raise _unsupported_error(params)
-    params.validate()
-    impl = params.event_race_impl if impl is None else impl
-    shards = _resolve_shards(shards, [params])
-    max_steps = max_steps or default_max_steps(params)
-    chunk = min(chunk_steps or DEFAULT_CHUNK_STEPS, max_steps)
-    init_state = _initial_state(params, n_replicas, max_runs)
-    channels = _hist_channels([params])
-    args = (1, n_replicas, chunk, jnp.int32(max_steps // chunk),
-            max_steps % chunk, impl, early_exit,
-            _struct_key(params), hazards.hazard_kind(params),
-            hazards.repair_kind(params), channels,
-            faultdomains.scenario_key(params), init_state,
-            hazards.hazard_segment_count(params),
-            hazards.repair_segment_count(params))
-    pv, key = _params_vector(params), jax.random.PRNGKey(seed)
-    if shards:
-        out = _run_chunked_sharded(pv, rsharding.shard_keys(key, shards),
-                                   *args, mesh=_shard_mesh(shards,
-                                                           n_replicas))
-    else:
-        out = _run_chunked(pv, key, *args)
-    return _extract(out, channels=channels)
+    with tracing.span(tracing.PREPARE, rows=n_replicas,
+                      real_rows=n_replicas):
+        if not supports(params):
+            raise _unsupported_error(params)
+        params.validate()
+        impl = params.event_race_impl if impl is None else impl
+        shards = _resolve_shards(shards, [params])
+        max_steps = max_steps or default_max_steps(params)
+        chunk = min(chunk_steps or DEFAULT_CHUNK_STEPS, max_steps)
+        init_state = _initial_state(params, n_replicas, max_runs)
+        channels = _hist_channels([params])
+        args = (1, n_replicas, chunk, jnp.int32(max_steps // chunk),
+                max_steps % chunk, impl, early_exit,
+                _struct_key(params), hazards.hazard_kind(params),
+                hazards.repair_kind(params), channels,
+                faultdomains.scenario_key(params), init_state,
+                hazards.hazard_segment_count(params),
+                hazards.repair_segment_count(params))
+        pv, key = _params_vector(params), jax.random.PRNGKey(seed)
+        if shards:
+            run, args, kw = (_run_chunked_sharded,
+                             (pv, rsharding.shard_keys(key, shards)) + args,
+                             {"mesh": _shard_mesh(shards, n_replicas)})
+        else:
+            run, args, kw = _run_chunked, (pv, key) + args, {}
+    out, counters = _wait(run, args, kw)
+    with tracing.span(tracing.TRANSFER, chunk=chunk, real_rows=n_replicas,
+                      **counters):
+        return _extract(out, channels=channels)
 
 
 def simulate_ctmc_sweep(params_list, n_replicas: int = 1024, seed=0,
@@ -1845,16 +1894,24 @@ def simulate_ctmc_sweep(params_list, n_replicas: int = 1024, seed=0,
     """
     params_list = list(params_list)
     results: list = [None] * len(params_list)
-    for idxs, R_run, run, args, kw in sweep_programs(
+    with tracing.span(tracing.PREPARE) as prepare:
+        programs = sweep_programs(
             params_list, n_replicas, seed, max_steps, impl, chunk_steps,
-            early_exit, padded, bucketed, max_runs, shards):
-        out = run(*args, **kw)
+            early_exit, padded, bucketed, max_runs, shards)
+        prepare.set_metadata(
+            rows=sum(args[0].shape[0] for _, _, _, args, _ in programs),
+            real_rows=len(params_list) * n_replicas)
+    for idxs, R_run, run, args, kw in programs:
+        out, counters = _wait(run, args, kw)
         channels = _hist_channels(params_list)   # params_list is non-empty
-        for j, i in enumerate(idxs):
-            rows = (slice(j * R_run, j * R_run + n_replicas)
-                    if R_run == n_replicas
-                    else np.arange(n_replicas) + j * R_run)
-            results[i] = _extract(out, rows, channels)
+        # args: (pv, key, P, R, chunk, ...)
+        with tracing.span(tracing.TRANSFER, chunk=args[4],
+                          real_rows=len(idxs) * n_replicas, **counters):
+            for j, i in enumerate(idxs):
+                rows = (slice(j * R_run, j * R_run + n_replicas)
+                        if R_run == n_replicas
+                        else np.arange(n_replicas) + j * R_run)
+                results[i] = _extract(out, rows, channels)
     return results
 
 
