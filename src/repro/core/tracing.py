@@ -22,8 +22,10 @@ until a trace is taken.  Run any CTMC study under ``jax.profiler.trace``
   metadata, which the profiler reports as each op's ``tf_op`` path.
 * program counters (:data:`COUNTERS`), returned beside the state by the
   chunk loop and attached to ``aires.transfer``: full chunks run, the
-  sum over those chunks of the rows still active when each started, and
-  the steps run.  They never enter the simulated outputs.
+  sum over those chunks of the rows still active when each started, the
+  steps run, and the histogram adds (one per chunk the loop ran,
+  remainder included; ``steps_run / hist_flushes`` steps share an add).
+  They never enter the simulated outputs.
 """
 
 from __future__ import annotations
@@ -43,7 +45,8 @@ NAMES = ("aires.study", "aires.prepare", "aires.wait", "aires.transfer",
 
 #: scalars the chunk loop returns beside the state (one per shard on the
 #: sharded path)
-COUNTERS = ("chunks_run", "active_row_chunks", "steps_run")
+COUNTERS = ("chunks_run", "active_row_chunks", "steps_run",
+            "hist_flushes")
 
 _numbers = itertools.count()
 _current: contextvars.ContextVar = contextvars.ContextVar(
@@ -74,9 +77,10 @@ def span(name: str, **args) -> jax.profiler.TraceAnnotation:
 
 def counter_args(counters) -> dict:
     """Span arguments from the fetched counters: one value per counter,
-    the largest over shards (steps and chunks, which bound the program's
-    time) or their sum (active row-chunks); a sharded run adds the
-    per-shard values as one string, ``name:v0/v1/...`` per counter."""
+    the largest over shards (steps, chunks and histogram adds, which
+    bound the program's time) or their sum (active row-chunks); a
+    sharded run adds the per-shard values as one string,
+    ``name:v0/v1/...`` per counter."""
     shards = {k: [int(x) for x in v.reshape(-1)] for k, v in counters.items()}
     args = {k: (sum(v) if k == "active_row_chunks" else max(v))
             for k, v in shards.items()}

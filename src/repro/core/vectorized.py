@@ -41,7 +41,10 @@ run-count bound, so distribution percentiles survive multi-year horizons
 where the ring buffer truncates.  The bin layout matches the pure-numpy
 reference accumulator in :mod:`repro.core.histograms` (left-closed /
 right-open, under/overflow slots), so both engines emit comparable
-distributions; ``histogram=None`` compiles the accumulator out.
+distributions; ``histogram=None`` compiles the accumulator out.  Each
+step only records its bins; the chunk loop adds a chunk's records to
+the histogram once, after its scan (the counts are exact integers, so
+this equals adding every step).
 
 Checkpoint rollback + write cost: when ``Params.checkpoint_interval``
 is positive, the scan tracks work-since-last-checkpoint in a dedicated
@@ -154,7 +157,7 @@ domains / campaigns combined with non-exponential repairs.
 from __future__ import annotations
 
 import math
-from functools import partial
+from functools import partial, reduce
 from typing import Dict, Optional
 
 import jax
@@ -558,6 +561,58 @@ def row_hit(col: jnp.ndarray, width: int) -> jnp.ndarray:
     return jnp.arange(width) == col[..., None]
 
 
+def _bin_bits(n_counts: int) -> int:
+    """Bits of one channel's slot in a packed histogram record: the
+    narrowest of 8 / 16 / 32 that holds the sentinel ``n_counts``."""
+    return next(b for b in (8, 16, 32) if n_counts < 2 ** b)
+
+
+def _hist_bins(edges: jnp.ndarray, vals: jnp.ndarray,
+               masks: jnp.ndarray) -> jnp.ndarray:
+    """One step's histogram record: ``(C, B)`` values and masks ->
+    ``(n_words, B)`` uint32.
+
+    Each channel's bin is ``searchsorted(edges, val, side="right")``
+    (compared against every edge: the default binary search lowers to a
+    gather loop on the TPU); a masked channel records the sentinel
+    ``n_counts``, which matches no bin.  ``32 // _bin_bits(n_counts)``
+    channels share a word, so the rows stay on the lane axis: a
+    channel-minor record would pad the channels to a full lane tile.
+    """
+    n_counts = edges.shape[0] + 1
+    bits = _bin_bits(n_counts)
+    per = 32 // bits
+    idx = jnp.searchsorted(edges, vals, side="right", method="compare_all")
+    idx = jnp.where(masks, idx, n_counts).astype(jnp.uint32)
+    return jnp.stack([
+        reduce(jnp.bitwise_or, [
+            idx[c] << (bits * (c - lo))
+            for c in range(lo, min(lo + per, idx.shape[0]))])
+        for lo in range(0, idx.shape[0], per)])
+
+
+def _hist_flush(hist: jnp.ndarray, words) -> jnp.ndarray:
+    """``hist`` (B, C, n_counts) plus the counts of K steps' records:
+    ``words[w]`` is word ``w`` of :func:`_hist_bins` stacked over the
+    steps, (K, B).
+
+    Counts in int32 and converts once; the counts are integers below
+    2**24, so the float32 sum equals adding the steps one at a time, in
+    any order.
+    """
+    n_counts = hist.shape[-1]
+    bits = _bin_bits(n_counts)
+    per = 32 // bits
+    idx = jnp.stack([(words[c // per] >> (bits * (c % per)))
+                     & (2 ** bits - 1)
+                     for c in range(hist.shape[1])],
+                    axis=1).astype(jnp.int32)                  # (K, C, B)
+    # (K, C, n_counts, B): the rows stay on the lane axis
+    hit = jnp.arange(n_counts)[:, None] == idx[:, :, None, :]
+    counts = jnp.sum(hit, axis=0, dtype=jnp.int32)
+    return hist + jnp.moveaxis(counts, -1, 0).astype(jnp.float32)
+
+
 # ---------------------------------------------------------------------------
 # one transition
 # ---------------------------------------------------------------------------
@@ -607,7 +662,9 @@ def _step_u(s: Dict[str, jnp.ndarray], u: jnp.ndarray, pv: jnp.ndarray,
     from different log slices shares one compiled program.
 
     ``hist_channels`` is the static tuple of histogram channels the scan
-    state carries (must match ``s["hist"].shape[1]``).
+    state carries (must match ``s["hist"].shape[1]``).  A state with
+    ``hist`` gets the step's counts added; one with ``hist_bins`` instead
+    (the chunk loop's) gets the step's record there, for the loop to add.
 
     ``scen`` is the static scenario key ``(D, codes)`` — when set, 2D +
     3L trailing scenario columns follow the repair columns (see
@@ -1364,7 +1421,7 @@ def _step_u(s: Dict[str, jnp.ndarray], u: jnp.ndarray, pv: jnp.ndarray,
     # stall interval is included — matching the event engine's
     # failure-to-restart timing.
     with jax.named_scope(tracing.HIST):
-        if "hist" in s:
+        if "hist" in s or "hist_bins" in s:
             stall_wait = ns["t"] - s["stall_start"]
             ended = resolves | unstall
             downtime = jnp.where(resolves, fail_timer, stall_wait + recovery)
@@ -1377,11 +1434,9 @@ def _step_u(s: Dict[str, jnp.ndarray], u: jnp.ndarray, pv: jnp.ndarray,
                 downtime = jnp.where(sh_resolves, shock_timer, downtime)
                 acquire_wait = jnp.where(sh_resolves, shock_timer - recovery,
                                          acquire_wait)
-            # one fused bin search + masked add across the selected channels
-            # (static ``hist_channels``, HIST_CHANNELS order); unselected
-            # channels are compiled out entirely.  The search compares
-            # against every edge: the default binary search lowers to a
-            # gather loop on the TPU
+            # one fused bin search across the selected channels (static
+            # ``hist_channels``, HIST_CHANNELS order); unselected channels
+            # are compiled out entirely
             channel_vals = {"run_duration": (run_val, record),
                             "recovery": (downtime, ended),
                             "waiting": (acquire_wait, ended),
@@ -1391,14 +1446,13 @@ def _step_u(s: Dict[str, jnp.ndarray], u: jnp.ndarray, pv: jnp.ndarray,
                             "goodput": (ns["useful_work"]
                                         / jnp.maximum(ns["t"], 1e-9),
                                         is_complete)}
-            vals = jnp.stack([channel_vals[ch][0] for ch in hist_channels],
-                             axis=1)
-            masks = jnp.stack([channel_vals[ch][1] for ch in hist_channels],
-                              axis=1)                       # (B, n_sel)
-            idx = jnp.searchsorted(s["hist_edges"], vals, side="right",
-                                   method="compare_all")
-            hit = row_hit(idx, s["hist"].shape[-1]) & masks[..., None]
-            ns["hist"] = s["hist"] + hit.astype(jnp.float32)
+            vals = jnp.stack([channel_vals[ch][0] for ch in hist_channels])
+            masks = jnp.stack([channel_vals[ch][1] for ch in hist_channels])
+            bins = _hist_bins(s["hist_edges"], vals, masks)
+            if "hist" in s:
+                ns["hist"] = _hist_flush(s["hist"], bins[:, None])
+            else:
+                ns["hist_bins"] = bins
     return ns
 
 
@@ -1500,14 +1554,27 @@ def _chunk_loop(pv: jnp.ndarray, key: jax.Array, P: int, R: int,
     with jax.named_scope(tracing.CHUNK):
         R_draw = _next_pow2(R)
 
+        # the histogram leaves the scan: each step records its bins
+        # (``hist_bins``, starting all sentinel), the scan stacks the
+        # records, and one add per chunk folds them into ``hist``, which
+        # rides in the chunk loop's carry
+        state = dict(init_state)
+        hist = state.pop("hist", None)
+        has_hist = hist is not None
+        if has_hist:
+            cb = (hist.shape[1], hist.shape[0])
+            state["hist_bins"] = _hist_bins(
+                state["hist_edges"], jnp.zeros(cb), jnp.zeros(cb, bool))
+
         def scan_body(state, u):
             if P > 1:
                 with jax.named_scope(tracing.CRN_TILE):
                     u = jnp.tile(u, (P, 1))
-            return _step_u(state, u, pv, impl, kind, rkind, hist_channels,
-                           scen, n_seg, n_rseg), None
+            state = _step_u(state, u, pv, impl, kind, rkind, hist_channels,
+                            scen, n_seg, n_rseg)
+            return state, tuple(state["hist_bins"]) if has_hist else None
 
-        def run_chunk(state, i, n_steps):
+        def run_chunk(flushes, hist, state, i, n_steps):
             # one batched threefry call per chunk (a per-step split + draw is
             # the dominant scan cost on CPU); the non-exponential hazard /
             # repair families draw extra uniform lanes per step
@@ -1518,50 +1585,64 @@ def _chunk_loop(pv: jnp.ndarray, key: jax.Array, P: int, R: int,
                     dtype=jnp.float32, minval=1e-12, maxval=1.0)
                 if R_draw != R:
                     us = us[:, :R]
-            state, _ = jax.lax.scan(scan_body, state, us)
-            return state
+            state, words = jax.lax.scan(scan_body, state, us)
+            if has_hist:
+                with jax.named_scope(tracing.HIST):
+                    hist = _hist_flush(hist, words)
+                flushes += 1
+            return flushes, hist, state
 
         def chunk_body(carry):
-            i, active_rows, state = carry
+            i, active_rows, flushes, hist, state = carry
             # rows still running as the chunk starts (padding rows start
             # DONE and never count)
             active_rows += jnp.sum(state["phase"] != DONE, dtype=jnp.int32)
-            return i + 1, active_rows, run_chunk(state, i, chunk)
+            return (i + 1, active_rows,
+                    *run_chunk(flushes, hist, state, i, chunk))
 
         def cond(carry):
-            i, _, state = carry
+            i, *_, state = carry
             not_done = i < n_chunks
             if early_exit:
                 not_done &= jnp.any(state["phase"] != DONE)
             return not_done
 
-        n_run, active_rows, state = jax.lax.while_loop(
-            cond, chunk_body, (jnp.int32(0), jnp.int32(0), init_state))
+        n_run, active_rows, flushes, hist, state = jax.lax.while_loop(
+            cond, chunk_body,
+            (jnp.int32(0), jnp.int32(0), jnp.int32(0), hist, state))
         steps = n_run * chunk
         if rem:
             # partial final chunk so an explicit max_steps is honored exactly.
             # Finished replicas are inert, so under early_exit skipping the
             # remainder once everything is DONE is bit-identical and free.
-            def do_rem(s):
-                return run_chunk(s, n_chunks, rem)
+            def do_rem(*carry):
+                return run_chunk(*carry, n_chunks, rem)
 
+            carry = (flushes, hist, state)
             if early_exit:
                 rem_runs = jnp.any(state["phase"] != DONE)
-                state = jax.lax.cond(rem_runs, do_rem, lambda s: s, state)
+                carry = jax.lax.cond(rem_runs, do_rem, lambda *c: c, *carry)
                 steps += jnp.where(rem_runs, rem, 0)
             else:
-                state = do_rem(state)
+                carry = do_rem(*carry)
                 steps += rem
+            flushes, hist, state = carry
+        if has_hist:
+            state.pop("hist_bins")
+            state["hist"] = hist
         state["completed"] = (state["phase"] == DONE).astype(jnp.float32)
         state["total_time"] = jnp.where(state["phase"] == DONE,
                                         state["total_time"], state["t"])
         # the counters (tracing.COUNTERS), never part of the simulated
         # outputs: full chunks the early-exit loop executed (the remainder
         # chunk not counted), the sum over those chunks of the rows active
-        # as each started, and every step run, remainder included
+        # as each started, every step run, remainder included, and the
+        # histogram adds (one per chunk run, remainder included; 0 without
+        # a histogram)
         state["chunks_run"] = n_run
         state["active_row_chunks"] = active_rows
         state["steps_run"] = steps
+        state["hist_flushes"] = flushes
     return state
 
 

@@ -64,7 +64,7 @@ def test_names_are_one_prefixed_tuple():
     assert len(set(tracing.NAMES)) == len(tracing.NAMES) == 12
     assert all(n.startswith("aires.") for n in tracing.NAMES)
     assert tracing.COUNTERS == ("chunks_run", "active_row_chunks",
-                                "steps_run")
+                                "steps_run", "hist_flushes")
 
 
 def test_run_replications_records_each_span_once(tmp_path):
@@ -82,6 +82,7 @@ def test_run_replications_records_each_span_once(tmp_path):
     assert tr["real_rows"] == 16 and tr["chunk"] == vz.DEFAULT_CHUNK_STEPS
     assert tr["steps_run"] >= tr["chunks_run"] * tr["chunk"] > 0
     assert 0 < tr["active_row_chunks"] <= tr["chunks_run"] * 16
+    assert tr["steps_run"] // tr["hist_flushes"] == tr["chunk"]
     assert "per_shard" not in tr
     # the spans follow one another in the study's order
     order = [n for n, _, _, _ in spans]
@@ -174,6 +175,29 @@ def test_steps_run_includes_a_remainder_that_ran():
     assert c["steps_run"] == vz.DEFAULT_CHUNK_STEPS + 9
 
 
+@pytest.mark.parametrize("max_steps,early_exit,p", [
+    (150, False, BASE),                     # remainder runs
+    (128, False, BASE),                     # no remainder
+    (20 * vz.DEFAULT_CHUNK_STEPS + 9, True, BASE),  # remainder skipped
+    (vz.DEFAULT_CHUNK_STEPS + 9, True,      # remainder runs under early exit
+     BASE.replace(job_length=64 * 1440.0)),
+], ids=["remainder", "whole_chunks", "early_exit", "early_exit_remainder"])
+def test_hist_flushes_count_chunks_and_a_remainder_that_ran(max_steps,
+                                                            early_exit, p):
+    c, _ = _counters(max_steps, early_exit, p=p)
+    rem_ran = c["steps_run"] > c["chunks_run"] * vz.DEFAULT_CHUNK_STEPS
+    assert c["hist_flushes"] == c["chunks_run"] + rem_ran
+    assert rem_ran == (max_steps % vz.DEFAULT_CHUNK_STEPS > 0
+                       and c["chunks_run"] == max_steps
+                       // vz.DEFAULT_CHUNK_STEPS)
+
+
+def test_no_histogram_no_flushes():
+    c, out = _counters(150, early_exit=False, p=BASE.replace(histogram=None))
+    assert c["hist_flushes"] == 0 and c["steps_run"] == 150
+    assert "hist" not in out and "hist_bins" not in out
+
+
 @pytest.mark.parametrize("early_exit", [True, False])
 def test_active_row_share_is_a_share(early_exit):
     c, _ = _counters(12 * vz.DEFAULT_CHUNK_STEPS, early_exit, R=16)
@@ -228,9 +252,15 @@ SHARDED = textwrap.dedent("""
     [(_, _, run, args, kw)] = vz.sweep_programs(grid, 32, seed=7, shards=4)
     out = run(*args, **kw)
     shards = {k: out[k].tolist() for k in tracing.COUNTERS}
-    print(json.dumps({"shards": shards,
-                      "args": tracing.counter_args(
-                          {k: out[k] for k in tracing.COUNTERS})}))
+    span_args = tracing.counter_args({k: out[k] for k in tracing.COUNTERS})
+    # an explicit budget keeps its remainder chunk; no early exit runs it
+    [(_, _, run, args, kw)] = vz.sweep_programs(
+        grid, 32, seed=7, shards=4, max_steps=2 * vz.DEFAULT_CHUNK_STEPS + 9,
+        early_exit=False)
+    out = run(*args, **kw)
+    assert "hist_bins" not in out
+    rem = {k: out[k].tolist() for k in tracing.COUNTERS}
+    print(json.dumps({"shards": shards, "rem": rem, "args": span_args}))
 """)
 
 
@@ -247,13 +277,20 @@ def test_sharded_counters_survive_one_per_shard():
     shards, args = got["shards"], got["args"]
     chunk = vz.DEFAULT_CHUNK_STEPS
     assert all(len(v) == 4 for v in shards.values())
-    for c, a, s in zip(shards["chunks_run"], shards["active_row_chunks"],
-                       shards["steps_run"]):
+    for c, a, s, h in zip(shards["chunks_run"], shards["active_row_chunks"],
+                          shards["steps_run"], shards["hist_flushes"]):
         assert s == c * chunk
         # each shard carries 2 points x 8 replica columns
         assert 0 < a <= c * 16
+        # the default budget is whole chunks: one flush per chunk
+        assert h == c
+    rem = got["rem"]
+    assert rem["chunks_run"] == [2] * 4
+    assert rem["steps_run"] == [2 * chunk + 9] * 4
+    assert rem["hist_flushes"] == [3] * 4
     assert args["steps_run"] == max(shards["steps_run"])
     assert args["chunks_run"] == max(shards["chunks_run"])
+    assert args["hist_flushes"] == max(shards["hist_flushes"])
     assert args["active_row_chunks"] == sum(shards["active_row_chunks"])
     assert args["per_shard"] == " ".join(
         f"{k}:" + "/".join(map(str, shards[k])) for k in tracing.COUNTERS)
