@@ -118,12 +118,22 @@ class FaultTopology:
         return rack // self.racks_per_pod
 
     def domain_members(self, domain: int, total: int) -> List[int]:
-        """Server ids (workers + spares) belonging to ``domain``."""
+        """Server ids (workers + spares) belonging to ``domain``, in
+        ascending order: rack ``r`` holds ``r, r + n_racks, ...``, and a
+        pod the servers of its ``racks_per_pod`` racks in a row (fewer
+        in the last pod when ``racks_per_pod`` does not divide
+        ``n_racks``)."""
         if domain < self.n_racks:
-            return [s for s in range(total) if s % self.n_racks == domain]
-        pod = domain - self.n_racks
-        return [s for s in range(total)
-                if (s % self.n_racks) // self.racks_per_pod == pod]
+            return list(range(domain, total, self.n_racks))
+        lo = (domain - self.n_racks) * self.racks_per_pod
+        hi = min(lo + self.racks_per_pod, self.n_racks)
+        return [s for base in range(0, total, self.n_racks)
+                for s in range(base + lo, min(base + hi, total))]
+
+    def domain_size(self, domain: int, total: int) -> int:
+        """``len(domain_members(domain, total))``, in closed form."""
+        return int(domain_sizes(domain, total, self.n_racks,
+                                self.racks_per_pod))
 
     def domain_rates(self) -> np.ndarray:
         """Per-domain shock rates, racks first then pods — shape (D,)."""
@@ -140,9 +150,34 @@ class FaultTopology:
         striping is uniform, so the per-domain fraction is the exact
         expectation of the event engine's member count in every pool.
         """
-        sizes = np.array([len(self.domain_members(d, total))
-                          for d in range(self.n_domains)], np.float64)
-        return sizes / max(total, 1)
+        sizes = domain_sizes(np.arange(self.n_domains), total,
+                             self.n_racks, self.racks_per_pod)
+        return sizes.astype(np.float64) / max(total, 1)
+
+
+def domain_sizes(domain, total, n_racks: int, racks_per_pod, xp=np):
+    """Servers in each ``domain`` index of a fleet of ``total`` under
+    round-robin striping (``rack = sid % n_racks``), in closed form.
+
+    With ``q, rem = divmod(total, n_racks)`` rack ``r`` holds
+    ``q + (r < rem)`` servers; pod ``p`` holds its ``nr`` racks from
+    ``lo = p * racks_per_pod``, that is ``nr * q`` servers plus those of
+    its racks below ``rem``.  ``xp`` is ``numpy`` on the host or
+    ``jax.numpy`` inside the compiled step, where ``domain``, ``total``
+    and ``racks_per_pod`` are integer arrays and ``n_racks`` is static.
+
+    >>> t = FaultTopology(n_racks=5, racks_per_pod=2)
+    >>> domain_sizes(np.arange(t.n_domains), 12, 5, 2).tolist()
+    [3, 3, 2, 2, 2, 6, 4, 2]
+    >>> [len(t.domain_members(d, 12)) for d in range(t.n_domains)]
+    [3, 3, 2, 2, 2, 6, 4, 2]
+    """
+    q, rem = total // n_racks, total % n_racks
+    rack = q + (domain < rem)
+    lo = (domain - n_racks) * racks_per_pod
+    nr = xp.minimum(racks_per_pod, n_racks - lo)
+    pod = nr * q + xp.clip(rem - lo, 0, nr)
+    return xp.where(domain < n_racks, rack, pod)
 
 
 # ---------------------------------------------------------------------------
@@ -225,48 +260,51 @@ class Campaign:
 # CTMC fast-path builders
 # ---------------------------------------------------------------------------
 # The scan treats the scenario as (static structure, traced numbers):
-# the *shape* — number of domains D and the tuple of schedule codes — is
-# a static compile key, while every rate, fraction, time, and target
-# domain rides in trailing params-vector columns.  A shock-rate grid or
-# a campaign-timing grid therefore shares one compiled program.
+# the *shape* — the rack and pod counts and the tuple of schedule codes
+# — is a static compile key, while every rate, the fleet size, and each
+# campaign entry's time, fraction and target domain ride in trailing
+# params-vector columns.  A shock-rate grid or a campaign-timing grid
+# therefore shares one compiled program.  The race holds one lane per
+# level (racks, pods), not one per domain: every domain of a level has
+# the same rate, so the struck domain is uniform within its level.
 
-def scenario_key(p) -> Optional[Tuple[int, Tuple[int, ...]]]:
-    """Static compile key ``(D, codes)`` — or None when no scenario."""
+def scenario_key(p) -> Optional[Tuple[int, int, Tuple[int, ...]]]:
+    """Static compile key ``(n_racks, n_pods, codes)`` — or None when
+    no scenario.  A campaign without a topology has ``n_racks == 0``."""
     if p.fault_domains is None and p.campaign is None:
         return None
-    d = p.fault_domains.n_domains if p.fault_domains is not None else 0
+    topo = p.fault_domains
     codes = tuple(code for _, code, _ in p.campaign.schedule()) \
         if p.campaign is not None else ()
-    return (d, codes)
+    if topo is None:
+        return (0, 0, codes)
+    return (topo.n_racks, topo.n_pods, codes)
 
 
 def scenario_columns(p) -> np.ndarray:
     """Traced trailing params-vector columns for the scenario.
 
-    Layout: ``[rates (D), fractions (D), times (L), fracs (L),
-    domains (L)]`` where L is the flattened schedule length.  Kill
-    entries carry the struck domain's fleet fraction; maintenance
-    entries carry zeros.
+    Layout: ``[fleet, racks_per_pod, rack_rate, pod_rate]`` when a
+    topology is set, then ``[times (L), fracs (L), domains (L)]`` where
+    L is the flattened schedule length.  Kill entries carry the struck
+    domain's fleet fraction; maintenance entries carry zeros.
     """
     topo, camp = p.fault_domains, p.campaign
     total = p.working_pool_size + p.spare_pool_size
+    cols: List[float] = []
     if topo is not None:
-        rates = topo.domain_rates()
-        fracs = topo.domain_fractions(total)
-    else:
-        rates = fracs = np.zeros(0, np.float64)
+        cols += [total, topo.racks_per_pod, topo.rack_shock_rate,
+                 topo.pod_shock_rate]
     times: List[float] = []
     efracs: List[float] = []
     edoms: List[float] = []
     if camp is not None:
         for t, code, dom in camp.schedule():
             times.append(t)
-            efracs.append(float(fracs[dom]) if code == KILL else 0.0)
+            efracs.append(topo.domain_size(dom, total) / max(total, 1)
+                          if code == KILL else 0.0)
             edoms.append(float(dom))
-    return np.concatenate([rates, fracs,
-                           np.asarray(times, np.float64),
-                           np.asarray(efracs, np.float64),
-                           np.asarray(edoms, np.float64)])
+    return np.asarray(cols + times + efracs + edoms, np.float64)
 
 
 def scenario_budget(p, horizon: float) -> Tuple[float, float]:
@@ -274,21 +312,24 @@ def scenario_budget(p, horizon: float) -> Tuple[float, float]:
 
     Each shock consumes one scan step plus the repair traffic of the
     block it kills (~4 steps per killed server: auto completion,
-    escalation, manual completion, return/unstall).  Maintenance
-    windows stretch the horizon by their duration (repairs pause) and
-    campaign entries each take a step of their own.
+    escalation, manual completion, return/unstall).  The racks, and the
+    pods, each partition the fleet, so a level's shocks kill ``total``
+    servers per unit of its per-domain rate.  Maintenance windows
+    stretch the horizon by their duration (repairs pause) and campaign
+    entries each take a step of their own.
     """
     topo, camp = p.fault_domains, p.campaign
     total = p.working_pool_size + p.spare_pool_size
     extra_steps = 0.0
     extra_horizon = 0.0
     if topo is not None:
-        rates = topo.domain_rates()
-        sizes = topo.domain_fractions(total) * total
-        lam = float(rates.sum())
+        lam = (topo.n_racks * topo.rack_shock_rate
+               + topo.n_pods * topo.pod_shock_rate)
         if lam > 0:
             n_shocks = lam * horizon
-            mean_kill = float((rates * sizes).sum()) / lam
+            mean_kill = (topo.rack_shock_rate
+                         + (topo.pod_shock_rate if topo.n_pods else 0.0)
+                         ) * total / lam
             extra_steps += n_shocks * (2.0 + 4.0 * mean_kill)
             extra_horizon += n_shocks * (
                 p.recovery_time + p.host_selection_time + p.waiting_time)
@@ -296,9 +337,7 @@ def scenario_budget(p, horizon: float) -> Tuple[float, float]:
         for _, code, dom in camp.schedule():
             extra_steps += 2.0
             if code == KILL and topo is not None:
-                extra_steps += 4.0 * len(topo.domain_members(dom, total))
-            elif code == MAINT_END:
-                pass
+                extra_steps += 4.0 * topo.domain_size(dom, total)
         extra_horizon += sum(e.duration for e in camp.events
                              if e.kind == "maintenance")
     return extra_steps, extra_horizon
@@ -333,16 +372,14 @@ class ShockInjector:
                  campaign: Optional[Campaign], total: int, rng) -> None:
         self.topology = topology
         self._rng = rng
+        self._total = total
         if topology is not None:
             self._rates = topology.domain_rates()
-            self._members = [topology.domain_members(d, total)
-                             for d in range(topology.n_domains)]
             self._next = np.array(
                 [rng.exponential(1.0 / r) if r > 0 else math.inf
                  for r in self._rates])
         else:
             self._rates = np.zeros(0)
-            self._members = []
             self._next = np.zeros(0)
         self._schedule = campaign.schedule() if campaign is not None else []
         self._ptr = 0
@@ -365,10 +402,13 @@ class ShockInjector:
             t, code, dom = self._schedule[self._ptr]
             self._ptr += 1
             if code == KILL:
-                return Injection(t, "kill", dom, self._members[dom])
+                return Injection(t, "kill", dom,
+                                 self.topology.domain_members(dom,
+                                                              self._total))
             kind = "maint_start" if code == MAINT_START else "maint_end"
             return Injection(t, kind, 0, [])
         d = int(self._next.argmin())
         t = self._next[d]
         self._next[d] = t + self._rng.exponential(1.0 / self._rates[d])
-        return Injection(float(t), "shock", d, self._members[d])
+        return Injection(float(t), "shock", d,
+                         self.topology.domain_members(d, self._total))

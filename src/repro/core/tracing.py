@@ -10,22 +10,33 @@ until a trace is taken.  Run any CTMC study under ``jax.profiler.trace``
   ``aires.wait`` (the compiled call until its outputs are ready, and the
   fetch of the counters), ``aires.transfer`` (device-to-host copy and
   per-point slicing) and one ``aires.aggregate`` per point (the host
-  statistics).  Each span's keyword arguments come back as stats of its
-  trace event; every child carries the ``study`` number of its root.
+  statistics).  Inside ``aires.prepare``, ``aires.scenario`` covers the
+  host-side set-up of fault domains and campaigns: one span per point
+  around its parameter columns (kill fractions among them) and one
+  around its step budget; a study without a scenario has none.  Each span's keyword arguments
+  come back as stats of its trace event; every child carries the
+  ``study`` number of its root.
 * device scopes (``jax.named_scope``): ``aires.chunk`` encloses the whole
   chunk loop, and under it ``aires.draw`` (the threefry uniforms),
   ``aires.crn_tile`` (common random numbers tiled over the points),
   ``aires.race`` (the event race), ``aires.repair_lane`` (the
   repair-slot countdown, entry, and the decode of a slot's completion),
-  ``aires.ring`` (run-duration ring buffer) and ``aires.hist``
-  (streaming histograms).  A scope names the ops it holds in their HLO
-  metadata, which the profiler reports as each op's ``tf_op`` path.
+  ``aires.ring`` (run-duration ring buffer), ``aires.hist`` (streaming
+  histograms) and ``aires.shock`` (fault domains and campaigns: the
+  struck domain's pick within its level, the bulk kill, the waterfall
+  and deficit lanes, and the per-domain shock record and its add).  A
+  scope names the ops it holds in their HLO metadata, which the
+  profiler reports as each op's ``tf_op`` path.
 * program counters (:data:`COUNTERS`), returned beside the state by the
   chunk loop and attached to ``aires.transfer``: full chunks run, the
   sum over those chunks of the rows still active when each started, the
-  steps run, and the histogram adds (one per chunk the loop ran,
-  remainder included; ``steps_run / hist_flushes`` steps share an add).
-  They never enter the simulated outputs.
+  steps run, the histogram adds (one per chunk the loop ran, remainder
+  included; ``steps_run / hist_flushes`` steps share an add), and the
+  shocks that fired and the servers they and campaign kills struck
+  (``domain_shocks_total``, ``shock_killed_total``: the sums over rows
+  of ``n_domain_shocks`` and ``n_shock_killed``, taken once from the
+  final state; 0 without a scenario).  They never enter the simulated
+  outputs.
 """
 
 from __future__ import annotations
@@ -36,17 +47,22 @@ import itertools
 
 import jax
 
-#: every span and scope name, host spans first
+#: every span and scope name: the five host spans of every study, the
+#: device scopes, then the scenario's host span and its device scope
 NAMES = ("aires.study", "aires.prepare", "aires.wait", "aires.transfer",
          "aires.aggregate", "aires.chunk", "aires.draw", "aires.crn_tile",
-         "aires.race", "aires.repair_lane", "aires.ring", "aires.hist")
+         "aires.race", "aires.repair_lane", "aires.ring", "aires.hist",
+         "aires.scenario", "aires.shock")
 (STUDY, PREPARE, WAIT, TRANSFER, AGGREGATE, CHUNK, DRAW, CRN_TILE, RACE,
- REPAIR_LANE, RING, HIST) = NAMES
+ REPAIR_LANE, RING, HIST, SCENARIO, SHOCK) = NAMES
 
 #: scalars the chunk loop returns beside the state (one per shard on the
 #: sharded path)
 COUNTERS = ("chunks_run", "active_row_chunks", "steps_run",
-            "hist_flushes")
+            "hist_flushes", "domain_shocks_total", "shock_killed_total")
+#: counters whose shards add up; the others' largest shard bounds the
+#: program's time
+SUMMED = ("active_row_chunks", "domain_shocks_total", "shock_killed_total")
 
 _numbers = itertools.count()
 _current: contextvars.ContextVar = contextvars.ContextVar(
@@ -78,11 +94,11 @@ def span(name: str, **args) -> jax.profiler.TraceAnnotation:
 def counter_args(counters) -> dict:
     """Span arguments from the fetched counters: one value per counter,
     the largest over shards (steps, chunks and histogram adds, which
-    bound the program's time) or their sum (active row-chunks); a
-    sharded run adds the per-shard values as one string,
-    ``name:v0/v1/...`` per counter."""
+    bound the program's time) or their sum (:data:`SUMMED`); a sharded
+    run adds the per-shard values as one string, ``name:v0/v1/...`` per
+    counter."""
     shards = {k: [int(x) for x in v.reshape(-1)] for k, v in counters.items()}
-    args = {k: (sum(v) if k == "active_row_chunks" else max(v))
+    args = {k: (sum(v) if k in SUMMED else max(v))
             for k, v in shards.items()}
     if len(shards["steps_run"]) > 1:
         args["per_shard"] = " ".join(

@@ -116,21 +116,29 @@ bit-for-bit (memoryless repairs need no per-server state).
 
 Correlated failure domains + campaigns: when ``Params.fault_domains`` /
 ``Params.campaign`` are set (see :mod:`repro.core.faultdomains` and
-docs/scenarios.md), the race grows one extra exponential lane per fault
-domain — a shared *shock* clock that is live in every non-DONE phase —
-and the flattened campaign schedule races as one more deterministic
-residual (placed first, so a campaign entry beats a same-instant timer
-on both engines).  A shock or scripted kill removes ``fraction x count``
-servers from every pool at once (stochastically rounded, class-
-proportional), sends them through the auto-repair compartment, and
+docs/scenarios.md), the race grows one extra exponential lane per level
+of fault domains (racks, pods) — a shared *shock* clock of the level's
+domain count times its per-domain rate, live in every non-DONE phase;
+the struck domain is uniform within its level, picked from where the
+race's pick uniform fell inside the level's share of the total rate,
+and its fleet fraction follows from its index in closed form — and the
+flattened campaign schedule races as one more deterministic residual
+(placed first, so a campaign entry beats a same-instant timer on both
+engines).  Each step records its struck domain, and the chunk loop adds
+a chunk's records to the per-domain ``domain_shocks`` counts at once,
+as it adds the histogram.  A shock or scripted kill removes
+``fraction x count`` servers from every pool at once (stochastically
+rounded, class-proportional), sends them through the auto-repair
+compartment, and
 bulk-replaces the running block through the standby -> working ->
 spare waterfall; the replacement shortfall accumulates in a ``deficit``
 lane so the job only unstalls when the whole block is restored.
 Maintenance windows gate the exponential repair rates to zero — exact
-pause/resume by memorylessness.  The scenario *structure* (domain count,
-schedule codes) is a static compile switch; every rate, fraction, time,
-and target domain is traced, so a shock-rate grid compiles once.
-Scenarios require exponential repairs on this path (``supports`` routes
+pause/resume by memorylessness.  The scenario *structure* (rack and pod
+counts, schedule codes) is a static compile switch; every rate, the
+fleet size, and each campaign time, fraction and target domain is
+traced, so a shock-rate grid compiles once.  Scenarios require
+exponential repairs on this path (``supports`` routes
 non-exponential-repair scenarios to the event engine); in-shop servers
 struck by a shock re-break, which is exact-in-law a no-op for
 exponential stages and is therefore only counted.
@@ -367,8 +375,8 @@ def _initial_state_batch(pts, R: int, max_runs: int,
     ``rkind`` / ``n_slots`` size the repair-slot lane (non-exponential
     repairs only): ``repair_rem`` +inf marks a free slot.
 
-    ``scen`` is the static scenario key ``(D, codes)`` from
-    :func:`repro.core.faultdomains.scenario_key` — it adds the
+    ``scen`` is the static scenario key ``(n_racks, n_pods, codes)``
+    from :func:`repro.core.faultdomains.scenario_key` — it adds the
     replacement-deficit lane, the per-domain shock counters, and (when
     the flattened campaign schedule is non-empty) the schedule pointer
     and maintenance flag.
@@ -427,12 +435,13 @@ def _initial_state_batch(pts, R: int, max_runs: int,
                                   jnp.float32)
         state["hist_edges"] = jnp.asarray(spec.edges(), jnp.float32)
     if scen is not None:
-        D_dom, camp_codes = scen
+        n_racks, n_pods, camp_codes = scen
         # outstanding replacements after bulk kills: the job unstalls
         # only when the whole struck block has been restored
         state["deficit"] = jnp.zeros((B,), jnp.float32)
-        if D_dom:
-            state["domain_shocks"] = jnp.zeros((B, D_dom), jnp.float32)
+        if n_racks:
+            state["domain_shocks"] = jnp.zeros((B, n_racks + n_pods),
+                                               jnp.float32)
         if len(camp_codes):
             state["camp_idx"] = jnp.zeros((B,), jnp.int32)
         if faultdomains.MAINT_START in camp_codes:
@@ -613,9 +622,71 @@ def _hist_flush(hist: jnp.ndarray, words) -> jnp.ndarray:
     return hist + jnp.moveaxis(counts, -1, 0).astype(jnp.float32)
 
 
+#: a row's shock records placed per pass of :func:`_shock_flush`
+SHOCK_SLOTS = 8
+
+
+def _shock_flush(counts: jnp.ndarray, recs: jnp.ndarray) -> jnp.ndarray:
+    """``counts`` (B, D) plus K steps' shock records: ``recs`` (K, B),
+    each the struck domain or the sentinel D.
+
+    A row takes few shocks in a chunk, so its records are ranked in step
+    order and each pass adds the next :data:`SHOCK_SLOTS` of every row's
+    records in one dense pass over ``counts``: a chunk in which no row
+    took more costs one pass, a chunk without shocks none.  The counts
+    are integers below 2**24, so this equals adding every step's record,
+    in any order.
+    """
+    D = counts.shape[1]
+    hit = recs < D
+    rank = jnp.cumsum(hit, axis=0, dtype=jnp.int32) - 1
+    n = rank[-1] + 1                                    # shocks per row
+
+    def place(carry):
+        j0, counts = carry
+        add = 0
+        for j in range(SHOCK_SLOTS):
+            mine = hit & (rank == j0 + j)
+            dom = jnp.where(n > j0 + j,
+                            jnp.sum(jnp.where(mine, recs, 0), axis=0), D)
+            add = add + row_hit(dom, D)
+        return j0 + SHOCK_SLOTS, counts + add.astype(jnp.float32)
+
+    return jax.lax.while_loop(lambda c: c[0] < n.max(), place,
+                              (jnp.int32(0), counts))[1]
+
+
 # ---------------------------------------------------------------------------
 # one transition
 # ---------------------------------------------------------------------------
+
+def _struck_domain(rates: jnp.ndarray, u_pick: jnp.ndarray,
+                   ev: jnp.ndarray, n_racks: int, n_pods: int):
+    """The domain a shock strikes: uniform among the domains of the
+    level whose lane won the race (racks ``0..n_racks-1``, then pods).
+
+    The race picks lane ``k`` where ``u_pick`` falls in ``[C_{k-1},
+    C_k)`` of the lanes' cumulative rates over their total.  Where it
+    falls inside that interval is uniform, so its position picks one of
+    the level's equal-rate domains by inverse CDF — what racing one
+    lane per domain picks, up to float32 rounding.  Meaningless on a
+    step no shock won; the caller masks it.
+    """
+    base = rates[:, :K_EXP].sum(-1)
+    rack = rates[:, K_EXP]
+    if n_pods:
+        pod_won = ev == K_EXP + 1
+        before = base + jnp.where(pod_won, rack, 0.0)
+        width = jnp.where(pod_won, rates[:, K_EXP + 1], rack)
+        n = jnp.where(pod_won, n_pods, n_racks)
+        total = base + rack + rates[:, K_EXP + 1]
+    else:
+        before, width, n = base, rack, n_racks
+        total = base + rack
+    pos = (u_pick * total - before) / jnp.maximum(width, 1e-30)
+    idx = jnp.clip(jnp.floor(pos * n), 0, n - 1).astype(jnp.int32)
+    return jnp.where(pod_won, n_racks + idx, idx) if n_pods else idx
+
 
 def _n_uniforms(kind: str, rkind: str = "exponential") -> int:
     """Uniform draws per step: the exponential program keeps its
@@ -666,12 +737,15 @@ def _step_u(s: Dict[str, jnp.ndarray], u: jnp.ndarray, pv: jnp.ndarray,
     ``hist`` gets the step's counts added; one with ``hist_bins`` instead
     (the chunk loop's) gets the step's record there, for the loop to add.
 
-    ``scen`` is the static scenario key ``(D, codes)`` — when set, 2D +
-    3L trailing scenario columns follow the repair columns (see
-    :func:`repro.core.faultdomains.scenario_columns`) and the race gains
-    D shock lanes plus (for a non-empty schedule) a campaign residual.
-    Scenarios only reach this path with exponential repairs
-    (``supports``), so ``scen`` and the repair-slot lane never co-exist.
+    ``scen`` is the static scenario key ``(n_racks, n_pods, codes)`` —
+    when set, the scenario's trailing columns follow the repair columns
+    (see :func:`repro.core.faultdomains.scenario_columns`) and the race
+    gains one shock lane per level of fault domains (racks, pods) plus
+    (for a non-empty schedule) a campaign residual.  A state with
+    ``shock_dom`` (the chunk loop's) gets the step's struck domain
+    there, for the loop to add to ``domain_shocks``.  Scenarios only
+    reach this path with exponential repairs (``supports``), so
+    ``scen`` and the repair-slot lane never co-exist.
     """
     n_hc = hazards.hazard_col_count(kind, n_seg)
     n_rc = hazards.repair_col_count(rkind, n_rseg)
@@ -716,10 +790,12 @@ def _step_u(s: Dict[str, jnp.ndarray], u: jnp.ndarray, pv: jnp.ndarray,
               for i in range(16 + n_hc, n_cols)]
 
     if scen is not None:
-        # scenario columns: [rates (D), fractions (D), times (L),
-        # kill fracs (L), target domains (L)] — all traced; only the
-        # counts D / L and the schedule codes are static
-        D_dom, camp_codes = scen
+        # scenario columns: [fleet, racks_per_pod, rack rate, pod rate]
+        # with a topology, then [times (L), kill fracs (L), target
+        # domains (L)] — all traced; only the rack and pod counts, L and
+        # the schedule codes are static
+        n_racks, n_pods, camp_codes = scen
+        D_dom = n_racks + n_pods
         Lc = len(camp_codes)
         has_maint = faultdomains.MAINT_START in camp_codes
 
@@ -728,11 +804,14 @@ def _step_u(s: Dict[str, jnp.ndarray], u: jnp.ndarray, pv: jnp.ndarray,
                 return None
             return pv[lo:lo + n] if pv.ndim == 1 else pv[:, lo:lo + n]
 
-        shock_rate = _scol(n_cols, D_dom)
-        dom_frac = _scol(n_cols + D_dom, D_dom)
-        camp_t = _scol(n_cols + 2 * D_dom, Lc)
-        camp_frac = _scol(n_cols + 2 * D_dom + Lc, Lc)
-        camp_dom = _scol(n_cols + 2 * D_dom + 2 * Lc, Lc)
+        n_tc = 4 if n_racks else 0
+        if n_racks:
+            fleet, rpp, rack_rate, pod_rate = (
+                pv[n_cols + i] if pv.ndim == 1 else pv[:, n_cols + i]
+                for i in range(n_tc))
+        camp_t = _scol(n_cols + n_tc, Lc)
+        camp_frac = _scol(n_cols + n_tc + Lc, Lc)
+        camp_dom = _scol(n_cols + n_tc + 2 * Lc, Lc)
 
     u_time, u_pick, u_diag, u_wrong, u_cls, u_esc, u_succ, u_pool = (
         u[:, 0], u[:, 1], u[:, 2], u[:, 3], u[:, 4], u[:, 5], u[:, 6],
@@ -856,15 +935,17 @@ def _step_u(s: Dict[str, jnp.ndarray], u: jnp.ndarray, pv: jnp.ndarray,
             auto_rate = jnp.where(repair_on, auto_rate, 0.0)
             man_rate = jnp.where(repair_on, man_rate, 0.0)
             rate_parts = [fail_rand, fail_sys, auto_rate, man_rate]
-        if D_dom:
-            # shared-shock lanes: one exponential clock per fault
-            # domain, live in every non-DONE phase (a rack PDU does not
-            # care whether the job is computing) — only the trailing
-            # * active masks them
-            sr = shock_rate if pv.ndim == 2 else jnp.broadcast_to(
-                shock_rate, (run.shape[0], D_dom))
-            rate_parts.append(sr)
-            kx = K_EXP + D_dom
+        if n_racks:
+            # shared-shock lanes, one per level of fault domains (racks,
+            # then pods): a level's n equal-rate domains race as one
+            # exponential clock of n times the per-domain rate, live in
+            # every non-DONE phase (a rack PDU does not care whether the
+            # job is computing) — only the trailing * active masks them
+            levels = [n_racks * rack_rate] + (
+                [n_pods * pod_rate] if n_pods else [])
+            rate_parts.append(jnp.stack(
+                [jnp.broadcast_to(x, run.shape[:1]) for x in levels], -1))
+            kx = K_EXP + len(levels)
     rates = jnp.concatenate(rate_parts, axis=-1) * active[:, None]
 
     # residual column order matters for exact ties (argmin takes the
@@ -994,118 +1075,121 @@ def _step_u(s: Dict[str, jnp.ndarray], u: jnp.ndarray, pv: jnp.ndarray,
     is_ckpt = active & (ev == ckpt_ev)
 
     if scen is not None:
-        # ---- correlated shock / campaign event sizing -------------------
-        # Shock events arrive on lanes [K_EXP, kx); campaign entries on
-        # the first residual (ev == kx).  A shock or scripted kill is
-        # mutually exclusive with every other event this step, so the
-        # idle failure-path uniforms (u_diag/u_wrong/u_cls/u_esc/u_succ)
-        # are free to stochastically round the per-pool kill counts
-        # without widening the per-step stream — which is what keeps the
-        # rate->0 / empty-campaign programs bit-identical to the
-        # scenario-free ones.
-        brows = jnp.arange(run.shape[0])
-        false_b = jnp.zeros_like(active)
-        if D_dom:
-            is_shock = active & (ev >= K_EXP) & (ev < kx)
-            shock_dom = jnp.clip(ev - K_EXP, 0, D_dom - 1)
-        else:
-            is_shock = false_b
-            shock_dom = jnp.zeros_like(ev)
-        if Lc:
-            is_camp = camp_pending & (ev == kx)
-            code_arr = jnp.asarray(camp_codes, jnp.int32)
-            cur_code = code_arr[ci]
-            is_kill = is_camp & (cur_code == faultdomains.KILL)
-            is_m_on = is_camp & (cur_code == faultdomains.MAINT_START)
-            is_m_off = is_camp & (cur_code == faultdomains.MAINT_END)
-            kdom = (camp_dom[ci] if camp_dom.ndim == 1
-                    else camp_dom[brows, ci]).astype(jnp.int32)
-            kfrac = (camp_frac[ci] if camp_frac.ndim == 1
-                     else camp_frac[brows, ci])
-        else:
-            is_camp = is_kill = is_m_on = is_m_off = false_b
-            kdom = jnp.zeros_like(ev)
-            kfrac = jnp.zeros_like(u_time)
-        struck = is_shock | is_kill
-        dom = jnp.where(is_shock, shock_dom, kdom)
-        if D_dom:
-            dfrac = (dom_frac[dom] if dom_frac.ndim == 1
-                     else dom_frac[brows, dom])
-        else:
-            dfrac = jnp.zeros_like(u_time)
-        frac = jnp.where(is_kill, kfrac, dfrac)
+        with jax.named_scope(tracing.SHOCK):
+            # ---- correlated shock / campaign event sizing -------------------
+            # Shock events arrive on the level lanes [K_EXP, kx); campaign
+            # entries on the first residual (ev == kx).  A shock or scripted
+            # kill is mutually exclusive with every other event this step,
+            # so the idle failure-path uniforms (u_diag/u_wrong/u_cls/u_esc/
+            # u_succ) are free to stochastically round the per-pool kill
+            # counts without widening the per-step stream — which is what
+            # keeps the rate->0 / empty-campaign programs bit-identical to
+            # the scenario-free ones.
+            brows = jnp.arange(run.shape[0])
+            false_b = jnp.zeros_like(active)
+            if n_racks:
+                is_shock = active & (ev >= K_EXP) & (ev < kx)
+                shock_dom = _struck_domain(rates, u_pick, ev, n_racks, n_pods)
+            else:
+                is_shock = false_b
+                shock_dom = jnp.zeros_like(ev)
+            if Lc:
+                is_camp = camp_pending & (ev == kx)
+                code_arr = jnp.asarray(camp_codes, jnp.int32)
+                cur_code = code_arr[ci]
+                is_kill = is_camp & (cur_code == faultdomains.KILL)
+                is_m_on = is_camp & (cur_code == faultdomains.MAINT_START)
+                is_m_off = is_camp & (cur_code == faultdomains.MAINT_END)
+                kdom = (camp_dom[ci] if camp_dom.ndim == 1
+                        else camp_dom[brows, ci]).astype(jnp.int32)
+                kfrac = (camp_frac[ci] if camp_frac.ndim == 1
+                         else camp_frac[brows, ci])
+            else:
+                is_camp = is_kill = is_m_on = is_m_off = false_b
+                kdom = jnp.zeros_like(ev)
+                kfrac = jnp.zeros_like(u_time)
+            struck = is_shock | is_kill
+            dom = jnp.where(is_shock, shock_dom, kdom)
+            if n_racks:
+                # the struck domain's share of the fleet, from its index
+                dfrac = faultdomains.domain_sizes(
+                    shock_dom, fleet.astype(jnp.int32), n_racks,
+                    rpp.astype(jnp.int32), xp=jnp).astype(jnp.float32) / fleet
+            else:
+                dfrac = jnp.zeros_like(u_time)
+            frac = jnp.where(is_kill, kfrac, dfrac)
 
-        def _syscomp(cnt, tgt, uu):
-            # systematic (stratified) rounding of a fractional per-class
-            # target composition ``tgt`` (B, 4): returns integer
-            # per-class counts n_c in {floor(tgt_c), ceil(tgt_c)} that
-            # sum to the stochastic rounding of tgt.sum() — one uniform
-            # drives both the total and its split.  With integer
-            # occupancies and tgt_c <= cnt_c, n_c <= cnt_c always, so
-            # compartments keep the whole-server invariant the repair
-            # race's one-hot removals rely on.
-            C = jnp.cumsum(tgt, axis=-1)
-            Cm = jnp.concatenate([jnp.zeros_like(C[:, :1]), C[:, :-1]],
-                                 axis=-1)
-            up = jnp.maximum(jnp.ceil(C - uu[:, None]), 0.0)
-            lo = jnp.maximum(jnp.ceil(Cm - uu[:, None]), 0.0)
-            return up - lo
+            def _syscomp(cnt, tgt, uu):
+                # systematic (stratified) rounding of a fractional per-class
+                # target composition ``tgt`` (B, 4): returns integer
+                # per-class counts n_c in {floor(tgt_c), ceil(tgt_c)} that
+                # sum to the stochastic rounding of tgt.sum() — one uniform
+                # drives both the total and its split.  With integer
+                # occupancies and tgt_c <= cnt_c, n_c <= cnt_c always, so
+                # compartments keep the whole-server invariant the repair
+                # race's one-hot removals rely on.
+                C = jnp.cumsum(tgt, axis=-1)
+                Cm = jnp.concatenate([jnp.zeros_like(C[:, :1]), C[:, :-1]],
+                                     axis=-1)
+                up = jnp.maximum(jnp.ceil(C - uu[:, None]), 0.0)
+                lo = jnp.maximum(jnp.ceil(Cm - uu[:, None]), 0.0)
+                return up - lo
 
-        def _sround(x, uu):
-            fl = jnp.floor(x)
-            return fl + (uu < x - fl).astype(jnp.float32)
+            def _sround(x, uu):
+                fl = jnp.floor(x)
+                return fl + (uu < x - fl).astype(jnp.float32)
 
-        fr = frac[:, None]
-        rm_run = _syscomp(run, run * fr, u_diag) * struck[:, None]
-        rm_sb = _syscomp(s["sb"], s["sb"] * fr, u_wrong) * struck[:, None]
-        rm_fw = _syscomp(s["fw"], s["fw"] * fr, u_cls) * struck[:, None]
-        rm_fs = _syscomp(s["fs"], s["fs"] * fr, u_esc) * struck[:, None]
-        k_run = rm_run.sum(-1)
-        k_sb = rm_sb.sum(-1)
-        k_fw = rm_fw.sum(-1)
-        k_fs = rm_fs.sum(-1)
-        # in-shop members re-break: exact-in-law a no-op under the
-        # exponential stages this path guarantees — counted, not moved
-        shop_tot0 = jnp.maximum(s["auto"].sum(-1) + s["man"].sum(-1), 0.0)
-        k_shop = jnp.where(struck,
-                           _sround(shop_tot0 * frac, u_succ), 0.0)
-        # bulk replacement through the same standby -> working -> spare
-        # waterfall a single failure uses, sized against the post-kill
-        # pool occupancies (all integers, so the min-chain is exact)
-        sb_rem = jnp.maximum(s["sb"].sum(-1) - k_sb, 0.0)
-        fw_rem = jnp.maximum(s["fw"].sum(-1) - k_fw, 0.0)
-        fs_rem = jnp.maximum(s["fs"].sum(-1) - k_fs, 0.0)
-        t_sb = jnp.minimum(k_run, sb_rem)
-        t_fw = jnp.minimum(k_run - t_sb, fw_rem)
-        t_fs = jnp.minimum(k_run - t_sb - t_fw, fs_rem)
-        shortfall = jnp.maximum(k_run - t_sb - t_fw - t_fs, 0.0)
+            fr = frac[:, None]
+            rm_run = _syscomp(run, run * fr, u_diag) * struck[:, None]
+            rm_sb = _syscomp(s["sb"], s["sb"] * fr, u_wrong) * struck[:, None]
+            rm_fw = _syscomp(s["fw"], s["fw"] * fr, u_cls) * struck[:, None]
+            rm_fs = _syscomp(s["fs"], s["fs"] * fr, u_esc) * struck[:, None]
+            k_run = rm_run.sum(-1)
+            k_sb = rm_sb.sum(-1)
+            k_fw = rm_fw.sum(-1)
+            k_fs = rm_fs.sum(-1)
+            # in-shop members re-break: exact-in-law a no-op under the
+            # exponential stages this path guarantees — counted, not moved
+            shop_tot0 = jnp.maximum(s["auto"].sum(-1) + s["man"].sum(-1), 0.0)
+            k_shop = jnp.where(struck,
+                               _sround(shop_tot0 * frac, u_succ), 0.0)
+            # bulk replacement through the same standby -> working -> spare
+            # waterfall a single failure uses, sized against the post-kill
+            # pool occupancies (all integers, so the min-chain is exact)
+            sb_rem = jnp.maximum(s["sb"].sum(-1) - k_sb, 0.0)
+            fw_rem = jnp.maximum(s["fw"].sum(-1) - k_fw, 0.0)
+            fs_rem = jnp.maximum(s["fs"].sum(-1) - k_fs, 0.0)
+            t_sb = jnp.minimum(k_run, sb_rem)
+            t_fw = jnp.minimum(k_run - t_sb, fw_rem)
+            t_fs = jnp.minimum(k_run - t_sb - t_fw, fs_rem)
+            shortfall = jnp.maximum(k_run - t_sb - t_fw - t_fs, 0.0)
 
-        def _take(cnt, t, tot, uu):
-            ratio = (t / jnp.maximum(tot, 1.0))[:, None]
-            return _syscomp(cnt, cnt * ratio, uu)
+            def _take(cnt, t, tot, uu):
+                ratio = (t / jnp.maximum(tot, 1.0))[:, None]
+                return _syscomp(cnt, cnt * ratio, uu)
 
-        # the take compositions reuse u_pool (idle on shock steps) with
-        # golden-ratio decorrelation shifts — correlated rounding across
-        # pools is harmless (totals are exact; only the class split of a
-        # single bulk event is approximated)
-        PHI = 0.6180339887498949
-        mv_sb = _take(s["sb"] - rm_sb, t_sb, sb_rem, u_pool)
-        mv_fw = _take(s["fw"] - rm_fw, t_fw, fw_rem,
-                      jnp.mod(u_pool + PHI, 1.0))
-        mv_fs = _take(s["fs"] - rm_fs, t_fs, fs_rem,
-                      jnp.mod(u_pool + 2.0 * PHI, 1.0))
-        sh_affects = struck & (k_run > 0)
-        # full replacements while already stalled must not clobber the
-        # STALL — the original deficit is still outstanding
-        sh_resolves = sh_affects & (shortfall <= 1e-6) & ~stalled
-        sh_stalls = sh_affects & ~sh_resolves
-        # one concurrent group restart: host selection / preemption
-        # waits overlap across the block, so the overhead is charged
-        # once per event, not per server
-        shock_timer = (recovery
-                       + jnp.where(t_fw + t_fs > 1e-6, host_sel, 0.0)
-                       + jnp.where(t_fs > 1e-6, waiting + preempt_cost,
-                                   0.0))
+            # the take compositions reuse u_pool (idle on shock steps) with
+            # golden-ratio decorrelation shifts — correlated rounding across
+            # pools is harmless (totals are exact; only the class split of a
+            # single bulk event is approximated)
+            PHI = 0.6180339887498949
+            mv_sb = _take(s["sb"] - rm_sb, t_sb, sb_rem, u_pool)
+            mv_fw = _take(s["fw"] - rm_fw, t_fw, fw_rem,
+                          jnp.mod(u_pool + PHI, 1.0))
+            mv_fs = _take(s["fs"] - rm_fs, t_fs, fs_rem,
+                          jnp.mod(u_pool + 2.0 * PHI, 1.0))
+            sh_affects = struck & (k_run > 0)
+            # full replacements while already stalled must not clobber the
+            # STALL — the original deficit is still outstanding
+            sh_resolves = sh_affects & (shortfall <= 1e-6) & ~stalled
+            sh_stalls = sh_affects & ~sh_resolves
+            # one concurrent group restart: host selection / preemption
+            # waits overlap across the block, so the overhead is charged
+            # once per event, not per server
+            shock_timer = (recovery
+                           + jnp.where(t_fw + t_fs > 1e-6, host_sel, 0.0)
+                           + jnp.where(t_fs > 1e-6, waiting + preempt_cost,
+                                       0.0))
 
     ns = dict(s)
     ns["t"] = s["t"] + dt
@@ -1289,13 +1373,14 @@ def _step_u(s: Dict[str, jnp.ndarray], u: jnp.ndarray, pv: jnp.ndarray,
         # retires one unit and the job only restarts once the whole
         # block is restored (struck / goes_stall / finishes are
         # mutually exclusive per step, so the chain is race-free)
-        deficit = (s["deficit"]
-                   + jnp.where(goes_stall, 1.0, 0.0)
-                   + jnp.where(struck, shortfall, 0.0))
-        deficit = jnp.where(to_stalled,
-                            jnp.maximum(deficit - 1.0, 0.0), deficit)
-        unstall = to_stalled & (deficit <= 1e-6)
-        ns["deficit"] = deficit
+        with jax.named_scope(tracing.SHOCK):
+            deficit = (s["deficit"]
+                       + jnp.where(goes_stall, 1.0, 0.0)
+                       + jnp.where(struck, shortfall, 0.0))
+            deficit = jnp.where(to_stalled,
+                                jnp.maximum(deficit - 1.0, 0.0), deficit)
+            unstall = to_stalled & (deficit <= 1e-6)
+            ns["deficit"] = deficit
     ns["phase"] = jnp.where(unstall, OVERHEAD, ns["phase"])
     ns["timer"] = jnp.where(unstall, recovery, ns["timer"])
     ns["stall_time"] = s["stall_time"] \
@@ -1304,52 +1389,54 @@ def _step_u(s: Dict[str, jnp.ndarray], u: jnp.ndarray, pv: jnp.ndarray,
         + jnp.where(unstall, recovery, 0.0)
 
     if scen is not None:
-        # ---- correlated shock / campaign execution ----------------------
-        # the struck block leaves every compartment at once and enters
-        # the automated-repair stage; replacements drawn above through
-        # the standard waterfall join the run set in the same step.
-        # In-shop casualties (k_shop) re-break in place: under the
-        # exponential stages this path guarantees, a restarted repair is
-        # distributed exactly like the remaining one (memorylessness),
-        # so they are counted but not moved.
-        w = struck[:, None]
-        ns["run"] = jnp.where(
-            w, ns["run"] - rm_run + mv_sb + mv_fw + mv_fs, ns["run"])
-        ns["sb"] = jnp.where(w, ns["sb"] - rm_sb - mv_sb, ns["sb"])
-        ns["fw"] = jnp.where(w, ns["fw"] - rm_fw - mv_fw, ns["fw"])
-        ns["fs"] = jnp.where(w, ns["fs"] - rm_fs - mv_fs, ns["fs"])
-        ns["auto"] = jnp.where(
-            w, ns["auto"] + rm_run + rm_sb + rm_fw + rm_fs, ns["auto"])
-        ns["n_domain_shocks"] = s["n_domain_shocks"] \
-            + is_shock.astype(jnp.float32)
-        ns["n_campaign_events"] = s["n_campaign_events"] \
-            + is_camp.astype(jnp.float32)
-        ns["n_shock_killed"] = s["n_shock_killed"] \
-            + jnp.where(struck, k_run + k_sb + k_fw + k_fs + k_shop, 0.0)
-        ns["n_standby_swaps"] = ns["n_standby_swaps"] \
-            + jnp.where(struck, t_sb, 0.0)
-        ns["n_host_selections"] = ns["n_host_selections"] \
-            + jnp.where(struck, t_fw + t_fs, 0.0)
-        ns["n_preemptions"] = ns["n_preemptions"] \
-            + jnp.where(struck, t_fs, 0.0)
-        if D_dom:
-            ns["domain_shocks"] = s["domain_shocks"] + (
-                row_hit(dom, D_dom) & is_shock[:, None]).astype(jnp.float32)
-        if Lc:
-            ns["camp_idx"] = s["camp_idx"] + is_camp.astype(jnp.int32)
-        if has_maint:
-            ns["maint"] = jnp.where(
-                is_m_on, 1.0, jnp.where(is_m_off, 0.0, s["maint"]))
-        ns["timer"] = jnp.where(sh_resolves, shock_timer, ns["timer"])
-        ns["phase"] = jnp.where(sh_resolves, OVERHEAD, ns["phase"])
-        ns["phase"] = jnp.where(sh_stalls, STALL, ns["phase"])
-        # a shock aborts any in-flight checkpoint write: the ensuing
-        # OVERHEAD is a recovery (age resets when it expires)
-        ns["in_ckpt"] = jnp.where(sh_affects, 0.0, ns["in_ckpt"])
-        ns["stall_start"] = jnp.where(sh_stalls & ~stalled, ns["t"],
-                                      ns["stall_start"])
-        ns["recovery_overhead"] = ns["recovery_overhead"] \
-            + jnp.where(sh_resolves, recovery, 0.0)
+        with jax.named_scope(tracing.SHOCK):
+            # ---- correlated shock / campaign execution ----------------------
+            # the struck block leaves every compartment at once and enters
+            # the automated-repair stage; replacements drawn above through
+            # the standard waterfall join the run set in the same step.
+            # In-shop casualties (k_shop) re-break in place: under the
+            # exponential stages this path guarantees, a restarted repair is
+            # distributed exactly like the remaining one (memorylessness),
+            # so they are counted but not moved.
+            w = struck[:, None]
+            ns["run"] = jnp.where(
+                w, ns["run"] - rm_run + mv_sb + mv_fw + mv_fs, ns["run"])
+            ns["sb"] = jnp.where(w, ns["sb"] - rm_sb - mv_sb, ns["sb"])
+            ns["fw"] = jnp.where(w, ns["fw"] - rm_fw - mv_fw, ns["fw"])
+            ns["fs"] = jnp.where(w, ns["fs"] - rm_fs - mv_fs, ns["fs"])
+            ns["auto"] = jnp.where(
+                w, ns["auto"] + rm_run + rm_sb + rm_fw + rm_fs, ns["auto"])
+            ns["n_domain_shocks"] = s["n_domain_shocks"] \
+                + is_shock.astype(jnp.float32)
+            ns["n_campaign_events"] = s["n_campaign_events"] \
+                + is_camp.astype(jnp.float32)
+            ns["n_shock_killed"] = s["n_shock_killed"] \
+                + jnp.where(struck, k_run + k_sb + k_fw + k_fs + k_shop, 0.0)
+            ns["n_standby_swaps"] = ns["n_standby_swaps"] \
+                + jnp.where(struck, t_sb, 0.0)
+            ns["n_host_selections"] = ns["n_host_selections"] \
+                + jnp.where(struck, t_fw + t_fs, 0.0)
+            ns["n_preemptions"] = ns["n_preemptions"] \
+                + jnp.where(struck, t_fs, 0.0)
+            if "shock_dom" in s:
+                # the step's struck domain, or the sentinel D; the chunk
+                # loop adds a chunk's records at once
+                ns["shock_dom"] = jnp.where(is_shock, dom, D_dom)
+            if Lc:
+                ns["camp_idx"] = s["camp_idx"] + is_camp.astype(jnp.int32)
+            if has_maint:
+                ns["maint"] = jnp.where(
+                    is_m_on, 1.0, jnp.where(is_m_off, 0.0, s["maint"]))
+            ns["timer"] = jnp.where(sh_resolves, shock_timer, ns["timer"])
+            ns["phase"] = jnp.where(sh_resolves, OVERHEAD, ns["phase"])
+            ns["phase"] = jnp.where(sh_stalls, STALL, ns["phase"])
+            # a shock aborts any in-flight checkpoint write: the ensuing
+            # OVERHEAD is a recovery (age resets when it expires)
+            ns["in_ckpt"] = jnp.where(sh_affects, 0.0, ns["in_ckpt"])
+            ns["stall_start"] = jnp.where(sh_stalls & ~stalled, ns["t"],
+                                          ns["stall_start"])
+            ns["recovery_overhead"] = ns["recovery_overhead"] \
+                + jnp.where(sh_resolves, recovery, 0.0)
 
     # ---- repair-slot lane (non-exponential repairs) ----------------------
     # repairs run on wall-clock time: every occupied slot counts down by
@@ -1472,9 +1559,11 @@ def _params_vector(p: Params) -> jnp.ndarray:
     ], np.float32)
     parts = [base, hazards.hazard_columns(p), hazards.repair_columns(p)]
     if faultdomains.scenario_key(p) is not None:
-        # trailing scenario columns (2D + 3L) — traced, so a shock-rate
-        # or campaign-time grid shares one compiled program
-        parts.append(faultdomains.scenario_columns(p).astype(np.float32))
+        # trailing scenario columns (a few per level, 3 per campaign
+        # entry) — traced, so a shock-rate or campaign-time grid shares
+        # one compiled program
+        with tracing.span(tracing.SCENARIO):
+            parts.append(faultdomains.scenario_columns(p).astype(np.float32))
     return jnp.asarray(np.concatenate(parts))
 
 
@@ -1494,7 +1583,8 @@ def default_max_steps(p: Params, safety: float = 2.0) -> int:
     if p.fault_domains is not None or p.campaign is not None:
         # shocks + campaign entries + their bulk repair traffic, and the
         # horizon stretch of maintenance windows / shock recoveries
-        extra, extra_h = faultdomains.scenario_budget(p, horizon)
+        with tracing.span(tracing.SCENARIO):
+            extra, extra_h = faultdomains.scenario_budget(p, horizon)
         horizon += extra_h
     steps = max(128, int((lam * horizon + extra) * 3.2 * safety))
     if p.checkpoint_interval > 0:
@@ -1557,7 +1647,8 @@ def _chunk_loop(pv: jnp.ndarray, key: jax.Array, P: int, R: int,
         # the histogram leaves the scan: each step records its bins
         # (``hist_bins``, starting all sentinel), the scan stacks the
         # records, and one add per chunk folds them into ``hist``, which
-        # rides in the chunk loop's carry
+        # rides in the chunk loop's carry; the per-domain shock counts
+        # do the same with each step's struck domain (``shock_dom``)
         state = dict(init_state)
         hist = state.pop("hist", None)
         has_hist = hist is not None
@@ -1565,6 +1656,10 @@ def _chunk_loop(pv: jnp.ndarray, key: jax.Array, P: int, R: int,
             cb = (hist.shape[1], hist.shape[0])
             state["hist_bins"] = _hist_bins(
                 state["hist_edges"], jnp.zeros(cb), jnp.zeros(cb, bool))
+        shocks = state.pop("domain_shocks", None)
+        if shocks is not None:
+            state["shock_dom"] = jnp.full(shocks.shape[:1], shocks.shape[1],
+                                          jnp.int32)
 
         def scan_body(state, u):
             if P > 1:
@@ -1572,9 +1667,10 @@ def _chunk_loop(pv: jnp.ndarray, key: jax.Array, P: int, R: int,
                     u = jnp.tile(u, (P, 1))
             state = _step_u(state, u, pv, impl, kind, rkind, hist_channels,
                             scen, n_seg, n_rseg)
-            return state, tuple(state["hist_bins"]) if has_hist else None
+            return state, (tuple(state["hist_bins"]) if has_hist else None,
+                           state.get("shock_dom"))
 
-        def run_chunk(flushes, hist, state, i, n_steps):
+        def run_chunk(flushes, hist, shocks, state, i, n_steps):
             # one batched threefry call per chunk (a per-step split + draw is
             # the dominant scan cost on CPU); the non-exponential hazard /
             # repair families draw extra uniform lanes per step
@@ -1585,20 +1681,23 @@ def _chunk_loop(pv: jnp.ndarray, key: jax.Array, P: int, R: int,
                     dtype=jnp.float32, minval=1e-12, maxval=1.0)
                 if R_draw != R:
                     us = us[:, :R]
-            state, words = jax.lax.scan(scan_body, state, us)
+            state, (words, recs) = jax.lax.scan(scan_body, state, us)
             if has_hist:
                 with jax.named_scope(tracing.HIST):
                     hist = _hist_flush(hist, words)
                 flushes += 1
-            return flushes, hist, state
+            if shocks is not None:
+                with jax.named_scope(tracing.SHOCK):
+                    shocks = _shock_flush(shocks, recs)
+            return flushes, hist, shocks, state
 
         def chunk_body(carry):
-            i, active_rows, flushes, hist, state = carry
+            i, active_rows, flushes, hist, shocks, state = carry
             # rows still running as the chunk starts (padding rows start
             # DONE and never count)
             active_rows += jnp.sum(state["phase"] != DONE, dtype=jnp.int32)
             return (i + 1, active_rows,
-                    *run_chunk(flushes, hist, state, i, chunk))
+                    *run_chunk(flushes, hist, shocks, state, i, chunk))
 
         def cond(carry):
             i, *_, state = carry
@@ -1607,9 +1706,10 @@ def _chunk_loop(pv: jnp.ndarray, key: jax.Array, P: int, R: int,
                 not_done &= jnp.any(state["phase"] != DONE)
             return not_done
 
-        n_run, active_rows, flushes, hist, state = jax.lax.while_loop(
-            cond, chunk_body,
-            (jnp.int32(0), jnp.int32(0), jnp.int32(0), hist, state))
+        n_run, active_rows, flushes, hist, shocks, state = \
+            jax.lax.while_loop(cond, chunk_body,
+                               (jnp.int32(0), jnp.int32(0), jnp.int32(0),
+                                hist, shocks, state))
         steps = n_run * chunk
         if rem:
             # partial final chunk so an explicit max_steps is honored exactly.
@@ -1618,7 +1718,7 @@ def _chunk_loop(pv: jnp.ndarray, key: jax.Array, P: int, R: int,
             def do_rem(*carry):
                 return run_chunk(*carry, n_chunks, rem)
 
-            carry = (flushes, hist, state)
+            carry = (flushes, hist, shocks, state)
             if early_exit:
                 rem_runs = jnp.any(state["phase"] != DONE)
                 carry = jax.lax.cond(rem_runs, do_rem, lambda *c: c, *carry)
@@ -1626,23 +1726,31 @@ def _chunk_loop(pv: jnp.ndarray, key: jax.Array, P: int, R: int,
             else:
                 carry = do_rem(*carry)
                 steps += rem
-            flushes, hist, state = carry
+            flushes, hist, shocks, state = carry
         if has_hist:
             state.pop("hist_bins")
             state["hist"] = hist
+        if shocks is not None:
+            state.pop("shock_dom")
+            state["domain_shocks"] = shocks
         state["completed"] = (state["phase"] == DONE).astype(jnp.float32)
         state["total_time"] = jnp.where(state["phase"] == DONE,
                                         state["total_time"], state["t"])
         # the counters (tracing.COUNTERS), never part of the simulated
         # outputs: full chunks the early-exit loop executed (the remainder
         # chunk not counted), the sum over those chunks of the rows active
-        # as each started, every step run, remainder included, and the
+        # as each started, every step run, remainder included, the
         # histogram adds (one per chunk run, remainder included; 0 without
-        # a histogram)
+        # a histogram), and the shocks and the servers they struck, summed
+        # once over the final state's rows (0 without a scenario)
         state["chunks_run"] = n_run
         state["active_row_chunks"] = active_rows
         state["steps_run"] = steps
         state["hist_flushes"] = flushes
+        for counter, metric in (("domain_shocks_total", "n_domain_shocks"),
+                                ("shock_killed_total", "n_shock_killed")):
+            state[counter] = (jnp.int32(0) if scen is None else jnp.sum(
+                state[metric].astype(jnp.int32)))
     return state
 
 
@@ -2042,8 +2150,9 @@ def sweep_programs(params_list, n_replicas: int = 1024, seed=0,
         # compiles exactly once.
         kind = hazards.hazard_kind(p)
         rkind = hazards.repair_kind(p)
-        # the scenario key (domain count + campaign codes) sizes the race
-        # and the trailing parameter columns, so it splits groups the
+        # the scenario key (rack and pod counts + campaign codes) sizes
+        # the race, the per-domain counts and the trailing parameter
+        # columns, so it splits groups the
         # same way the hazard family does; shock *rates* and campaign
         # *times/fractions* stay traced — a shock-rate grid over one
         # topology compiles exactly once.  Likewise the empirical

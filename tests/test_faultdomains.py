@@ -16,17 +16,25 @@ docs/scenarios.md):
   * per-domain shock telemetry is consistent (``domain_shocks`` sums to
     ``n_domain_shocks``);
   * a shock-rate sweep is traced — the whole grid compiles one program;
+  * the race holds one lane per level of domains: the struck domain is
+    uniform within its level, its membership and fleet fraction follow
+    from its index in closed form, and the chunk loop's once-a-chunk
+    add of the struck domains equals adding every step;
   * scenarios combined with non-exponential repairs stay on the event
     oracle (``supports()`` gating), where struck in-shop servers are
     re-broken by redrawing the stage.
 """
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import (Campaign, CampaignEvent, FaultTopology, OneWaySweep,
-                        Params, Tracer, resolve_engine, run_replications,
-                        simulate, simulate_one)
+                        Params, Tracer, faultdomains, hazards,
+                        resolve_engine, run_replications, simulate,
+                        simulate_one)
+from repro.core import vectorized as vz
 from repro.core.metrics import aggregate, histograms_from_arrays
 from repro.core.simulation import ClusterSimulation
 from repro.core.vectorized import simulate_ctmc, simulate_ctmc_sweep, supports
@@ -48,6 +56,19 @@ BASE = Params(job_size=24, working_pool_size=32, spare_pool_size=8,
               random_failure_rate=2e-4, systematic_failure_rate=1e-3,
               recovery_time=10.0, seed=5)
 SCENARIO = BASE.replace(fault_domains=TOPO, campaign=CAMPAIGN)
+#: many racks: 64 racks in 4 pods of 16, each rack 2 working-pool
+#: servers and 1 spare (both pools stripe evenly, as in TOPO), and a
+#: scripted kill of pod 2 (domain 66) mid-run
+MANY_RACKS = Params(job_size=112, working_pool_size=128, spare_pool_size=64,
+                    warm_standbys=4, job_length=3000.0,
+                    random_failure_rate=1e-4, systematic_failure_rate=5e-4,
+                    recovery_time=10.0, seed=5,
+                    fault_domains=FaultTopology(
+                        n_racks=64, racks_per_pod=16,
+                        rack_shock_rate=1.5e-5, pod_shock_rate=4e-5),
+                    campaign=Campaign(events=(
+                        CampaignEvent(time=1200.0, kind="kill",
+                                      domain=66),)))
 
 
 def _z(a: np.ndarray, b: np.ndarray) -> float:
@@ -146,18 +167,29 @@ def test_inert_scenario_reduces_exactly_ctmc():
 # cross-engine agreement (acceptance criteria)
 # ---------------------------------------------------------------------------
 
-@pytest.fixture(scope="module")
-def scenario_runs():
-    out = simulate_ctmc(SCENARIO, n_replicas=N_CTMC, seed=6)
+def _both_engines(p):
+    out = simulate_ctmc(p, n_replicas=N_CTMC, seed=6)
     assert out["completed"].mean() > 0.99, "CTMC replicas did not finish"
-    res = simulate(SCENARIO, N_EVENT, base_seed=5)
+    res = simulate(p, N_EVENT, base_seed=5)
     return out, res
 
 
-def test_scenario_matches_event_oracle(scenario_runs):
-    """Shocks + mid-run domain kill + maintenance window: metric means
-    agree across engines within sampling error."""
-    out, res = scenario_runs
+@pytest.fixture(scope="module")
+def scenario_runs():
+    return _both_engines(SCENARIO)
+
+
+@pytest.fixture(scope="module")
+def many_rack_runs():
+    return _both_engines(MANY_RACKS)
+
+
+@pytest.mark.parametrize("runs", ["scenario_runs", "many_rack_runs"],
+                         ids=["rack_pod_campaign", "many_racks"])
+def test_scenario_matches_event_oracle(runs, request):
+    """Shocks + mid-run domain kill (+ a maintenance window on the small
+    topology): metric means agree across engines within sampling error."""
+    out, res = request.getfixturevalue(runs)
     for m in ("total_time", "n_failures", "n_standby_swaps",
               "n_host_selections", "n_preemptions", "recovery_overhead",
               "n_domain_shocks", "n_shock_killed", "n_campaign_events"):
@@ -352,3 +384,178 @@ def test_n_incomplete_ctmc_arrays():
     assert stats["n_incomplete"].mean == pytest.approx(
         1.0 - float(out["completed"].mean()))
     assert stats["n_incomplete"].mean > 0.0
+
+
+# ---------------------------------------------------------------------------
+# one race lane per level: closed-form membership, uniform pick, flush
+# ---------------------------------------------------------------------------
+
+def _members_by_scan(topo, domain, total):
+    """The definition by a scan of the whole fleet: server ``s`` is in
+    rack ``s % n_racks`` and in that rack's pod."""
+    if domain < topo.n_racks:
+        return [s for s in range(total) if s % topo.n_racks == domain]
+    pod = domain - topo.n_racks
+    return [s for s in range(total)
+            if (s % topo.n_racks) // topo.racks_per_pod == pod]
+
+
+@pytest.mark.parametrize("n_racks,racks_per_pod,total", [
+    (4, 2, 40),          # the test topology: 10 servers a rack
+    (5, 2, 12),          # 5 racks do not divide 12; a last pod of 1 rack
+    (7, 3, 50),
+    (64, 16, 128),
+    (10, 4, 10),         # one server a rack
+    (3, 0, 9),           # no pod level
+    (1536, 192, 2976),   # the Llama 3 cluster at its 2080-server pool
+])
+def test_closed_form_membership_equals_a_scan(n_racks, racks_per_pod,
+                                              total):
+    topo = FaultTopology(n_racks=n_racks, racks_per_pod=racks_per_pod,
+                         rack_shock_rate=1e-5,
+                         pod_shock_rate=2e-5 if racks_per_pod else 0.0)
+    want = [_members_by_scan(topo, d, total)
+            for d in range(topo.n_domains)]
+    assert [topo.domain_members(d, total)
+            for d in range(topo.n_domains)] == want
+    sizes = [len(m) for m in want]
+    assert [topo.domain_size(d, total)
+            for d in range(topo.n_domains)] == sizes
+    np.testing.assert_array_equal(
+        topo.domain_fractions(total),
+        np.asarray(sizes, np.float64) / total)
+    # the compiled step's form of the same arithmetic
+    got = faultdomains.domain_sizes(
+        jnp.arange(topo.n_domains), jnp.int32(total), n_racks,
+        jnp.int32(racks_per_pod), xp=jnp)
+    np.testing.assert_array_equal(np.asarray(got), sizes)
+    # the step budget's kill sizes: the rate-weighted mean of the scan
+    p = Params(job_size=min(8, total), working_pool_size=total - 1,
+               spare_pool_size=1, warm_standbys=0, fault_domains=topo,
+               campaign=Campaign(events=(CampaignEvent(
+                   time=5.0, kind="kill", domain=topo.n_domains - 1),)))
+    rates = topo.domain_rates()
+    lam = rates.sum()
+    n_shocks = lam * 1000.0
+    want_steps = (n_shocks * (2.0 + 4.0 * (rates * sizes).sum() / lam)
+                  + 2.0 + 4.0 * sizes[-1])
+    steps, _ = faultdomains.scenario_budget(p, 1000.0)
+    assert steps == pytest.approx(want_steps, rel=1e-12)
+
+
+def test_race_takes_one_lane_per_level():
+    """The scenario's columns are a few per level, not two per domain,
+    and the race sees K_EXP + 2 exponential lanes for racks and pods."""
+    p = MANY_RACKS
+    assert faultdomains.scenario_key(p) == (64, 4, (faultdomains.KILL,))
+    n_base = len(vz._params_vector(MANY_RACKS.replace(
+        fault_domains=None, campaign=None)))
+    assert len(vz._params_vector(p)) == n_base + 4 + 3
+    seen = []
+    real = vz.ops.event_race
+
+    def spy(rates, residuals, *a, **k):
+        seen.append(rates.shape[1])
+        return real(rates, residuals, *a, **k)
+
+    import unittest.mock
+    with unittest.mock.patch.object(vz.ops, "event_race", spy):
+        jax.eval_shape(lambda s: vz._step(
+            s, jax.random.PRNGKey(0), vz._params_vector(p), None,
+            scen=faultdomains.scenario_key(p)),
+            vz._initial_state(p, 8, None))
+    assert seen == [vz.K_EXP + 2]
+
+
+def test_struck_domain_is_uniform_within_its_level():
+    """Hundreds of racks: the shock counts per rack, and per pod, over
+    many replicas fit a uniform law (chi-square), and the levels split
+    the shocks by their total rates."""
+    from scipy import stats
+
+    topo = FaultTopology(n_racks=300, racks_per_pod=10,
+                         rack_shock_rate=1.5e-5, pod_shock_rate=5e-5)
+    p = Params(job_size=16, working_pool_size=320, spare_pool_size=280,
+               warm_standbys=2, job_length=2000.0, fault_domains=topo,
+               histogram=None)
+    out = simulate_ctmc(p, n_replicas=4096, seed=11)
+    assert out["completed"].mean() == 1.0
+    per = np.asarray(out["domain_shocks"]).sum(0)
+    racks, pods = per[:300], per[300:]
+    assert racks.sum() > 20000 and pods.sum() > 5000
+    assert stats.chisquare(racks).pvalue > 1e-3
+    assert stats.chisquare(pods).pvalue > 1e-3
+    # rack level 300 x 1.5e-5 against pod level 30 x 5e-5: 3 to 1
+    share = racks.sum() / per.sum()
+    assert abs(share - 0.75) < 4 * np.sqrt(0.75 * 0.25 / per.sum())
+
+
+def test_shock_flush_equals_adding_every_record():
+    """Rows with none, a few, and more shocks than one pass places."""
+    K, B, D = 64, 40, 37
+    rng = np.random.default_rng(4)
+    density = rng.choice([0.0, 0.05, 0.3, 0.9], size=B)
+    recs = np.where(rng.random((K, B)) < density, rng.integers(0, D, (K, B)),
+                    D).astype(np.int32)
+    assert (recs < D).sum(0).max() > 2 * vz.SHOCK_SLOTS
+    counts0 = rng.integers(0, 5, (B, D)).astype(np.float32)
+    want = counts0.copy()
+    k, b = np.nonzero(recs < D)
+    np.add.at(want, (b, recs[k, b]), 1.0)
+    got = vz._shock_flush(jnp.asarray(counts0), jnp.asarray(recs))
+    np.testing.assert_array_equal(np.asarray(got), want)
+    none = np.full((K, B), D, np.int32)
+    np.testing.assert_array_equal(
+        np.asarray(vz._shock_flush(jnp.asarray(counts0), jnp.asarray(none))),
+        counts0)
+
+
+def _adding_every_step(step):
+    """``step`` made to add its struck domain at once: the counts ride in
+    the scan carry as ``shocks_now``, and the chunk loop's own record is
+    reset to the sentinel, so its flush adds nothing."""
+    def immediate(s, u, *args, **kw):
+        inner = {k: v for k, v in s.items() if k != "shocks_now"}
+        ns = step(inner, u, *args, **kw)
+        D = s["shocks_now"].shape[1]
+        now = s["shocks_now"] + vz.row_hit(ns["shock_dom"], D)
+        return dict(ns, shocks_now=now.astype(jnp.float32),
+                    shock_dom=jnp.full_like(ns["shock_dom"], D))
+    return immediate
+
+
+@pytest.mark.parametrize("early_exit", [True, False],
+                         ids=["early_exit", "full_budget"])
+def test_deferred_shock_add_is_bit_identical_to_adding_every_step(
+        early_exit, monkeypatch):
+    p, R = MANY_RACKS.replace(
+        fault_domains=FaultTopology(n_racks=64, racks_per_pod=16,
+                                    rack_shock_rate=4e-4,
+                                    pod_shock_rate=4e-4)), 12
+    chunk = vz.DEFAULT_CHUNK_STEPS
+    budget = 6 * chunk + 9                         # a remainder chunk
+    init = vz._initial_state(p, R, None)
+    args = (vz._params_vector(p), jax.random.PRNGKey(7), 1, R, chunk,
+            np.int32(budget // chunk), budget % chunk, None, early_exit,
+            hazards.hazard_kind(p), hazards.repair_kind(p),
+            vz._hist_channels([p]), faultdomains.scenario_key(p))
+
+    def run(state):
+        # a fresh jit each time: the patched step is traced anew
+        return jax.jit(lambda st: vz._chunk_loop(*args, st))(state)
+
+    deferred = run(init)
+    monkeypatch.setattr(vz, "_step_u", _adding_every_step(vz._step_u))
+    at_once = run(dict(init, shocks_now=init["domain_shocks"]))
+    np.testing.assert_array_equal(at_once.pop("domain_shocks"),
+                                  init["domain_shocks"])
+    at_once["domain_shocks"] = at_once.pop("shocks_now")
+    assert set(deferred) == set(at_once)
+    assert "shock_dom" not in deferred
+    for k, v in at_once.items():
+        got, want = np.asarray(deferred[k]), np.asarray(v)
+        assert got.dtype == want.dtype and got.shape == want.shape, k
+        assert got.tobytes() == want.tobytes(), k
+    shocks = np.asarray(deferred["domain_shocks"])
+    assert shocks.sum() > 0
+    np.testing.assert_array_equal(shocks.sum(1), deferred["n_domain_shocks"])

@@ -101,3 +101,26 @@ def test_fig2a_sweep_program_compiles(tpu_target):
     assert args[2] * R_run == ROWS
     _fits_and_has_kernel(run.lower(*_shapes(args, tpu_target),
                                    **kw).compile())
+
+
+def test_llama3_fault_domain_program_compiles(tpu_target):
+    """The Llama 3 cluster's 1,544 fault domains race as 2 level lanes
+    (K = 18), so the race kernel's block fits VMEM; one lane a domain
+    (K = 1,560) needed 146.5M of the chip's 128M.  The cell's grid at
+    1,024 replicas a point: 16,384 rows, the kernel's full block."""
+    import json
+    from pathlib import Path
+
+    from bench import harness
+
+    bench = Path(__file__).resolve().parents[1] / "bench"
+    grid = [Params.from_dict(json.loads(json.dumps(p)))
+            for p in harness.grid_points(
+                harness.load_config("llama3_24k_fault_domains", bench),
+                harness.load_traffic("llama3_pod_grid", bench))]
+    [(_, R_run, run, args, kw)] = vz.sweep_programs(grid, 1024, seed=0,
+                                                    impl="pallas")
+    compiled = run.lower(*_shapes(args, tpu_target), **kw).compile()
+    _fits_and_has_kernel(compiled)
+    # the kernel's rates: K = 18 lanes over the 16,384 rows in 128 x 128
+    assert "f32[18,128,128]" in compiled.as_text()

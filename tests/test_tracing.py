@@ -18,16 +18,23 @@ import jax
 import numpy as np
 import pytest
 
-from repro.core import Params, faultdomains, hazards, run_replications
-from repro.core import run_replications_batch, tracing
+from repro.core import FaultTopology, Params, faultdomains, hazards
+from repro.core import run_replications, run_replications_batch, tracing
 from repro.core import vectorized as vz
 
 BASE = Params(job_size=48, working_pool_size=56, spare_pool_size=8,
               warm_standbys=2, job_length=2000.0)
 GRID = [BASE, BASE.replace(recovery_time=20.0),
         BASE.replace(working_pool_size=60)]
+#: the spans every study records
 HOST_SPANS = tracing.NAMES[:5]
-SCOPES = tracing.NAMES[5:]
+#: the scopes every program family holds; a scenario adds aires.shock
+SCOPES = tuple(n for n in tracing.NAMES[5:]
+               if n not in (tracing.SCENARIO, tracing.SHOCK))
+#: racks and pods shocking often enough to strike every replica
+TOPO = FaultTopology(n_racks=16, racks_per_pod=4, rack_shock_rate=4e-4,
+                     pod_shock_rate=2e-4)
+SCEN_GRID = [p.replace(fault_domains=TOPO) for p in GRID]
 
 
 def profiled(tmp_path, fn):
@@ -61,10 +68,12 @@ def assert_nested(spans):
 
 
 def test_names_are_one_prefixed_tuple():
-    assert len(set(tracing.NAMES)) == len(tracing.NAMES) == 12
+    assert len(set(tracing.NAMES)) == len(tracing.NAMES) == 14
     assert all(n.startswith("aires.") for n in tracing.NAMES)
     assert tracing.COUNTERS == ("chunks_run", "active_row_chunks",
-                                "steps_run", "hist_flushes")
+                                "steps_run", "hist_flushes",
+                                "domain_shocks_total", "shock_killed_total")
+    assert set(tracing.SUMMED) < set(tracing.COUNTERS)
 
 
 def test_run_replications_records_each_span_once(tmp_path):
@@ -237,18 +246,67 @@ def test_every_scope_reaches_the_compiled_program():
     text = run.lower(*args, **kw).as_text(debug_info=True)
     for scope in SCOPES:
         assert scope in text, scope
+    assert tracing.SHOCK not in text
+
+
+def test_shock_scope_reaches_a_scenario_program():
+    [(_, _, run, args, kw)] = vz.sweep_programs(SCEN_GRID, 8, seed=0)
+    text = run.lower(*args, **kw).as_text(debug_info=True)
+    # exponential repairs: no repair-slot lane
+    for scope in set(SCOPES) - {tracing.REPAIR_LANE} | {tracing.SHOCK}:
+        assert scope in text, scope
+
+
+def test_scenario_spans_sit_inside_prepare(tmp_path):
+    reps, spans = profiled(tmp_path, lambda: run_replications_batch(
+        SCEN_GRID, 12, engine="ctmc", base_seed=4))
+    assert counted(spans) == {tracing.STUDY: 1, tracing.PREPARE: 1,
+                              tracing.WAIT: 1, tracing.TRANSFER: 1,
+                              tracing.AGGREGATE: 3}
+    assert_nested(spans)
+    [(_, lo, hi, _)] = [sp for sp in spans if sp[0] == tracing.PREPARE]
+    scen = [sp for sp in spans if sp[0] == tracing.SCENARIO]
+    # each point's parameter columns and step budget
+    assert len(scen) >= len(SCEN_GRID)
+    assert all(lo <= s <= e <= hi for _, s, e, _ in scen)
+    # the counters on the transfer span are the sums of the outputs
+    [tr] = [a for n, _, _, a in spans if n == tracing.TRANSFER]
+    shocks = sum(r.arrays["n_domain_shocks"].sum() for r in reps)
+    killed = sum(r.arrays["n_shock_killed"].sum() for r in reps)
+    assert tr["domain_shocks_total"] == int(shocks) > 0
+    assert tr["shock_killed_total"] == int(killed) > 0
+
+
+@pytest.mark.parametrize("p", [BASE, SCEN_GRID[0]],
+                         ids=["no_scenario", "scenario"])
+def test_shock_counters_equal_the_outputs(p):
+    c, out = _counters(20 * vz.DEFAULT_CHUNK_STEPS, early_exit=True, p=p)
+    assert c["domain_shocks_total"] == int(np.sum(out["n_domain_shocks"]))
+    assert c["shock_killed_total"] == int(np.sum(out["n_shock_killed"]))
+    assert (c["domain_shocks_total"] > 0) == (p.fault_domains is not None)
 
 
 SHARDED = textwrap.dedent("""
     import json
     import jax
-    from repro.core import Params, tracing
+    import numpy as np
+    from repro.core import FaultTopology, Params, tracing
     from repro.core import vectorized as vz
 
     assert jax.device_count() == 4
     p = Params(job_size=48, working_pool_size=56, spare_pool_size=8,
                warm_standbys=2, job_length=2000.0)
     grid = [p, p.replace(recovery_time=20.0)]
+    # shocks on four shards: the counters against the outputs' sums
+    topo = FaultTopology(n_racks=16, racks_per_pod=4,
+                         rack_shock_rate=4e-4, pod_shock_rate=2e-4)
+    [(_, _, run, args, kw)] = vz.sweep_programs(
+        [q.replace(fault_domains=topo) for q in grid], 32, seed=7, shards=4)
+    out = run(*args, **kw)
+    scen = {k: out[k].tolist() for k in tracing.COUNTERS}
+    scen_args = tracing.counter_args({k: out[k] for k in tracing.COUNTERS})
+    sums = {m: int(np.asarray(out[m]).sum())
+            for m in ("n_domain_shocks", "n_shock_killed")}
     [(_, _, run, args, kw)] = vz.sweep_programs(grid, 32, seed=7, shards=4)
     out = run(*args, **kw)
     shards = {k: out[k].tolist() for k in tracing.COUNTERS}
@@ -260,11 +318,14 @@ SHARDED = textwrap.dedent("""
     out = run(*args, **kw)
     assert "hist_bins" not in out
     rem = {k: out[k].tolist() for k in tracing.COUNTERS}
-    print(json.dumps({"shards": shards, "rem": rem, "args": span_args}))
+    print(json.dumps({"shards": shards, "rem": rem, "args": span_args,
+                      "scen": scen, "scen_args": scen_args,
+                      "sums": sums}))
 """)
 
 
-def test_sharded_counters_survive_one_per_shard():
+@pytest.fixture(scope="module")
+def sharded_run():
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4")
     src = str(Path(__file__).resolve().parents[1] / "src")
@@ -273,7 +334,25 @@ def test_sharded_counters_survive_one_per_shard():
     done = subprocess.run([sys.executable, "-c", SHARDED], env=env,
                           capture_output=True, text=True, timeout=600)
     assert done.returncode == 0, done.stderr[-4000:]
-    got = json.loads(done.stdout.strip().splitlines()[-1])
+    return json.loads(done.stdout.strip().splitlines()[-1])
+
+
+def test_sharded_shock_counters_add_up_to_the_outputs(sharded_run):
+    scen, args, sums = (sharded_run["scen"], sharded_run["scen_args"],
+                        sharded_run["sums"])
+    assert all(len(v) == 4 for v in scen.values())
+    # one value per shard, each shard's own sum
+    assert all(v > 0 for v in scen["domain_shocks_total"])
+    assert sum(scen["domain_shocks_total"]) == sums["n_domain_shocks"]
+    assert sum(scen["shock_killed_total"]) == sums["n_shock_killed"]
+    assert args["domain_shocks_total"] == sums["n_domain_shocks"]
+    assert args["shock_killed_total"] == sums["n_shock_killed"]
+    # the plain grid has no scenario: every shard reads 0
+    assert sharded_run["shards"]["domain_shocks_total"] == [0] * 4
+
+
+def test_sharded_counters_survive_one_per_shard(sharded_run):
+    got = sharded_run
     shards, args = got["shards"], got["args"]
     chunk = vz.DEFAULT_CHUNK_STEPS
     assert all(len(v) == 4 for v in shards.values())
