@@ -89,7 +89,7 @@ class Replications:
 def _from_arrays(arrays: Dict[str, np.ndarray], n: int,
                  point: int = 0) -> Replications:
     """Host statistics of one point's per-replica arrays."""
-    with tracing.span(tracing.AGGREGATE, point=point):
+    with tracing.span(tracing.AGGREGATE, point=point) as span:
         incomplete = int(n - arrays["completed"].sum())
         if incomplete:
             warnings.warn(
@@ -108,10 +108,13 @@ def _from_arrays(arrays: Dict[str, np.ndarray], n: int,
                 "biased) — raise Params.repair_slots",
                 RuntimeWarning, stacklevel=3)
         hists = histograms_from_arrays(arrays)
-        return Replications(
-            engine="ctmc", n=n,
-            stats=aggregate_arrays(arrays, histograms=hists),
-            arrays=arrays, histograms=hists)
+        stats = aggregate_arrays(arrays, histograms=hists)
+        # the run records pooled into run_duration_pooled (none where
+        # the ring was compiled out), the bulk of this span's work
+        span.set_metadata(pooled=int(np.minimum(
+            arrays["n_runs"], arrays["run_durations"].shape[1]).sum()))
+        return Replications(engine="ctmc", n=n, stats=stats,
+                            arrays=arrays, histograms=hists)
 
 
 def _from_results(results: List[RunResult], n: int,

@@ -280,8 +280,8 @@ def percentiles_per_row(edges: SpecOrEdges, counts_2d: np.ndarray,
         raise ValueError(
             f"counts shape {counts.shape} does not match "
             f"(R, {len(edges) + 1}) for {len(edges)} edges")
-    total = counts.sum(axis=1)
     cum = np.cumsum(counts, axis=1)
+    total = cum[:, -1]      # exact: counts are whole numbers below 2**53
     target = q / 100.0 * total
     i = np.sum(cum < target[:, None], axis=1)          # searchsorted left
     i = np.minimum(i, counts.shape[1] - 1)

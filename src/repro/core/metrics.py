@@ -12,7 +12,7 @@ from __future__ import annotations
 import dataclasses
 import math
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -146,6 +146,39 @@ _HIST_PERCENTILES = (25, 50, 75, 90, 99, 99.9)
 REPLICA_TAIL_PERCENTILE = 99
 
 
+#: the quantiles of :data:`_PERCENTILES`, divided as np.percentile does
+_QUANTILES = np.true_divide(_PERCENTILES, 100)
+
+
+def _order_stats(s: np.ndarray) -> Tuple[float, Dict[int, float]]:
+    """``np.median`` and ``np.percentile(..., p)`` for every p in
+    :data:`_PERCENTILES` of the values of ``s``, a non-empty sorted
+    float64 vector.
+
+    Numpy's own rules, step for step, so every value is bit-identical:
+    the median is the mean of the one or two middle values; percentile
+    q's virtual index is (n - 1) q, and the linear method interpolates
+    from its floor to the next value (the last value stands for both
+    at the last index), from the lower value below a weight of 0.5 and
+    back from the upper one from 0.5 on.  A NaN anywhere makes all of
+    them NaN.
+    """
+    n = s.size
+    if np.isnan(s[-1]):     # NaN sorts last
+        nan = float("nan")
+        return nan, {p: nan for p in _PERCENTILES}
+    median = float(s[(n - 1) // 2:n // 2 + 1].mean())
+    virtual = (n - 1) * _QUANTILES
+    lo = np.floor(virtual).astype(np.intp)
+    hi = np.minimum(lo + 1, n - 1)
+    gamma = virtual - lo
+    below, above = s[lo], s[hi]
+    diff = above - below
+    vals = np.where(gamma >= 0.5, above - diff * (1 - gamma),
+                    below + diff * gamma)
+    return median, dict(zip(_PERCENTILES, vals.tolist()))
+
+
 @dataclass(frozen=True)
 class Stat:
     mean: float
@@ -157,19 +190,23 @@ class Stat:
 
     @classmethod
     def of(cls, xs: Sequence[float]) -> "Stat":
-        a = np.asarray(list(xs), dtype=np.float64)
+        # an ndarray converts as it is; lists and generators go through
+        # a list (the values are the same either way)
+        a = np.asarray(xs if isinstance(xs, np.ndarray) else list(xs),
+                       dtype=np.float64)
         if a.size == 0:
             # empty inputs (empty sweeps, zero recorded runs) must yield
-            # a well-formed NaN Stat, never raise from np.percentile
+            # a well-formed NaN Stat, never raise
             nan = float("nan")
             return cls(nan, nan, nan, nan, nan, {p: nan for p in _PERCENTILES})
+        median, percentiles = _order_stats(np.sort(a, axis=None))
         return cls(
             mean=float(a.mean()),
-            median=float(np.median(a)),
+            median=median,
             std=float(a.std(ddof=1)) if a.size > 1 else 0.0,
             minimum=float(a.min()),
             maximum=float(a.max()),
-            percentiles={p: float(np.percentile(a, p)) for p in _PERCENTILES},
+            percentiles=percentiles,
         )
 
     @classmethod
@@ -350,20 +387,25 @@ def aggregate_arrays(arrays: Dict[str, np.ndarray],
                                                    np.float64)
     exact = "run_durations" in arrays and "n_runs" in arrays
     if exact:
-        buf = np.asarray(arrays["run_durations"], np.float64)
+        buf = np.asarray(arrays["run_durations"])
         n_runs = np.asarray(arrays["n_runs"], np.int64)
         max_runs = buf.shape[1]
         n_valid = np.minimum(n_runs, max_runs)
-        valid = np.arange(max_runs)[None, :] < n_valid[:, None]
         recorded_total = (
             np.asarray(arrays["useful_work"], np.float64)
             + np.asarray(arrays.get("lost_work", zeros), np.float64)
             - np.asarray(arrays.get("cur_run", zeros), np.float64))
         derived["mean_run_duration"] = np.where(
             n_runs > 0, recorded_total / np.maximum(n_runs, 1), 0.0)
-        # max_runs=0 means recording was compiled out: pool the (still
-        # exact) per-replica means instead of individual intervals
-        pooled = buf[valid] if max_runs else derived["mean_run_duration"]
+        # the surviving records in row-major order; max_runs=0 means
+        # recording was compiled out: pool the (still exact) per-replica
+        # means instead of individual intervals
+        if not max_runs:
+            pooled = derived["mean_run_duration"]
+        elif (n_valid == max_runs).all():   # every ring full: no gather
+            pooled = buf.reshape(-1)
+        else:
+            pooled = buf[np.arange(max_runs)[None, :] < n_valid[:, None]]
         truncated = (n_runs - n_valid).astype(np.float64)
     else:
         derived["mean_run_duration"] = total_time / (

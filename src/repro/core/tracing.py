@@ -10,7 +10,8 @@ until a trace is taken.  Run any CTMC study under ``jax.profiler.trace``
   ``aires.wait`` (the compiled call until its outputs are ready, and the
   fetch of the counters), ``aires.transfer`` (device-to-host copy and
   per-point slicing) and one ``aires.aggregate`` per point (the host
-  statistics).  Inside ``aires.prepare``, ``aires.scenario`` covers the
+  statistics; ``pooled`` counts the run records its stats pooled).
+  Inside ``aires.prepare``, ``aires.scenario`` covers the
   host-side set-up of fault domains and campaigns: one span per point
   around its parameter columns (kill fractions among them) and one
   around its step budget; a study without a scenario has none.  Each span's keyword arguments

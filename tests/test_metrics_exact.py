@@ -183,3 +183,220 @@ def test_fallback_approximation_for_foreign_arrays():
     assert stats["mean_run_duration"].mean == pytest.approx(
         (100.0 / 2 + 200.0 / 4) / 2)
     assert stats["run_duration_truncated"].mean == 0.0
+
+
+# ---------------------------------------------------------------------------
+# identity with the straightforward order statistics
+# ---------------------------------------------------------------------------
+# Stat.of reads the median and every percentile from one sort, and
+# aggregate_arrays pools a full ring without a gather.  The references
+# below are the plain forms they replace, kept verbatim: every field
+# must come out equal (NaN to NaN).
+
+def _reference_stat(xs):
+    from repro.core.metrics import Stat, _PERCENTILES
+
+    a = np.asarray(list(xs), dtype=np.float64)
+    if a.size == 0:
+        nan = float("nan")
+        return Stat(nan, nan, nan, nan, nan, {p: nan for p in _PERCENTILES})
+    return Stat(
+        mean=float(a.mean()),
+        median=float(np.median(a)),
+        std=float(a.std(ddof=1)) if a.size > 1 else 0.0,
+        minimum=float(a.min()),
+        maximum=float(a.max()),
+        percentiles={p: float(np.percentile(a, p)) for p in _PERCENTILES},
+    )
+
+
+def _reference_percentiles_per_row(edges, counts_2d, q):
+    counts = np.asarray(counts_2d, np.float64)
+    total = counts.sum(axis=1)
+    cum = np.cumsum(counts, axis=1)
+    target = q / 100.0 * total
+    i = np.sum(cum < target[:, None], axis=1)
+    i = np.minimum(i, counts.shape[1] - 1)
+    lo_edges = np.concatenate([[0.0], edges])
+    hi_edges = np.concatenate([edges, [edges[-1]]])
+    below = np.where(i > 0,
+                     np.take_along_axis(cum, np.maximum(i - 1, 0)[:, None],
+                                        axis=1)[:, 0], 0.0)
+    in_bin = np.take_along_axis(counts, i[:, None], axis=1)[:, 0]
+    frac = np.clip((target - below) / np.maximum(in_bin, 1e-30), 0.0, 1.0)
+    val = lo_edges[i] + frac * (hi_edges[i] - lo_edges[i])
+    val = np.where(i == counts.shape[1] - 1, edges[-1], val)
+    return np.where(total > 0, val, np.nan)
+
+
+def _reference_aggregate_arrays(arrays):
+    from repro.core.metrics import (_SCALAR_METRICS, REPLICA_TAIL_PERCENTILE,
+                                    Stat, histograms_from_arrays)
+    from repro.core.histograms import HIST_CHANNELS
+
+    R = len(next(iter(arrays.values())))
+    zeros = np.zeros(R, dtype=np.float64)
+    total_time = np.asarray(arrays["total_time"], np.float64)
+    safe_total = np.maximum(total_time, 1e-12)
+    useful = np.asarray(arrays["useful_work"], np.float64)
+    derived = {
+        "overhead_fraction": np.where(total_time > 0,
+                                      1.0 - useful / safe_total, 0.0),
+        "goodput": np.where(total_time > 0, useful / safe_total, 0.0),
+    }
+    if "completed" in arrays:
+        derived["n_incomplete"] = 1.0 - np.asarray(arrays["completed"],
+                                                   np.float64)
+    if "run_durations" in arrays and "n_runs" in arrays:
+        buf = np.asarray(arrays["run_durations"], np.float64)
+        n_runs = np.asarray(arrays["n_runs"], np.int64)
+        max_runs = buf.shape[1]
+        n_valid = np.minimum(n_runs, max_runs)
+        valid = np.arange(max_runs)[None, :] < n_valid[:, None]
+        recorded_total = (
+            useful + np.asarray(arrays.get("lost_work", zeros), np.float64)
+            - np.asarray(arrays.get("cur_run", zeros), np.float64))
+        derived["mean_run_duration"] = np.where(
+            n_runs > 0, recorded_total / np.maximum(n_runs, 1), 0.0)
+        pooled = buf[valid] if max_runs else derived["mean_run_duration"]
+        truncated = (n_runs - n_valid).astype(np.float64)
+    else:
+        derived["mean_run_duration"] = total_time / (
+            np.asarray(arrays["n_failures"], np.float64) + 1.0)
+        pooled = derived["mean_run_duration"]
+        truncated = zeros
+    out = {}
+    for name in _SCALAR_METRICS:
+        if name in arrays:
+            xs = np.asarray(arrays[name], np.float64)
+        else:
+            xs = derived.get(name, zeros)
+        out[name] = _reference_stat(xs)
+    if "completed" in arrays:
+        out["completed"] = _reference_stat(
+            np.asarray(arrays["completed"], np.float64))
+    out["run_duration_pooled"] = _reference_stat(pooled)
+    out["run_duration_truncated"] = _reference_stat(truncated)
+    for ch, h in histograms_from_arrays(arrays).items():
+        out[f"{ch}_dist"] = Stat.from_histogram(h)
+    if "hist_edges" in arrays:
+        edges = np.asarray(arrays["hist_edges"], np.float64)
+        for ch in HIST_CHANNELS:
+            if f"hist_{ch}" in arrays:
+                per = _reference_percentiles_per_row(
+                    edges, arrays[f"hist_{ch}"], REPLICA_TAIL_PERCENTILE)
+                out[f"{ch}_p{REPLICA_TAIL_PERCENTILE}_replica"] = (
+                    _reference_stat(per[np.isfinite(per)]))
+    return out
+
+
+def _assert_same_stat(got, want):
+    def same(a, b):
+        return a == b or (np.isnan(a) and np.isnan(b))
+
+    for f in ("mean", "median", "std", "minimum", "maximum"):
+        assert same(getattr(got, f), getattr(want, f)), f
+    assert got.percentiles.keys() == want.percentiles.keys()
+    for p in want.percentiles:
+        assert same(got.percentiles[p], want.percentiles[p]), p
+
+
+def _rng():
+    return np.random.default_rng(17)
+
+
+_INF = float("inf")
+_NAN = float("nan")
+#: name -> a function making the same input afresh (a generator is
+#: spent once)
+_STAT_INPUTS = {
+    "empty_array": lambda: np.zeros(0),
+    "empty_list": lambda: [],
+    "size1": lambda: np.array([3.25]),
+    "size2": lambda: np.array([7.0, -2.5]),
+    "size3": lambda: np.array([0.1, 0.7, 0.3]),
+    "size4": lambda: np.array([4.0, 1.0, 3.0, 2.0]),
+    "ring_2097152_float32": lambda: _rng().exponential(
+        400.0, 2_097_152).astype(np.float32),
+    "odd_1001": lambda: _rng().normal(size=1001),
+    "even_1000": lambda: _rng().normal(size=1000),
+    "all_equal": lambda: np.full(33, 2.5),
+    "heavy_ties": lambda: _rng().integers(0, 3, 1000).astype(np.float64),
+    "inf_only": lambda: np.array([_INF]),
+    "inf_both_ends": lambda: np.array([-_INF, 1.0, 2.0, _INF]),
+    "inf_tail": lambda: np.array([1.0, 5.0, _INF, _INF, 2.0]),
+    "neg_inf": lambda: np.array([-_INF, -_INF, 0.0, 5.0, 1.0]),
+    "nan": lambda: np.array([1.0, _NAN, 3.0]),
+    "nan_only": lambda: np.array([_NAN]),
+    "nan_and_inf": lambda: np.array([_INF, 2.0, _NAN, -_INF]),
+    "int32": lambda: _rng().integers(-50, 5000, 4099).astype(np.int32),
+    "bool": lambda: _rng().random(777) < 0.3,
+    "float32": lambda: _rng().lognormal(3.0, 1.2, 5000).astype(np.float32),
+    "float64": lambda: _rng().lognormal(3.0, 1.2, 5000),
+    "list": lambda: [3.0, 1.5, 9.0, 2.0, 2.0, 8.5],
+    "generator": lambda: (float(i % 7) for i in range(50)),
+}
+
+
+@pytest.mark.filterwarnings("ignore:invalid value:RuntimeWarning")
+@pytest.mark.parametrize("name", list(_STAT_INPUTS))
+def test_stat_of_matches_plain_order_statistics(name):
+    from repro.core.metrics import Stat
+
+    make = _STAT_INPUTS[name]
+    _assert_same_stat(Stat.of(make()), _reference_stat(make()))
+
+
+def _hand_built_arrays(kind, R=9, max_runs=5, seed=3):
+    """Arrays shaped as the CTMC engine returns them: float32 and int32
+    lanes, a float32 run ring, and (R, n_bins + 2) float64 histogram
+    channels that are views into one (R, channels, slots) block."""
+    from repro.core.histograms import HistogramSpec
+
+    rng = np.random.default_rng(seed)
+    useful = rng.uniform(500.0, 900.0, R).astype(np.float32)
+    arrays = {
+        "total_time": (useful + rng.uniform(0.0, 300.0, R)).astype(
+            np.float32),
+        "useful_work": useful,
+        "lost_work": rng.uniform(0.0, 50.0, R).astype(np.float32),
+        "n_failures": rng.integers(0, 12, R).astype(np.int32),
+        "n_random_failures": rng.integers(0, 6, R).astype(np.int32),
+        "stall_time": rng.exponential(3.0, R).astype(np.float32),
+        "completed": (rng.random(R) < 0.8).astype(np.float32),
+    }
+    if kind == "legacy":
+        return arrays
+    n_runs = {"full_ring": rng.integers(max_runs, 3 * max_runs, R),
+              "partial_ring": rng.integers(0, 2 * max_runs, R),
+              "no_ring": rng.integers(0, 9, R)}[kind]
+    if kind == "partial_ring":
+        n_runs[:2] = (0, max_runs - 1)      # an empty and a short ring
+    width = 0 if kind == "no_ring" else max_runs
+    arrays.update(
+        n_runs=n_runs.astype(np.int32),
+        cur_run=rng.uniform(0.0, 20.0, R).astype(np.float32),
+        run_durations=rng.exponential(60.0, (R, width)).astype(np.float32))
+    spec = HistogramSpec(n_bins=6, channels=("run_duration", "recovery"))
+    block = rng.integers(0, 4, (R, 2, spec.n_bins + 2)).astype(np.float64)
+    block[0, 1] = 0.0                       # a replica with no records
+    block[1:4, 0, :] = [0, 0, 5, 0, 0, 0, 0, 0]   # ties across replicas
+    arrays["hist_edges"] = np.asarray(spec.edges(), np.float64)
+    arrays["hist_run_duration"] = block[:, 0]
+    arrays["hist_recovery"] = block[:, 1]
+    return arrays
+
+
+@pytest.mark.parametrize("kind", ["full_ring", "partial_ring", "no_ring",
+                                  "legacy"])
+def test_aggregate_arrays_matches_plain_aggregation(kind):
+    arrays = _hand_built_arrays(kind)
+    got = aggregate_arrays(arrays)
+    want = _reference_aggregate_arrays(arrays)
+    assert got.keys() == want.keys()
+    for name in want:
+        _assert_same_stat(got[name], want[name])
+    if kind == "full_ring":
+        assert (arrays["n_runs"] >= arrays["run_durations"].shape[1]).all()
+    if kind == "partial_ring":
+        assert got["run_duration_truncated"].minimum == 0.0

@@ -115,6 +115,12 @@ def test_batch_records_one_aggregate_per_point(tmp_path):
     points = [a["point"] for n, _, _, a in spans if n == tracing.AGGREGATE]
     assert points == [0, 1, 2]
     assert len(reps) == 3
+    # each point's span counts the run records its stats pooled
+    pooled = [a["pooled"] for n, _, _, a in spans if n == tracing.AGGREGATE]
+    ring = reps[0].arrays["run_durations"].shape[1]
+    assert pooled == [int(np.minimum(r.arrays["n_runs"], ring).sum())
+                      for r in reps]
+    assert min(pooled) > 0
 
 
 def test_each_study_gets_its_own_number(tmp_path):
