@@ -82,11 +82,11 @@ def fig2a_grid():
 
 def kernel_phase(kind: str) -> None:
     """(b) Pallas vs reference race at the engine's real widths."""
-    from benchmarks.engine_perf import correlated_bench_params
     from repro.kernels import ops
 
     B = 16 * REPLICAS
-    n_dom = correlated_bench_params().fault_domains.n_domains
+    # a 16-rack, 4-racks-a-pod topology raced one lane per domain
+    n_dom = 16 + 4
     for k_exp, k_det, label in ((16, 3, "exponential"),
                                 (16 + n_dom, 4, "correlated")):
         kr, ko, kd, kt, kp = jax.random.split(
@@ -218,14 +218,33 @@ def grid_phase(kind: str) -> None:
         check(same or z_ref < Z_REF, f"pallas vs ref |z| = {z_ref:.2f}")
 
 
+def multijob_grid():
+    """Three mixed-size jobs (64/32/16 servers, different lengths)
+    contending for one shared spare pool and one finite repair shop, hot
+    enough (~400 failures a replication) that the engine spends its time
+    on the contention machinery; spare-pool depth x repair servers."""
+    from repro.core import JobSpec, Params
+
+    day = 1440.0
+    cluster = Params(job_size=16, working_pool_size=200,
+                     spare_pool_size=12, job_length=0.5 * day,
+                     random_failure_rate=0.004,
+                     systematic_failure_rate=0.01,
+                     auto_repair_time=180.0, manual_repair_time=480.0,
+                     repair_servers=4, histogram=None, seed=0)
+    jobs = (JobSpec(64, 0.5 * day, warm_standbys=2),
+            JobSpec(32, 0.7 * day, warm_standbys=1),
+            JobSpec(16, 0.6 * day, warm_standbys=1))
+    return [(cluster.replace(spare_pool_size=s, repair_servers=r), jobs)
+            for s in (7, 8, 9, 10) for r in (3, 4)]
+
+
 def multijob_phase(kind: str) -> None:
     """The second compiled engine: 3 jobs sharing one pool and shop."""
-    from benchmarks.engine_perf import (multijob_bench_params,
-                                        multijob_capacity_grid)
     from repro.core import run_multijob_batch
 
-    cluster, jobs = multijob_bench_params()
-    grid = multijob_capacity_grid(cluster, jobs, (7, 8, 9, 10), (3, 4))
+    grid = multijob_grid()
+    jobs = grid[0][1]
     t0 = time.perf_counter()
     reps = run_multijob_batch(grid, MJ_REPLICAS, engine="ctmc",
                               base_seed=SEED)
@@ -246,14 +265,13 @@ def multijob_phase(kind: str) -> None:
 
 def four_chip_phase(kind: str) -> None:
     """The sharded grid on 4 chips against its per-shard references."""
-    from repro.core import run_replications_batch
-    from repro.parallel import sharding as rsharding
+    from repro.core import replicas, run_replications_batch
 
     n = 4
     check(len(jax.devices()) >= n,
           f"--four-chips needs {n} devices, found {len(jax.devices())}")
     grid = fig2a_grid()
-    mesh = rsharding.replica_mesh(n)
+    mesh = replicas.replica_mesh(n)
     ids = sorted(d.id for d in mesh.devices.flat)
     t0 = time.perf_counter()
     sharded = run_replications_batch(
@@ -270,7 +288,7 @@ def four_chip_phase(kind: str) -> None:
           f"a mesh device never held its shard's state: {peaks}")
 
     R_loc = REPLICAS // n
-    keys = rsharding.shard_keys(jax.random.PRNGKey(SEED), n)
+    keys = replicas.shard_keys(jax.random.PRNGKey(SEED), n)
     for s in range(n):
         t0 = time.perf_counter()
         refs = run_replications_batch(grid, R_loc, engine="ctmc",

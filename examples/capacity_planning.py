@@ -254,8 +254,7 @@ if args.shock == "on":
 # what-if: multi-job shared-pool contention (docs/multijob.md)
 # ---------------------------------------------------------------------------
 if args.jobs == "on":
-    from repro.core import JobSpec, MultiJobSweep
-    from repro.core import vectorized_multijob
+    from repro.core import JobSpec, MultiJobSweep, vectorized
 
     # three mixed-size jobs on one 200-server pool: how many spares and
     # repair servers does the *fleet* need?  Job count is the only
@@ -273,12 +272,12 @@ if args.jobs == "on":
     print(f"\n=== what-if: 3 mixed-size jobs (64/32/16) on one shared "
           f"pool, spare x repair-server grid, engine=auto, {n_rep_mj} "
           f"reps ===")
-    compiles_before = vectorized_multijob.compile_cache_size()
+    compiles_before = vectorized.compile_cache_size()
     mj = MultiJobSweep("fleet-capacity", mj_jobs, "spare_pool_size",
                        [8, 10, 12], parameter_b="repair_servers",
                        values_b=[3, 4], n_replications=n_rep_mj,
                        base_params=mj_cluster, engine="auto").run()
-    compiles_after = vectorized_multijob.compile_cache_size()
+    compiles_after = vectorized.compile_cache_size()
     compiles = compiles_after - compiles_before
     print(f"{'spares':>7} {'shop':>5} {'engine':>7} {'makespan h':>11} "
           f"{'stalls':>7} {'queued':>7} {'job0 h':>7} {'job2 h':>7}")

@@ -1,15 +1,14 @@
 #!/usr/bin/env bash
 # Tier-1 verification in one command (what the roadmap calls "tier-1
-# verify"), plus the machine-readable sweep-performance artifact.
+# verify"): tests, doctests, link check, standalone reruns, the forced
+# 4-device sharding suite and the hazard-fitting smoke.  The engine's
+# speed is measured on the chip (bench/, BENCHMARK.json), not here.
 #
-#   scripts/ci.sh           # tests + compile smokes + quick bench gate
-#   scripts/ci.sh --bench   # also: full sweep benchmarks -> BENCH_sweep.json,
-#                           #       gated against the committed baseline and
-#                           #       appended to BENCH_history.jsonl
+#   scripts/ci.sh
 #
 # Environment knobs (the hosted workflow sets these):
-#   CI_ARTIFACTS_DIR  if set, write pytest junit XML + the smoke/bench
-#                     output there for upload as CI artifacts
+#   CI_ARTIFACTS_DIR  if set, write pytest junit XML there for upload as
+#                     a CI artifact
 set -euo pipefail
 cd "$(dirname "$0")/.." || exit 1
 
@@ -58,7 +57,7 @@ python -m pytest -q -p no:randomly -p no:cacheprovider --doctest-modules \
     src/repro/core/backend.py src/repro/core/sweeps.py \
     src/repro/core/vectorized.py src/repro/core/hazards.py \
     src/repro/core/faultdomains.py src/repro/core/empirical.py \
-    src/repro/parallel/sharding.py src/repro/kernels/ops.py
+    src/repro/core/replicas.py src/repro/kernels/ops.py
 
 # docs suite link check: every relative markdown link in README/docs
 # must resolve to a real file (no network; scheme links are skipped)
@@ -86,38 +85,3 @@ XLA_FLAGS="--xla_force_host_platform_device_count=4" \
 # trace-driven fitting smoke: synthetic log -> fit_piecewise_hazard ->
 # JSON round trip -> a short CTMC study from the fitted hazard
 python scripts/fit_hazard.py --selftest
-
-# compile-count smokes: a tiny mixed-structure grid must compile exactly
-# one XLA program per padded group, two same-bucket sweeps of different
-# (P, R, step-budget) must share exactly one program, a
-# repair-parameter grid under non-exponential repairs must compile
-# once, a mixed-size multi-job capacity grid must compile once
-# (J is the only static key), and a traced (checkpoint_interval x
-# checkpoint_cost) grid must compile once — plus a golden-section
-# optimizer micro-run pinning its bounds/eval-count contract; exits
-# nonzero on any regression.
-if [[ -n "${CI_ARTIFACTS_DIR:-}" ]]; then
-    python benchmarks/engine_perf.py --smoke \
-        | tee "${CI_ARTIFACTS_DIR}/bench_smoke.json"
-else
-    python benchmarks/engine_perf.py --smoke
-fi
-
-# bench regression gate, quick mode: scaled-down warm-speedup
-# measurements against the committed BENCH_sweep.json baselines (loose
-# tolerance — catches a fast path silently collapsing, not noise)
-python scripts/check_bench.py --quick
-
-if [[ "${1:-}" == "--bench" ]]; then
-    # full benchmarks regenerate BENCH_sweep.json; gate the fresh
-    # numbers against the pre-run baseline and append the perf record
-    BASELINE="$(mktemp)"
-    cp BENCH_sweep.json "${BASELINE}"
-    python benchmarks/engine_perf.py
-    python scripts/check_bench.py --baseline "${BASELINE}" \
-        --fresh BENCH_sweep.json --append-history BENCH_history.jsonl
-    rm -f "${BASELINE}"
-    if [[ -n "${CI_ARTIFACTS_DIR:-}" ]]; then
-        cp BENCH_sweep.json "${CI_ARTIFACTS_DIR}/BENCH_sweep.json"
-    fi
-fi

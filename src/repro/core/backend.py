@@ -148,17 +148,14 @@ def run_replications_batch(params_list: Sequence[Params], n: int,
                            impl: Optional[str] = None,
                            max_steps: Optional[int] = None,
                            progress: Optional[Callable[[int], None]] = None,
-                           padded: bool = True,
                            bucketed: bool = True,
                            ) -> List[Replications]:
     """Replication studies for a whole sweep grid, batched where possible.
 
     Every point that resolves to the CTMC engine is executed in a single
-    ``vectorized.simulate_ctmc_sweep`` call — with ``padded=True`` (the
-    default) even a mixed-structure grid compiles exactly one XLA
-    program; ``padded=False`` keeps the legacy one-program-per-structure
-    grouping for A/B benchmarks.  ``bucketed=True`` (default, padded path
-    only) additionally rounds the (points, replicas, step-budget) shape
+    ``vectorized.simulate_ctmc_sweep`` call — even a mixed-structure grid
+    compiles exactly one XLA program.  ``bucketed=True`` (default)
+    additionally rounds the (points, replicas, step-budget) shape
     signature up to its power-of-two bucket with inert padding rows, so
     repeated sweeps of different sizes reuse one compiled program.  The
     rest run through the event engine one by one.  Results come back in
@@ -197,8 +194,7 @@ def run_replications_batch(params_list: Sequence[Params], n: int,
         with tracing.study(points=len(ctmc_idx), replicas=n):
             arrays_list = vectorized.simulate_ctmc_sweep(
                 [params_list[i] for i in ctmc_idx], n_replicas=n,
-                seed=seed, impl=impl, max_steps=max_steps, padded=padded,
-                bucketed=bucketed)
+                seed=seed, impl=impl, max_steps=max_steps, bucketed=bucketed)
             for i, arrays in zip(ctmc_idx, arrays_list):
                 out[i] = _from_arrays(arrays, n, point=i)
 
@@ -260,9 +256,11 @@ class MultiJobReplications:
     histograms: Dict[str, Histogram] = field(default_factory=dict)
 
 
-def _multijob_from_arrays(point: Dict[str, object],
-                          n: int) -> MultiJobReplications:
-    agg = aggregate_multijob_arrays(point)
+def _multijob_from_arrays(point: Dict[str, object], n: int,
+                          index: int = 0) -> MultiJobReplications:
+    """Host statistics of one multi-job point's arrays."""
+    with tracing.span(tracing.AGGREGATE, point=index):
+        agg = aggregate_multijob_arrays(point)
     per_job = []
     for arrays, stats, hists in zip(point["per_job"], agg["per_job"],
                                     agg["per_job_histograms"]):
@@ -350,11 +348,12 @@ def run_multijob_batch(points: Sequence, n: int, engine: str = "auto",
     if ctmc_idx:
         seed = (points[ctmc_idx[0]][0].seed if base_seed is None
                 else base_seed)
-        point_outs = vectorized_multijob.simulate_multijob_ctmc_sweep(
-            [points[i] for i in ctmc_idx], n_replicas=n, seed=seed,
-            impl=impl, max_steps=max_steps)
-        for i, po in zip(ctmc_idx, point_outs):
-            out[i] = _multijob_from_arrays(po, n)
+        with tracing.study(points=len(ctmc_idx), replicas=n):
+            point_outs = vectorized_multijob.simulate_multijob_ctmc_sweep(
+                [points[i] for i in ctmc_idx], n_replicas=n, seed=seed,
+                impl=impl, max_steps=max_steps)
+            for i, po in zip(ctmc_idx, point_outs):
+                out[i] = _multijob_from_arrays(po, n, i)
 
     for i, c in enumerate(chosen):
         if c == "event":

@@ -182,7 +182,7 @@ class Params:
     #: honored exactly by both engines.  ``None`` disables.
     campaign: Optional[Campaign] = None
     #: shard the CTMC engine's replica axis over this many local devices
-    #: via ``shard_map`` (see :mod:`repro.parallel.sharding` and
+    #: via ``shard_map`` (see :mod:`repro.core.replicas` and
     #: docs/scaling.md).  0 (default) = unsharded single-device dispatch;
     #: 1 = a one-device mesh (bit-identical to 0, guarded by tests);
     #: N > 1 splits each point's replicas into N independently-seeded

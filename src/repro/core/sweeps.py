@@ -16,9 +16,8 @@ route through :mod:`repro.core.backend`, which batches every grid point
 that fits the vectorized CTMC engine's envelope into a single compiled
 XLA program and runs the rest through the event-driven engine.  Thanks
 to structure padding this includes *structural* sweeps (job_size, pool
-sizes, warm_standbys, ...): a mixed-structure grid still compiles once
-(``padded=False`` opts back into per-structure compilation for A/B
-measurements).  Failure-hazard and repair-distribution *families* are
+sizes, warm_standbys, ...): a mixed-structure grid still compiles
+once.  Failure-hazard and repair-distribution *families* are
 static compile switches (one batch per combination), but their
 *parameters* stay traced — a repair-policy grid over
 ``auto_repair_time`` / ``manual_repair_time`` under Weibull or
@@ -187,7 +186,7 @@ class OneWaySweep:
     def __init__(self, title: str, parameter: str, values: Sequence[Any],
                  n_replications: int = 5, base_params: Optional[Params] = None,
                  base_seed: int = 0, engine: str = "auto",
-                 padded: bool = True, bucketed: bool = True):
+                 bucketed: bool = True):
         self.title = title
         self.parameter = parameter
         self.values = list(values)
@@ -195,7 +194,6 @@ class OneWaySweep:
         self.base_params = base_params or Params()
         self.base_seed = base_seed
         self.engine = engine
-        self.padded = padded
         self.bucketed = bucketed
 
     def run(self, progress: Optional[Callable[[str], None]] = None) -> SweepResult:
@@ -210,7 +208,6 @@ class OneWaySweep:
         reps = run_replications_batch(grid, self.n_replications,
                                       engine=self.engine,
                                       base_seed=self.base_seed, progress=cb,
-                                      padded=self.padded,
                                       bucketed=self.bucketed)
         points = [SweepPoint.of({self.parameter: v}, rep)
                   for v, rep in zip(self.values, reps)]
@@ -242,7 +239,7 @@ class TwoWaySweep:
                  parameter_b: str, values_b: Sequence[Any],
                  n_replications: int = 5, base_params: Optional[Params] = None,
                  base_seed: int = 0, engine: str = "auto",
-                 padded: bool = True, bucketed: bool = True):
+                 bucketed: bool = True):
         self.title = title
         self.parameter_a, self.values_a = parameter_a, list(values_a)
         self.parameter_b, self.values_b = parameter_b, list(values_b)
@@ -250,7 +247,6 @@ class TwoWaySweep:
         self.base_params = base_params or Params()
         self.base_seed = base_seed
         self.engine = engine
-        self.padded = padded
         self.bucketed = bucketed
 
     def run(self, progress: Optional[Callable[[str], None]] = None) -> SweepResult:
@@ -265,7 +261,6 @@ class TwoWaySweep:
         reps = run_replications_batch(grid, self.n_replications,
                                       engine=self.engine,
                                       base_seed=self.base_seed, progress=cb,
-                                      padded=self.padded,
                                       bucketed=self.bucketed)
         points = [SweepPoint.of({self.parameter_a: va, self.parameter_b: vb},
                                 rep)

@@ -21,10 +21,9 @@ x 4 pools + the two repair shops), so differing pool structures differ
 only in the *initial occupancy values*, which are traced inputs.  A
 point with smaller pools leaves the surplus compartments at zero
 occupancy; zero-count compartments contribute zero rates and are inert
-in the event race.  ``simulate_ctmc_sweep(padded=True)`` (the default)
-exploits this to run a mixed-structure grid as one flat ``(P*R,)`` batch
-with exactly one XLA compilation; ``padded=False`` keeps the legacy
-one-program-per-:func:`_struct_key` grouping for A/B benchmarking.
+in the event race.  :func:`simulate_ctmc_sweep` exploits this to run a
+mixed-structure grid as one flat ``(P*R,)`` batch with exactly one XLA
+compilation.
 
 Exact run durations: the scan carries a per-replica ring buffer of the
 last ``max_runs`` failure-to-failure useful-compute intervals (the event
@@ -60,7 +59,7 @@ one XLA program, and ``checkpoint_interval=0`` leaves the residual at
 +inf — the same program, bit-identical trajectories and uniform stream.
 
 Shape bucketing: on top of structure padding, ``simulate_ctmc_sweep``
-(``bucketed=True``, the default on the padded path) rounds the point
+(``bucketed=True``, the default) rounds the point
 count P and replica count R up to powers of two with *inert* padding
 rows (phase DONE from step 0, zero rates, masked out of extraction) and
 rounds the step budget up to a whole number of chunks with the chunk
@@ -164,6 +163,7 @@ domains / campaigns combined with non-exponential repairs.
 
 from __future__ import annotations
 
+import dataclasses
 import math
 from functools import partial, reduce
 from typing import Dict, Optional
@@ -174,8 +174,7 @@ import numpy as np
 from jax.sharding import PartitionSpec
 
 from repro.kernels import ops
-from repro.parallel import sharding as rsharding
-from . import faultdomains, hazards, tracing
+from . import faultdomains, hazards, replicas, tracing
 from .histograms import DEFAULT_CHANNELS, HIST_CHANNELS
 from .params import Params
 
@@ -475,8 +474,9 @@ def _bucket_pad_state(state: Dict[str, jnp.ndarray], P: int, R: int,
                       P_pad: int, R_pad: int) -> Dict[str, jnp.ndarray]:
     """Pad a (P*R, ...) point-major state to (P_pad*R_pad, ...).
 
-    Padding rows start in phase DONE with zero occupancies, so they carry
-    zero rates and are inert for the entire scan — including the global
+    Padding rows start in phase DONE (every job of the row, where the
+    phase has a job axis) with zero occupancies, so they carry zero
+    rates and are inert for the entire scan — including the global
     early-exit check.  Extraction masks them out; only the shared shape
     signature (and therefore the compiled program) sees them.
     """
@@ -490,7 +490,9 @@ def _bucket_pad_state(state: Dict[str, jnp.ndarray], P: int, R: int,
         out[k] = jnp.pad(v, pad).reshape((P_pad * R_pad,) + v.shape[2:])
     real = ((jnp.arange(P_pad * R_pad) // R_pad < P)
             & (jnp.arange(P_pad * R_pad) % R_pad < R))
-    out["phase"] = jnp.where(real, out["phase"], DONE)
+    phase = out["phase"]
+    out["phase"] = jnp.where(real.reshape((-1,) + (1,) * (phase.ndim - 1)),
+                             phase, DONE)
     return out
 
 
@@ -1602,42 +1604,66 @@ def default_max_steps(p: Params, safety: float = 2.0) -> int:
 DEFAULT_CHUNK_STEPS = 64
 
 
-def _struct_key(p: Params):
-    """Hashable identity of a point's pool *structure*.
+@dataclasses.dataclass(frozen=True)
+class _SingleJobStep:
+    """The single-job engine as the chunk loop's static ``step``: the
+    static arguments of :func:`_step_u`, hashed by value, so two studies
+    of one signature share a compiled program."""
 
-    With structure padding the compiled program no longer depends on any
-    of this — initial occupancies are traced inputs — so the padded sweep
-    path ignores it (``struct_key=None`` -> one compile).  It remains the
-    grouping key of the legacy ``padded=False`` path, where it is passed
-    as a static jit argument precisely to force one XLA program per
-    structure (the behavior the structural-sweep benchmark A/Bs against).
-    """
-    return (p.job_size, p.working_pool_size, p.spare_pool_size,
-            p.warm_standbys, round(p.systematic_failure_fraction, 6),
-            round(p.job_length, 3), round(p.host_selection_time, 3))
+    impl: Optional[str]
+    kind: str
+    rkind: str
+    hist_channels: tuple
+    scen: object
+    n_seg: int = 0
+    n_rseg: int = 0
+
+    @property
+    def n_uniforms(self) -> int:
+        return _n_uniforms(self.kind, self.rkind)
+
+    def __call__(self, s, u, pv):
+        return _step_u(s, u, pv, self.impl, self.kind, self.rkind,
+                       self.hist_channels, self.scen, self.n_seg,
+                       self.n_rseg)
+
+    def extract(self, state, rows) -> Dict[str, np.ndarray]:
+        return _extract(state, rows, self.hist_channels)
+
+
+def _single_job_step(p: Params, impl: Optional[str] = None):
+    """The step of ``p`` (``impl`` defaults to its
+    ``Params.event_race_impl``)."""
+    return _SingleJobStep(
+        p.event_race_impl if impl is None else impl,
+        hazards.hazard_kind(p), hazards.repair_kind(p), _hist_channels([p]),
+        faultdomains.scenario_key(p), hazards.hazard_segment_count(p),
+        hazards.repair_segment_count(p))
 
 
 def _chunk_loop(pv: jnp.ndarray, key: jax.Array, P: int, R: int,
-                chunk: int, n_chunks, rem: int, impl: Optional[str],
-                early_exit: bool, kind: str, rkind: str,
-                hist_channels: tuple, scen,
-                init_state: Dict[str, jnp.ndarray],
-                n_seg: int = 0, n_rseg: int = 0):
+                chunk: int, n_chunks, rem: int, early_exit: bool, step,
+                init_state: Dict[str, jnp.ndarray]):
     """Chunked scan with early exit; batch axis is B = P * R (point-major).
 
-    The shared compute core of :func:`_run_chunked` (single-device jit)
-    and :func:`_run_chunked_sharded` (per-shard body under shard_map,
-    where R is the shard-local replica count and ``key`` the shard's
-    folded key).  Runs exactly ``n_chunks * chunk + rem`` steps (minus
-    chunks skipped by early exit).  ``n_chunks`` is a *traced* scalar —
-    the while-loop trip count — so any two budgets with the same chunk
-    size and remainder share one compiled program (the bucketed sweep
-    path rounds the budget so ``rem == 0`` always).  Uniforms are drawn
-    per *replica column* at the power-of-two width ``next_pow2(R)`` and
+    The one chunk loop of both compiled engines: ``step`` is the engine
+    (:class:`_SingleJobStep`, or the multi-job engine's step), called as
+    ``step(state, u, pv)`` with ``step.n_uniforms`` uniforms a row.  The
+    shared compute core of :func:`_run_chunked` (single-device jit) and
+    :func:`_run_chunked_sharded` (per-shard body under shard_map, where R
+    is the shard-local replica count and ``key`` the shard's folded
+    key).  Runs exactly ``n_chunks * chunk + rem`` steps (minus chunks
+    skipped by early exit).  ``n_chunks`` is a *traced* scalar — the
+    while-loop trip count — so any two budgets with the same chunk size
+    and remainder share one compiled program (the bucketed sweep path
+    rounds the budget so ``rem == 0`` always).  Uniforms are drawn per
+    *replica column* at the power-of-two width ``next_pow2(R)`` and
     sliced to R, then tiled across the P points: every sweep point sees
     common random numbers (the batched analogue of the event engine's
     same-seed-per-replication policy), and a bucket-padded run draws the
-    identical stream for its real replica columns.
+    identical stream for its real replica columns.  A ``phase`` with a
+    job axis (the multi-job state) keeps a row running while any of its
+    jobs is.
     """
     # every op of the program sits under the chunk scope (loop body,
     # exit check, remainder chunk and the outputs' final fix-up)
@@ -1665,8 +1691,7 @@ def _chunk_loop(pv: jnp.ndarray, key: jax.Array, P: int, R: int,
             if P > 1:
                 with jax.named_scope(tracing.CRN_TILE):
                     u = jnp.tile(u, (P, 1))
-            state = _step_u(state, u, pv, impl, kind, rkind, hist_channels,
-                            scen, n_seg, n_rseg)
+            state = step(state, u, pv)
             return state, (tuple(state["hist_bins"]) if has_hist else None,
                            state.get("shock_dom"))
 
@@ -1677,7 +1702,7 @@ def _chunk_loop(pv: jnp.ndarray, key: jax.Array, P: int, R: int,
             with jax.named_scope(tracing.DRAW):
                 us = jax.random.uniform(
                     jax.random.fold_in(key, i),
-                    (n_steps, R_draw, _n_uniforms(kind, rkind)),
+                    (n_steps, R_draw, step.n_uniforms),
                     dtype=jnp.float32, minval=1e-12, maxval=1.0)
                 if R_draw != R:
                     us = us[:, :R]
@@ -1695,7 +1720,10 @@ def _chunk_loop(pv: jnp.ndarray, key: jax.Array, P: int, R: int,
             i, active_rows, flushes, hist, shocks, state = carry
             # rows still running as the chunk starts (padding rows start
             # DONE and never count)
-            active_rows += jnp.sum(state["phase"] != DONE, dtype=jnp.int32)
+            active = state["phase"] != DONE
+            if active.ndim > 1:
+                active = jnp.any(active, axis=-1)
+            active_rows += jnp.sum(active, dtype=jnp.int32)
             return (i + 1, active_rows,
                     *run_chunk(flushes, hist, shocks, state, i, chunk))
 
@@ -1734,8 +1762,9 @@ def _chunk_loop(pv: jnp.ndarray, key: jax.Array, P: int, R: int,
             state.pop("shock_dom")
             state["domain_shocks"] = shocks
         state["completed"] = (state["phase"] == DONE).astype(jnp.float32)
+        t = state["t"] if state["phase"].ndim == 1 else state["t"][:, None]
         state["total_time"] = jnp.where(state["phase"] == DONE,
-                                        state["total_time"], state["t"])
+                                        state["total_time"], t)
         # the counters (tracing.COUNTERS), never part of the simulated
         # outputs: full chunks the early-exit loop executed (the remainder
         # chunk not counted), the sum over those chunks of the rows active
@@ -1749,49 +1778,33 @@ def _chunk_loop(pv: jnp.ndarray, key: jax.Array, P: int, R: int,
         state["hist_flushes"] = flushes
         for counter, metric in (("domain_shocks_total", "n_domain_shocks"),
                                 ("shock_killed_total", "n_shock_killed")):
-            state[counter] = (jnp.int32(0) if scen is None else jnp.sum(
+            state[counter] = (jnp.int32(0) if step.scen is None else jnp.sum(
                 state[metric].astype(jnp.int32)))
     return state
 
 
-@partial(jax.jit, static_argnames=("P", "R", "chunk", "rem", "impl",
-                                   "early_exit", "struct_key", "kind",
-                                   "rkind", "hist_channels", "scen",
-                                   "n_seg", "n_rseg"))
+@partial(jax.jit, static_argnames=("P", "R", "chunk", "rem", "early_exit",
+                                   "step"))
 def _run_chunked(pv: jnp.ndarray, key: jax.Array, P: int, R: int,
-                 chunk: int, n_chunks, rem: int, impl: Optional[str],
-                 early_exit: bool, struct_key, kind: str, rkind: str,
-                 hist_channels: tuple, scen,
-                 init_state: Dict[str, jnp.ndarray],
-                 n_seg: int = 0, n_rseg: int = 0):
-    """Single-device jit entry over :func:`_chunk_loop` (see there).
-
-    ``struct_key`` is unused in the body — it is a static argument
-    precisely so the legacy ``padded=False`` path compiles one program
-    per structure.
-    """
-    return _chunk_loop(pv, key, P, R, chunk, n_chunks, rem, impl,
-                       early_exit, kind, rkind, hist_channels, scen,
-                       init_state, n_seg, n_rseg)
+                 chunk: int, n_chunks, rem: int, early_exit: bool, step,
+                 init_state: Dict[str, jnp.ndarray]):
+    """Single-device jit entry over :func:`_chunk_loop` (see there)."""
+    return _chunk_loop(pv, key, P, R, chunk, n_chunks, rem, early_exit,
+                       step, init_state)
 
 
 @partial(jax.jit, static_argnames=("mesh", "P", "R", "chunk", "rem",
-                                   "impl", "early_exit", "struct_key",
-                                   "kind", "rkind", "hist_channels",
-                                   "scen", "n_seg", "n_rseg"))
+                                   "early_exit", "step"))
 def _run_chunked_sharded(pv: jnp.ndarray, keys: jax.Array, P: int, R: int,
-                         chunk: int, n_chunks, rem: int,
-                         impl: Optional[str], early_exit: bool, struct_key,
-                         kind: str, rkind: str, hist_channels: tuple, scen,
-                         init_state: Dict[str, jnp.ndarray],
-                         n_seg: int = 0, n_rseg: int = 0, *, mesh):
+                         chunk: int, n_chunks, rem: int, early_exit: bool,
+                         step, init_state: Dict[str, jnp.ndarray], *, mesh):
     """Replica-sharded twin of :func:`_run_chunked` via ``shard_map``.
 
     Reshapes every batched state leaf ``(P*R, ...) -> (P, R, ...)``,
     shards the replica axis over the 1-D device mesh
-    (:func:`repro.parallel.sharding.replica_mesh`), and runs
+    (:func:`repro.core.replicas.replica_mesh`), and runs
     :func:`_chunk_loop` independently per shard with that shard's folded
-    key (``keys`` is the :func:`repro.parallel.sharding.shard_keys`
+    key (``keys`` is the :func:`repro.core.replicas.shard_keys`
     stack, one row per device).  There are no collectives inside the
     body — shards early-exit independently — and the ``out_specs``
     concatenation IS the cross-device merge: every output lane
@@ -1806,13 +1819,13 @@ def _run_chunked_sharded(pv: jnp.ndarray, keys: jax.Array, P: int, R: int,
     output is bit-identical to the unsharded engine (pinned by
     tests/test_replica_sharding.py).
     """
-    n_shards = mesh.shape[rsharding.REPLICA_AXIS]
+    n_shards = mesh.shape[replicas.REPLICA_AXIS]
     R_loc = R // n_shards
     unbatched = {k: init_state[k] for k in _UNBATCHED_STATE
                  if k in init_state}
     state = {k: v.reshape((P, R) + v.shape[1:])
              for k, v in init_state.items() if k not in unbatched}
-    rspec = PartitionSpec(None, rsharding.REPLICA_AXIS)
+    rspec = PartitionSpec(None, replicas.REPLICA_AXIS)
     # simulate_ctmc passes one shared (n_cols,) parameter vector
     # (replicated); the sweep path passes per-row (P*R, n_cols) columns
     # (sharded like the state)
@@ -1821,7 +1834,7 @@ def _run_chunked_sharded(pv: jnp.ndarray, keys: jax.Array, P: int, R: int,
     pv_spec = rspec if pv_batched else PartitionSpec()
     out_specs = {k: rspec for k in list(state) + ["completed"]}
     # the counters come back one per shard: shards exit independently
-    out_specs.update({k: PartitionSpec(rsharding.REPLICA_AXIS)
+    out_specs.update({k: PartitionSpec(replicas.REPLICA_AXIS)
                       for k in tracing.COUNTERS})
 
     def body(keys_s, pv_s, n_chunks_s, unbatched_s, state_s):
@@ -1830,10 +1843,8 @@ def _run_chunked_sharded(pv: jnp.ndarray, keys: jax.Array, P: int, R: int,
         flat.update(unbatched_s)
         pv_flat = (pv_s.reshape(P * R_loc, pv_s.shape[-1])
                    if pv_batched else pv_s)
-        out = _chunk_loop(pv_flat,
-                          keys_s[0], P, R_loc, chunk, n_chunks_s, rem,
-                          impl, early_exit, kind, rkind, hist_channels,
-                          scen, flat, n_seg, n_rseg)
+        out = _chunk_loop(pv_flat, keys_s[0], P, R_loc, chunk, n_chunks_s,
+                          rem, early_exit, step, flat)
         for k in unbatched_s:
             out.pop(k)
         counters = {k: out.pop(k).reshape(1) for k in tracing.COUNTERS}
@@ -1842,10 +1853,10 @@ def _run_chunked_sharded(pv: jnp.ndarray, keys: jax.Array, P: int, R: int,
 
     out = jax.shard_map(
         body, mesh=mesh,
-        in_specs=(PartitionSpec(rsharding.REPLICA_AXIS), pv_spec,
+        in_specs=(PartitionSpec(replicas.REPLICA_AXIS), pv_spec,
                   PartitionSpec(),
                   {k: PartitionSpec() for k in unbatched},
-                  rsharding.replica_state_specs(state)),
+                  replicas.replica_state_specs(state)),
         out_specs=out_specs, check_vma=False,
     )(keys, pv2, n_chunks, unbatched, state)
     out = {k: v if k in tracing.COUNTERS
@@ -1855,13 +1866,14 @@ def _run_chunked_sharded(pv: jnp.ndarray, keys: jax.Array, P: int, R: int,
 
 
 def compile_cache_size() -> int:
-    """Compiled-program cache entries of the chunked-scan driver.
+    """Compiled-program cache entries of the chunked-scan driver (both
+    engines).
 
     One entry per distinct static signature = one XLA compilation; the
-    structural-sweep smoke (scripts/ci.sh) and benchmarks diff this
-    around a sweep to assert the padded path's one-compile invariant.
-    Reads jax's ``PjitFunction._cache_size``: a jax without it fails
-    here, loudly, rather than letting a compile guard pass unchecked.
+    compile-count tests diff this around a sweep to assert the one-compile
+    invariant.  Reads jax's ``PjitFunction._cache_size``: a jax without
+    it fails here, loudly, rather than letting a compile guard pass
+    unchecked.
     """
     return _run_chunked._cache_size()
 
@@ -1869,10 +1881,8 @@ def compile_cache_size() -> int:
 def shard_compile_cache_size() -> int:
     """Compiled-program cache entries of the *sharded* chunked driver.
 
-    The sharded weak-scaling benchmark diffs this around repeated sweeps
-    to assert the sharded path keeps the one-compile invariant (the mesh
-    object is part of the static signature, so re-running at the same
-    device count reuses one program).
+    The mesh object is part of the static signature, so re-running at
+    the same device count reuses one program.
     """
     return _run_chunked_sharded._cache_size()
 
@@ -1908,7 +1918,7 @@ def _shard_mesh(n_shards: int, R: int):
             f"columns.  Choose a divisor; bucketed sweeps round R up to "
             f"a power of two, so any power-of-two shard count <= R "
             f"divides it (docs/scaling.md)")
-    return rsharding.replica_mesh(n_shards)
+    return replicas.replica_mesh(n_shards)
 
 
 def _unsupported_error(params: Params) -> ValueError:
@@ -1954,6 +1964,87 @@ def _hist_channels(pts) -> tuple:
     return _selected_channels(pts[0].histogram)
 
 
+def _build_programs(groups, n_replicas: int, seed, max_steps, chunk_steps,
+                    early_exit: bool, bucketed: bool, shards: int, budget,
+                    params_vector, initial_state) -> list:
+    """The compiled chunk-loop calls of a sweep, unrun — both engines'.
+
+    ``groups`` lists ``(idxs, step, pts)``: the points ``pts`` (at
+    indices ``idxs`` of the sweep) that run as one batch through the
+    chunk loop's static ``step``.  The engine brings a point's step
+    budget (``budget(point)``), its parameter vector
+    (``params_vector(point)``) and a group's initial state
+    (``initial_state(pts, R, step)``, point-major ``(P*R, ...)``).
+
+    A derived budget (``max_steps`` None) is the largest of the group's,
+    rounded up to whole chunks when ``bucketed``; ``bucketed`` also
+    rounds P and R up to powers of two with inert rows (the parameter
+    rows edge-padded, the state by :func:`_bucket_pad_state`).  A
+    non-zero ``shards`` runs the group replica-sharded.
+
+    One ``(idxs, R_run, run, args, kwargs)`` entry per group:
+    ``run(*args, **kwargs)`` is the jitted chunk driver call, at
+    ``R_run`` replica rows per point; ``args`` are ``(pv, key, P, R,
+    chunk, n_chunks, rem, early_exit, step, init_state)``.
+    """
+    key = (seed if isinstance(seed, jax.Array)
+           else jax.random.PRNGKey(seed))
+    programs = []
+    for idxs, step, pts in groups:
+        P, R = len(pts), n_replicas
+        steps = max_steps or max(budget(p) for p in pts)
+        chunk = min(chunk_steps or DEFAULT_CHUNK_STEPS, steps)
+        P_run, R_run = (_next_pow2(P), _next_pow2(R)) if bucketed else (P, R)
+        if bucketed and max_steps is None:
+            # derived default budgets round up to whole chunks (rem
+            # statically 0 -> every such sweep shares one program); an
+            # *explicit* max_steps is still honored exactly — its
+            # remainder stays a static part of the signature, so pass a
+            # chunk multiple (or omit max_steps) for maximal sharing
+            steps = -(-steps // chunk) * chunk
+        pv = jnp.stack([params_vector(p) for p in pts])        # (P, n_cols)
+        if P_run != P:
+            # padding rows are inert (phase DONE); replicating the last
+            # real row keeps every hazard column benign (a zero
+            # bathtub tau would evaluate g(t) to NaN — masked out, but
+            # edge-padding avoids NaNs entering the race at all)
+            pv = jnp.pad(pv, ((0, P_run - P), (0, 0)), mode="edge")
+        pv_flat = jnp.repeat(pv, R_run, axis=0)       # (P_run*R_run, n_cols)
+        init_state = initial_state(pts, R, step)
+        if (P_run, R_run) != (P, R):
+            init_state = _bucket_pad_state(init_state, P, R, P_run, R_run)
+        run_args = (P_run, R_run, chunk, jnp.int32(steps // chunk),
+                    steps % chunk, early_exit, step, init_state)
+        if shards:
+            programs.append((
+                idxs, R_run, _run_chunked_sharded,
+                (pv_flat, replicas.shard_keys(key, shards)) + run_args,
+                {"mesh": _shard_mesh(shards, R_run)}))
+        else:
+            programs.append((idxs, R_run, _run_chunked,
+                             (pv_flat, key) + run_args, {}))
+    return programs
+
+
+def _run_programs(programs, n_points: int, n_replicas: int) -> list:
+    """Run what :func:`_build_programs` built, one program at a time;
+    returns each point's outputs in point order, as its step extracts
+    them from the point's real rows."""
+    results: list = [None] * n_points
+    for idxs, R_run, run, args, kw in programs:
+        out, counters = _wait(run, args, kw)
+        step = args[8]
+        # args: (pv, key, P, R, chunk, ...)
+        with tracing.span(tracing.TRANSFER, chunk=args[4],
+                          real_rows=len(idxs) * n_replicas, **counters):
+            for j, i in enumerate(idxs):
+                rows = (slice(j * R_run, j * R_run + n_replicas)
+                        if R_run == n_replicas
+                        else np.arange(n_replicas) + j * R_run)
+                results[i] = step.extract(out, rows)
+    return results
+
+
 def simulate_ctmc(params: Params, n_replicas: int = 1024, seed: int = 0,
                   max_steps: Optional[int] = None,
                   impl: Optional[str] = None,
@@ -1992,30 +2083,24 @@ def simulate_ctmc(params: Params, n_replicas: int = 1024, seed: int = 0,
         if not supports(params):
             raise _unsupported_error(params)
         params.validate()
-        impl = params.event_race_impl if impl is None else impl
         shards = _resolve_shards(shards, [params])
         max_steps = max_steps or default_max_steps(params)
         chunk = min(chunk_steps or DEFAULT_CHUNK_STEPS, max_steps)
         init_state = _initial_state(params, n_replicas, max_runs)
-        channels = _hist_channels([params])
+        step = _single_job_step(params, impl)
         args = (1, n_replicas, chunk, jnp.int32(max_steps // chunk),
-                max_steps % chunk, impl, early_exit,
-                _struct_key(params), hazards.hazard_kind(params),
-                hazards.repair_kind(params), channels,
-                faultdomains.scenario_key(params), init_state,
-                hazards.hazard_segment_count(params),
-                hazards.repair_segment_count(params))
+                max_steps % chunk, early_exit, step, init_state)
         pv, key = _params_vector(params), jax.random.PRNGKey(seed)
         if shards:
             run, args, kw = (_run_chunked_sharded,
-                             (pv, rsharding.shard_keys(key, shards)) + args,
+                             (pv, replicas.shard_keys(key, shards)) + args,
                              {"mesh": _shard_mesh(shards, n_replicas)})
         else:
             run, args, kw = _run_chunked, (pv, key) + args, {}
     out, counters = _wait(run, args, kw)
     with tracing.span(tracing.TRANSFER, chunk=chunk, real_rows=n_replicas,
                       **counters):
-        return _extract(out, channels=channels)
+        return _extract(out, channels=step.hist_channels)
 
 
 def simulate_ctmc_sweep(params_list, n_replicas: int = 1024, seed=0,
@@ -2023,39 +2108,36 @@ def simulate_ctmc_sweep(params_list, n_replicas: int = 1024, seed=0,
                         impl: Optional[str] = None,
                         chunk_steps: Optional[int] = None,
                         early_exit: bool = True,
-                        padded: bool = True,
                         bucketed: bool = True,
                         max_runs: Optional[int] = None,
                         shards: Optional[int] = None):
     """Batched sweep: one compiled program for the whole grid.
 
     ``params_list`` is a sequence of :class:`Params` (the sweep grid, any
-    order).  With ``padded=True`` (default) the entire grid — even when
-    points differ *structurally* (job_size, pool sizes, warm_standbys,
-    systematic fraction, job_length) — is stacked into one (P, 16)
-    parameter array plus per-point padded initial states, expanded to one
-    row per replica, and the whole (P * R,) batch runs through the same
-    chunked scan as :func:`simulate_ctmc` in a single XLA compilation —
-    the ``event_race`` kernel sees a single flat batch axis, so Pallas
-    block sizes stay aligned.  The step budget is the max over points;
+    order).  The entire grid — even when points differ *structurally*
+    (job_size, pool sizes, warm_standbys, systematic fraction,
+    job_length) — is stacked into one (P, 16) parameter array plus
+    per-point padded initial states, expanded to one row per replica,
+    and the whole (P * R,) batch runs through the same chunked scan as
+    :func:`simulate_ctmc` in a single XLA compilation — the
+    ``event_race`` kernel sees a single flat batch axis, so Pallas block
+    sizes stay aligned.  The step budget is the max over points;
     replicas of cheaper points finish early and sit inert, so the shared
     head-room costs only chunks the early-exit check cannot skip.
+    Per-point results are bit-identical to running each point alone
+    whenever both step budgets suffice (common random numbers are drawn
+    per replica column either way).
 
-    ``padded=False`` restores the legacy grouping — one compiled program
-    per :func:`_struct_key` — for A/B benchmarking; per-point results are
-    bit-identical to the padded path whenever both step budgets suffice
-    (common random numbers are drawn per replica column either way).
-
-    ``bucketed=True`` (the default; only active on the padded path)
-    additionally buckets the *shape* signature: P and R round up to
-    powers of two with inert phase-DONE padding rows, and the chunk
-    count is traced, so repeated sweeps of any size inside one bucket
-    reuse a single XLA program.  A *derived* default budget rounds up to
-    a whole number of chunks (remainder statically 0); an explicit
-    ``max_steps`` is honored exactly.  Real rows are bit-identical to
-    ``bucketed=False`` for any explicit ``max_steps``, and under the
-    default budget whenever every replica finishes (early exit skips
-    the rounded-up head-room); padding rows never reach the caller.
+    ``bucketed=True`` (the default) additionally buckets the *shape*
+    signature: P and R round up to powers of two with inert phase-DONE
+    padding rows, and the chunk count is traced, so repeated sweeps of
+    any size inside one bucket reuse a single XLA program.  A *derived*
+    default budget rounds up to a whole number of chunks (remainder
+    statically 0); an explicit ``max_steps`` is honored exactly.  Real
+    rows are bit-identical to ``bucketed=False`` for any explicit
+    ``max_steps``, and under the default budget whenever every replica
+    finishes (early exit skips the rounded-up head-room); padding rows
+    never reach the caller.
 
     Uniforms are shared across points (the batched analogue of the event
     engine's same-seed-per-replication policy), giving common random
@@ -2076,48 +2158,37 @@ def simulate_ctmc_sweep(params_list, n_replicas: int = 1024, seed=0,
     batch per backend.
 
     ``seed`` is an int or a PRNG key array — a row of
-    :func:`repro.parallel.sharding.shard_keys` reproduces one shard of
+    :func:`repro.core.replicas.shard_keys` reproduces one shard of
     a sharded sweep on its own.
 
     Returns a list of ``{metric: np.ndarray (R,)}`` dicts in input order.
     """
     params_list = list(params_list)
-    results: list = [None] * len(params_list)
     with tracing.span(tracing.PREPARE) as prepare:
         programs = sweep_programs(
             params_list, n_replicas, seed, max_steps, impl, chunk_steps,
-            early_exit, padded, bucketed, max_runs, shards)
+            early_exit, bucketed, max_runs, shards)
         prepare.set_metadata(
             rows=sum(args[0].shape[0] for _, _, _, args, _ in programs),
             real_rows=len(params_list) * n_replicas)
-    for idxs, R_run, run, args, kw in programs:
-        out, counters = _wait(run, args, kw)
-        channels = _hist_channels(params_list)   # params_list is non-empty
-        # args: (pv, key, P, R, chunk, ...)
-        with tracing.span(tracing.TRANSFER, chunk=args[4],
-                          real_rows=len(idxs) * n_replicas, **counters):
-            for j, i in enumerate(idxs):
-                rows = (slice(j * R_run, j * R_run + n_replicas)
-                        if R_run == n_replicas
-                        else np.arange(n_replicas) + j * R_run)
-                results[i] = _extract(out, rows, channels)
-    return results
+    return _run_programs(programs, len(params_list), n_replicas)
 
 
 def sweep_programs(params_list, n_replicas: int = 1024, seed=0,
                    max_steps: Optional[int] = None,
                    impl: Optional[str] = None,
                    chunk_steps: Optional[int] = None,
-                   early_exit: bool = True, padded: bool = True,
-                   bucketed: bool = True, max_runs: Optional[int] = None,
+                   early_exit: bool = True, bucketed: bool = True,
+                   max_runs: Optional[int] = None,
                    shards: Optional[int] = None) -> list:
     """The compiled programs :func:`simulate_ctmc_sweep` runs, unrun.
 
-    One ``(idxs, R_run, run, args, kwargs)`` entry per compile group:
-    ``run(*args, **kwargs)`` is the jitted chunk driver call for the
-    points ``idxs`` of ``params_list``, at ``R_run`` replica rows per
-    point.  ``run.lower(*args, **kwargs).compile()`` gives the program
-    itself — its text, its memory analysis — without running it.
+    One ``(idxs, R_run, run, args, kwargs)`` entry per compile group
+    (:func:`_build_programs`): ``run(*args, **kwargs)`` is the jitted
+    chunk driver call for the points ``idxs`` of ``params_list``, at
+    ``R_run`` replica rows per point.  ``run.lower(*args,
+    **kwargs).compile()`` gives the program itself — its text, its
+    memory analysis — without running it.
     """
     params_list = list(params_list)
     for p in params_list:
@@ -2139,80 +2210,30 @@ def sweep_programs(params_list, n_replicas: int = 1024, seed=0,
 
     groups: Dict[tuple, list] = {}
     for i, p in enumerate(params_list):
-        # the hazard and repair families are static compile switches
-        # (they change the step program and the uniform-stream width),
-        # so a grid mixing families splits into one batch per
-        # (failure, repair, age-dtype) combination; within a
-        # combination, structure padding keeps the whole sub-grid one
-        # compilation (struct_key None -> one jit cache entry).  Hazard
-        # AND repair *parameters* (k, taus, rates, repair scales/means)
-        # stay traced, so they never split a group — a repair-rate grid
-        # compiles exactly once.
-        kind = hazards.hazard_kind(p)
-        rkind = hazards.repair_kind(p)
-        # the scenario key (rack and pod counts + campaign codes) sizes
-        # the race, the per-domain counts and the trailing parameter
-        # columns, so it splits groups the
-        # same way the hazard family does; shock *rates* and campaign
-        # *times/fractions* stay traced — a shock-rate grid over one
-        # topology compiles exactly once.  Likewise the empirical
-        # family's segment *counts* (they size the column blocks) are
-        # part of the key while edge positions and rates stay traced —
-        # a grid of hazards fitted from different log slices is one
-        # program as long as the fits share a bin count.
-        gkey = (kind, rkind, p.age_dtype, faultdomains.scenario_key(p),
-                hazards.hazard_segment_count(p),
-                hazards.repair_segment_count(p),
-                None if padded else _struct_key(p),
-                # the event-race kernel backend is a static compile
-                # switch; an explicit impl= argument overrides every
-                # point's Params knob (one group), otherwise points
-                # split by their requested backend
-                impl if impl is not None else p.event_race_impl)
-        groups.setdefault(gkey, []).append(i)
+        # the step's static arguments split the grid into one batch
+        # each: the hazard and repair families (they change the step
+        # program and the uniform-stream width), the scenario key (rack
+        # and pod counts + campaign codes: it sizes the race, the
+        # per-domain counts and the trailing parameter columns), the
+        # empirical family's segment *counts* (they size the column
+        # blocks) and the event-race kernel backend (an explicit impl=
+        # argument overrides every point's Params knob); so does the age
+        # dtype.  Within a batch, structure padding keeps the whole
+        # sub-grid one compilation: hazard and repair *parameters*, shock
+        # rates, campaign times and fractions and empirical edge
+        # positions and rates stay traced, so a repair-rate, shock-rate
+        # or fitted-hazard grid compiles exactly once.
+        step = _single_job_step(p, impl)
+        groups.setdefault((p.age_dtype, step), []).append(i)
     mr = _max_runs_for(params_list) if max_runs is None else max_runs
 
-    bucket = padded and bucketed
-    channels = _hist_channels(params_list)
-    key = (seed if isinstance(seed, jax.Array)
-           else jax.random.PRNGKey(seed))
-    programs = []
-    for (kind, rkind, _adt, scen, n_seg, n_rseg, skey, impl_eff), idxs in \
-            groups.items():
-        pts = [params_list[i] for i in idxs]
-        P, R = len(pts), n_replicas
-        steps = max_steps or max(default_max_steps(p) for p in pts)
-        chunk = min(chunk_steps or DEFAULT_CHUNK_STEPS, steps)
-        P_run, R_run = (_next_pow2(P), _next_pow2(R)) if bucket else (P, R)
-        if bucket and max_steps is None:
-            # derived default budgets round up to whole chunks (rem
-            # statically 0 -> every such sweep shares one program); an
-            # *explicit* max_steps is still honored exactly — its
-            # remainder stays a static part of the signature, so pass a
-            # chunk multiple (or omit max_steps) for maximal sharing
-            steps = -(-steps // chunk) * chunk
-        pv = jnp.stack([_params_vector(p) for p in pts])        # (P, n_cols)
-        if P_run != P:
-            # padding rows are inert (phase DONE); replicating the last
-            # real row keeps every hazard column benign (a zero
-            # bathtub tau would evaluate g(t) to NaN — masked out, but
-            # edge-padding avoids NaNs entering the race at all)
-            pv = jnp.pad(pv, ((0, P_run - P), (0, 0)), mode="edge")
-        pv_flat = jnp.repeat(pv, R_run, axis=0)       # (P_run*R_run, n_cols)
-        init_state = _initial_state_batch(pts, R, mr, rkind,
-                                          _repair_slots_for(pts, rkind),
-                                          scen)
-        if (P_run, R_run) != (P, R):
-            init_state = _bucket_pad_state(init_state, P, R, P_run, R_run)
-        run_args = (P_run, R_run, chunk, jnp.int32(steps // chunk),
-                    steps % chunk, impl_eff, early_exit, skey, kind,
-                    rkind, channels, scen, init_state, n_seg, n_rseg)
-        if shards:
-            programs.append((
-                idxs, R_run, _run_chunked_sharded,
-                (pv_flat, rsharding.shard_keys(key, shards)) + run_args,
-                {"mesh": _shard_mesh(shards, R_run)}))
-        else:
-            programs.append((idxs, R_run, _run_chunked,
-                             (pv_flat, key) + run_args, {}))
-    return programs
+    def initial_state(pts, R, step):
+        return _initial_state_batch(pts, R, mr, step.rkind,
+                                    _repair_slots_for(pts, step.rkind),
+                                    step.scen)
+
+    return _build_programs(
+        [(idxs, step, [params_list[i] for i in idxs])
+         for (_, step), idxs in groups.items()],
+        n_replicas, seed, max_steps, chunk_steps, early_exit, bucketed,
+        shards, default_max_steps, _params_vector, initial_state)

@@ -31,6 +31,14 @@ rates, warm-standby targets, and pool/shop capacities are all traced —
 so a mixed-size capacity grid (spare-pool size x repair servers) runs
 as ONE compiled XLA program via :func:`simulate_multijob_ctmc_sweep`.
 
+The driver is the single-job engine's: this module brings its step
+(:class:`_MultiJobStep` around :func:`_mj_step_u`), parameter vector,
+step budget, initial state and per-point extraction, and
+:func:`repro.core.vectorized._build_programs` and ``_chunk_loop`` do
+the rest — budget rounding, pow2 bucketing, sharding, the chunked scan
+with early exit, the once-per-chunk histogram add, and the ``aires.*``
+spans, scopes and counters of :mod:`repro.core.tracing`.
+
 Dispatch semantics promoted from the event engine's ``Dispatcher``:
 
   * a repaired server goes to the **longest-stalled** job first (FIFO
@@ -62,7 +70,7 @@ picks, batch-proportional release hand-offs).
 
 from __future__ import annotations
 
-from functools import partial
+import dataclasses
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -70,12 +78,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops
-from . import hazards
+from . import hazards, tracing
+from . import vectorized as vz
 from .multijob import JobSpec
 from .params import Params
-from .vectorized import (COMPUTE, DONE, OVERHEAD, STALL, _next_pow2,
-                         _selected_channels, default_max_steps, row_hit)
-from .vectorized import DEFAULT_CHUNK_STEPS
+from .vectorized import (COMPUTE, DONE, OVERHEAD, STALL, _hist_bins,
+                         _hist_flush, _selected_channels, default_max_steps,
+                         row_hit)
 
 #: per-job scalar metrics carried as (B, J) lanes — the per-job
 #: RunResult fields the event oracle reports
@@ -231,7 +240,8 @@ def _mj_initial_state_batch(points: Sequence[Tuple[Params, tuple]],
     spec = points[0][0].histogram
     sel = _selected_channels(spec)
     if sel:
-        state["hist"] = jnp.zeros((B, J, len(sel), spec.n_counts),
+        # job-major channels: row j * len(sel) + c is job j's channel c
+        state["hist"] = jnp.zeros((B, J * len(sel), spec.n_counts),
                                   jnp.float32)
         state["hist_edges"] = jnp.asarray(spec.edges(), jnp.float32)
     for m in _MJ_JOB_METRICS:
@@ -239,27 +249,6 @@ def _mj_initial_state_batch(points: Sequence[Tuple[Params, tuple]],
     for m in _MJ_CLUSTER_METRICS:
         state[m] = jnp.zeros((B,), jnp.float32)
     return state
-
-
-_UNBATCHED = ("hist_edges",)
-
-
-def _mj_bucket_pad(state: Dict[str, jnp.ndarray], P: int, R: int,
-                   P_pad: int, R_pad: int) -> Dict[str, jnp.ndarray]:
-    """Pad a (P*R, ...) point-major state to (P_pad*R_pad, ...) with
-    inert rows (every job DONE from step 0, zero occupancies)."""
-    out: Dict[str, jnp.ndarray] = {}
-    for k, v in state.items():
-        if k in _UNBATCHED:
-            out[k] = v
-            continue
-        v = v.reshape((P, R) + v.shape[1:])
-        pad = [(0, P_pad - P), (0, R_pad - R)] + [(0, 0)] * (v.ndim - 2)
-        out[k] = jnp.pad(v, pad).reshape((P_pad * R_pad,) + v.shape[2:])
-    real = ((jnp.arange(P_pad * R_pad) // R_pad < P)
-            & (jnp.arange(P_pad * R_pad) % R_pad < R))
-    out["phase"] = jnp.where(real[:, None], out["phase"], DONE)
-    return out
 
 
 def _pick_cat(counts: jnp.ndarray, u: jnp.ndarray) -> jnp.ndarray:
@@ -347,7 +336,8 @@ def _mj_step_u(s: Dict[str, jnp.ndarray], u: jnp.ndarray, pv: jnp.ndarray,
         [jnp.where(computing, s["work_left"], jnp.inf),
          jnp.where(in_overhead, s["timer"], jnp.inf)], axis=-1)  # (B, 2J)
 
-    dt, ev = ops.event_race(rates, residuals, u_time, u_pick, impl=impl)
+    with jax.named_scope(tracing.RACE):
+        dt, ev = ops.event_race(rates, residuals, u_time, u_pick, impl=impl)
     dt = jnp.where(active_any & jnp.isfinite(dt), dt, 0.0)
     kx = 16 * J
 
@@ -385,10 +375,11 @@ def _mj_step_u(s: Dict[str, jnp.ndarray], u: jnp.ndarray, pv: jnp.ndarray,
     run_val = s["cur_run"] + progress
     max_runs = s["run_durations"].shape[2]
     if max_runs:
-        hit = row_hit(jnp.mod(s["n_runs"], max_runs), max_runs)  # (B,J,M)
-        ns["run_durations"] = jnp.where(hit & record[..., None],
-                                        run_val[..., None],
-                                        s["run_durations"])
+        with jax.named_scope(tracing.RING):
+            hit = row_hit(jnp.mod(s["n_runs"], max_runs), max_runs)
+            ns["run_durations"] = jnp.where(hit & record[..., None],
+                                            run_val[..., None],
+                                            s["run_durations"])
     ns["n_runs"] = s["n_runs"] + record.astype(jnp.int32)
     ns["cur_run"] = jnp.where(record, 0.0, run_val)
 
@@ -593,18 +584,25 @@ def _mj_step_u(s: Dict[str, jnp.ndarray], u: jnp.ndarray, pv: jnp.ndarray,
     ns["fs"] = ns["fs"] + rel_rem * jnp.asarray([0, 0, 1, 1], jnp.float32)
 
     # ---- streaming per-job histograms -----------------------------------
-    if "hist" in s:
-        channel_vals = {"run_duration": (run_val, record),
-                        "recovery": (downtime, ended),
-                        "waiting": (acquire_wait, ended)}
-        vals = jnp.stack([channel_vals[ch][0] for ch in hist_channels],
-                         axis=2)                               # (B, J, S)
-        masks = jnp.stack([channel_vals[ch][1] for ch in hist_channels],
-                          axis=2)
-        idx = jnp.searchsorted(s["hist_edges"], vals, side="right",
-                               method="compare_all")
-        hit = row_hit(idx, s["hist"].shape[-1]) & masks[..., None]
-        ns["hist"] = s["hist"] + hit.astype(jnp.float32)
+    # as on the single-job step: a state with ``hist`` gets the step's
+    # counts added, one with ``hist_bins`` (the chunk loop's) the step's
+    # record, for the loop to add once per chunk
+    with jax.named_scope(tracing.HIST):
+        if "hist" in s or "hist_bins" in s:
+            channel_vals = {"run_duration": (run_val, record),
+                            "recovery": (downtime, ended),
+                            "waiting": (acquire_wait, ended)}
+            vals = jnp.stack([channel_vals[ch][0] for ch in hist_channels],
+                             axis=2)                           # (B, J, S)
+            masks = jnp.stack([channel_vals[ch][1] for ch in hist_channels],
+                              axis=2)
+            # job-major channels, rows on the last axis: (J * S, B)
+            bins = _hist_bins(s["hist_edges"], vals.reshape(B, -1).T,
+                              masks.reshape(B, -1).T)
+            if "hist" in s:
+                ns["hist"] = _hist_flush(s["hist"], bins[:, None])
+            else:
+                ns["hist_bins"] = bins
 
     # ---- conservation invariant ----------------------------------------
     tot = (ns["run"].sum((-2, -1)) + ns["sb"].sum((-2, -1))
@@ -650,139 +648,25 @@ def default_max_steps_multijob(cluster: Params,
     return steps
 
 
-def _mj_chunk_loop(pv: jnp.ndarray, key: jax.Array, P: int, R: int,
-                   chunk: int, n_chunks, rem: int, J: int,
-                   impl: Optional[str], early_exit: bool,
-                   hist_channels: tuple,
-                   init_state: Dict[str, jnp.ndarray]):
-    """Chunked scan with early exit — the multi-job twin of the
-    single-job ``_chunk_loop`` (same chunking, bucketing, and
-    common-random-number conventions; see that docstring).  Not jitted
-    itself: called from the single-device jit entry
-    :func:`_mj_run_chunked` and from inside the ``shard_map`` body of
-    :func:`_mj_run_chunked_sharded`."""
-    R_draw = _next_pow2(R)
+@dataclasses.dataclass(frozen=True)
+class _MultiJobStep:
+    """The multi-job engine as the chunk loop's static ``step``
+    (:func:`repro.core.vectorized._chunk_loop`): the static arguments of
+    :func:`_mj_step_u`, hashed by value."""
 
-    def scan_body(state, u):
-        if P > 1:
-            u = jnp.tile(u, (P, 1))
-        return _mj_step_u(state, u, pv, J, impl, hist_channels), None
+    J: int
+    impl: Optional[str]
+    hist_channels: tuple
 
-    def run_chunk(state, i, n_steps):
-        us = jax.random.uniform(jax.random.fold_in(key, i),
-                                (n_steps, R_draw, _N_UNIFORMS),
-                                dtype=jnp.float32, minval=1e-12, maxval=1.0)
-        if R_draw != R:
-            us = us[:, :R]
-        state, _ = jax.lax.scan(scan_body, state, us)
-        return state
+    n_uniforms = _N_UNIFORMS
+    #: no fault domains or campaigns on this engine
+    scen = None
 
-    def chunk_body(carry):
-        i, state = carry
-        return i + 1, run_chunk(state, i, chunk)
+    def __call__(self, s, u, pv):
+        return _mj_step_u(s, u, pv, self.J, self.impl, self.hist_channels)
 
-    def cond(carry):
-        i, state = carry
-        not_done = i < n_chunks
-        if early_exit:
-            not_done &= jnp.any(state["phase"] != DONE)
-        return not_done
-
-    _, state = jax.lax.while_loop(cond, chunk_body,
-                                  (jnp.int32(0), init_state))
-    if rem:
-        def do_rem(s):
-            return run_chunk(s, n_chunks, rem)
-
-        if early_exit:
-            state = jax.lax.cond(jnp.any(state["phase"] != DONE),
-                                 do_rem, lambda s: s, state)
-        else:
-            state = do_rem(state)
-    state["completed"] = (state["phase"] == DONE).astype(jnp.float32)
-    state["total_time"] = jnp.where(state["phase"] == DONE,
-                                    state["total_time"],
-                                    state["t"][:, None])
-    return state
-
-
-@partial(jax.jit, static_argnames=("P", "R", "chunk", "rem", "J", "impl",
-                                   "early_exit", "hist_channels"))
-def _mj_run_chunked(pv: jnp.ndarray, key: jax.Array, P: int, R: int,
-                    chunk: int, n_chunks, rem: int, J: int,
-                    impl: Optional[str], early_exit: bool,
-                    hist_channels: tuple,
-                    init_state: Dict[str, jnp.ndarray]):
-    """Single-device jit entry over :func:`_mj_chunk_loop`."""
-    return _mj_chunk_loop(pv, key, P, R, chunk, n_chunks, rem, J, impl,
-                          early_exit, hist_channels, init_state)
-
-
-@partial(jax.jit, static_argnames=("mesh", "P", "R", "chunk", "rem", "J",
-                                   "impl", "early_exit", "hist_channels"))
-def _mj_run_chunked_sharded(pv: jnp.ndarray, keys: jax.Array, P: int,
-                            R: int, chunk: int, n_chunks, rem: int, J: int,
-                            impl: Optional[str], early_exit: bool,
-                            hist_channels: tuple,
-                            init_state: Dict[str, jnp.ndarray], *, mesh):
-    """Replica-sharded twin of :func:`_mj_run_chunked` via ``shard_map``.
-
-    Same contract as the single-job
-    :func:`repro.core.vectorized._run_chunked_sharded`: state leaves
-    reshape ``(P*R, ...) -> (P, R, ...)`` and shard the replica axis
-    over the 1-D mesh, each shard runs :func:`_mj_chunk_loop` with its
-    own folded key, ``hist_edges`` rides along replicated, no
-    collectives (shards early-exit independently), and the ``out_specs``
-    concatenation is the cross-device merge.  A 1-device mesh is
-    bit-identical to :func:`_mj_run_chunked`.
-    """
-    from jax.sharding import PartitionSpec
-
-    from repro.parallel import sharding as rsharding
-
-    n_shards = mesh.shape[rsharding.REPLICA_AXIS]
-    R_loc = R // n_shards
-    unbatched = {k: init_state[k] for k in _UNBATCHED if k in init_state}
-    state = {k: v.reshape((P, R) + v.shape[1:])
-             for k, v in init_state.items() if k not in unbatched}
-    rspec = PartitionSpec(None, rsharding.REPLICA_AXIS)
-    pv2 = pv.reshape((P, R, pv.shape[-1]))
-    out_specs = {k: rspec for k in list(state) + ["completed"]}
-
-    def body(keys_s, pv_s, n_chunks_s, unbatched_s, state_s):
-        flat = {k: v.reshape((P * R_loc,) + v.shape[2:])
-                for k, v in state_s.items()}
-        flat.update(unbatched_s)
-        out = _mj_chunk_loop(pv_s.reshape(P * R_loc, pv_s.shape[-1]),
-                             keys_s[0], P, R_loc, chunk, n_chunks_s, rem,
-                             J, impl, early_exit, hist_channels, flat)
-        for k in unbatched_s:
-            out.pop(k)
-        return {k: v.reshape((P, R_loc) + v.shape[1:])
-                for k, v in out.items()}
-
-    out = jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(PartitionSpec(rsharding.REPLICA_AXIS), rspec,
-                  PartitionSpec(),
-                  {k: PartitionSpec() for k in unbatched},
-                  rsharding.replica_state_specs(state)),
-        out_specs=out_specs, check_vma=False,
-    )(keys, pv2, n_chunks, unbatched, state)
-    out = {k: v.reshape((P * R,) + v.shape[2:]) for k, v in out.items()}
-    out.update(unbatched)
-    return out
-
-
-def compile_cache_size() -> int:
-    """Compiled-program cache entries of the multi-job chunked driver
-    (same contract as :func:`repro.core.vectorized.compile_cache_size`)."""
-    return _mj_run_chunked._cache_size()
-
-
-def shard_compile_cache_size() -> int:
-    """Compiled-program cache entries of the *sharded* multi-job driver."""
-    return _mj_run_chunked_sharded._cache_size()
+    def extract(self, state, rows) -> Dict[str, object]:
+        return _extract_point(state, rows, self.J, self.hist_channels)
 
 
 def _unsupported_error(cluster: Params, jobs) -> ValueError:
@@ -803,6 +687,9 @@ def _extract_point(state, rows, J: int, channels: tuple,
     per_job: List[Dict[str, np.ndarray]] = []
     edges = (np.asarray(state["hist_edges"], np.float64)
              if "hist" in state and channels else None)
+    if edges is not None:
+        hist = np.asarray(state["hist"][rows], np.float64)
+        hist = hist.reshape(hist.shape[0], J, len(channels), -1)
     for j in range(J):
         d: Dict[str, np.ndarray] = {}
         for m in _MJ_JOB_METRICS:
@@ -814,9 +701,8 @@ def _extract_point(state, rows, J: int, channels: tuple,
         d["n_runs"] = np.asarray(state["n_runs"][rows, j])
         d["cur_run"] = np.asarray(state["cur_run"][rows, j])
         if edges is not None:
-            hist = np.asarray(state["hist"][rows, j], np.float64)
             for ch_i, ch in enumerate(channels):
-                d[f"hist_{ch}"] = hist[:, ch_i]
+                d[f"hist_{ch}"] = hist[:, j, ch_i]
             d["hist_edges"] = edges
         per_job.append(d)
     out: Dict[str, object] = {"per_job": per_job}
@@ -877,93 +763,75 @@ def simulate_multijob_ctmc_sweep(
     cache) — the multi-job program is only built when the multi-job
     machinery is actually needed.
     """
-    from . import vectorized as vz
-
     points = [(c, tuple(js)) for c, js in points]
-    for c, js in points:
-        if not supports_multijob(c, js):
-            raise _unsupported_error(c, js)
-        # the cluster-level job fields are unused in multi-job mode;
-        # validate through a per-job surrogate (the event engine's
-        # Coordinator params are built the same way)
-        c.replace(job_size=js[0].job_size, job_length=js[0].job_length,
-                  warm_standbys=js[0].warm_standbys).validate()
-        total_needed = sum(j.job_size + j.warm_standbys for j in js)
-        if c.working_pool_size < total_needed:
+    with tracing.span(tracing.PREPARE) as prepare:
+        for c, js in points:
+            if not supports_multijob(c, js):
+                raise _unsupported_error(c, js)
+            # the cluster-level job fields are unused in multi-job mode;
+            # validate through a per-job surrogate (the event engine's
+            # Coordinator params are built the same way)
+            c.replace(job_size=js[0].job_size, job_length=js[0].job_length,
+                      warm_standbys=js[0].warm_standbys).validate()
+            total_needed = sum(j.job_size + j.warm_standbys for j in js)
+            if c.working_pool_size < total_needed:
+                raise ValueError(
+                    f"working pool {c.working_pool_size} cannot host "
+                    f"{len(js)} jobs needing {total_needed}")
+        if not points:
+            return []
+        if len({c.histogram for c, _ in points}) > 1:
             raise ValueError(
-                f"working pool {c.working_pool_size} cannot host "
-                f"{len(js)} jobs needing {total_needed}")
-    if not points:
-        return []
-    if len({c.histogram for c, _ in points}) > 1:
-        raise ValueError(
-            "all points of a batched multi-job sweep must share the same "
-            "Params.histogram spec (the in-scan accumulator layout is "
-            "per-batch); split the grid or unify the spec")
+                "all points of a batched multi-job sweep must share the "
+                "same Params.histogram spec (the in-scan accumulator "
+                "layout is per-batch); split the grid or unify the spec")
+        channels = _selected_channels(points[0][0].histogram)
+        # replica sharding + kernel dispatch resolve exactly like the
+        # single-job sweep: explicit args win, else the (single) Params
+        # value — a mixed engine_shards grid raises, mixed kernel impls
+        # split the compile groups
+        shards = vz._resolve_shards(shards, [c for c, _ in points])
 
-    results: List[Optional[Dict[str, object]]] = [None] * len(points)
-    channels = _selected_channels(points[0][0].histogram)
-    # replica sharding + kernel dispatch resolve exactly like the
-    # single-job sweep: explicit args win, else the (single) Params
-    # value — a mixed engine_shards grid raises, mixed kernel impls
-    # split the compile groups
-    shards = vz._resolve_shards(shards, [c for c, _ in points])
+        # the single-job reduction, then one group per job count
+        single_idx = [i for i, (c, js) in enumerate(points)
+                      if len(js) == 1 and c.repair_servers == 0]
+        programs = []
+        if single_idx:
+            sp = [points[i][0].replace(job_size=points[i][1][0].job_size,
+                                       job_length=points[i][1][0].job_length,
+                                       warm_standbys=points[i][1][0]
+                                       .warm_standbys)
+                  for i in single_idx]
+            programs = [([single_idx[k] for k in idxs], *rest)
+                        for idxs, *rest in vz.sweep_programs(
+                            sp, n_replicas, seed, max_steps, impl,
+                            chunk_steps, early_exit, bucketed, max_runs,
+                            shards)]
+        groups: Dict[_MultiJobStep, list] = {}
+        for i, (c, js) in enumerate(points):
+            if i not in single_idx:
+                step = _MultiJobStep(
+                    len(js), impl if impl is not None else c.event_race_impl,
+                    channels)
+                groups.setdefault(step, []).append(i)
 
-    # group: the single-job reduction, then one group per job count
-    single_idx = [i for i, (c, js) in enumerate(points)
-                  if len(js) == 1 and c.repair_servers == 0]
-    if single_idx:
-        sp = [points[i][0].replace(job_size=points[i][1][0].job_size,
-                                   job_length=points[i][1][0].job_length,
-                                   warm_standbys=points[i][1][0]
-                                   .warm_standbys)
-              for i in single_idx]
-        outs = vz.simulate_ctmc_sweep(
-            sp, n_replicas=n_replicas, seed=seed, max_steps=max_steps,
-            impl=impl, chunk_steps=chunk_steps, early_exit=early_exit,
-            bucketed=bucketed, max_runs=max_runs, shards=shards)
-        for i, arr in zip(single_idx, outs):
-            results[i] = _wrap_single_job(arr)
+        def initial_state(pts, R, step):
+            mr = (max(c.max_run_records for c, _ in pts) if max_runs is None
+                  else max_runs)
+            return _mj_initial_state_batch(pts, R, mr)
 
-    groups: Dict[tuple, list] = {}
-    for i, (c, js) in enumerate(points):
-        if results[i] is None:
-            impl_eff = impl if impl is not None else c.event_race_impl
-            groups.setdefault((len(js), impl_eff), []).append(i)
-    for (J, impl_eff), idxs in groups.items():
-        pts = [points[i] for i in idxs]
-        P, R = len(pts), n_replicas
-        steps = max_steps or max(default_max_steps_multijob(c, js)
-                                 for c, js in pts)
-        chunk = min(chunk_steps or DEFAULT_CHUNK_STEPS, steps)
-        P_run, R_run = ((_next_pow2(P), _next_pow2(R)) if bucketed
-                        else (P, R))
-        if bucketed and max_steps is None:
-            steps = -(-steps // chunk) * chunk
-        mr = (max(c.max_run_records for c, _ in pts) if max_runs is None
-              else max_runs)
-        pv = jnp.stack([_mj_params_vector(c, js) for c, js in pts])
-        if P_run != P:
-            pv = jnp.pad(pv, ((0, P_run - P), (0, 0)), mode="edge")
-        pv_flat = jnp.repeat(pv, R_run, axis=0)
-        init_state = _mj_initial_state_batch(pts, R, mr)
-        if (P_run, R_run) != (P, R):
-            init_state = _mj_bucket_pad(init_state, P, R, P_run, R_run)
-        run_args = (P_run, R_run, chunk, jnp.int32(steps // chunk),
-                    steps % chunk, J, impl_eff, early_exit, channels,
-                    init_state)
-        key = jax.random.PRNGKey(seed)
-        if shards:
-            from repro.parallel import sharding as rsharding
-            out = _mj_run_chunked_sharded(
-                pv_flat, rsharding.shard_keys(key, shards), *run_args,
-                mesh=vz._shard_mesh(shards, R_run))
-        else:
-            out = _mj_run_chunked(pv_flat, key, *run_args)
-        for jg, i in enumerate(idxs):
-            rows = (slice(jg * R_run, jg * R_run + R) if R_run == R
-                    else np.arange(R) + jg * R_run)
-            results[i] = _extract_point(out, rows, J, channels)
+        programs += vz._build_programs(
+            [(idxs, step, [points[i] for i in idxs])
+             for step, idxs in groups.items()],
+            n_replicas, seed, max_steps, chunk_steps, early_exit, bucketed,
+            shards, lambda pt: default_max_steps_multijob(*pt),
+            lambda pt: _mj_params_vector(*pt), initial_state)
+        prepare.set_metadata(
+            rows=sum(args[0].shape[0] for _, _, _, args, _ in programs),
+            real_rows=len(points) * n_replicas)
+    results = vz._run_programs(programs, len(points), n_replicas)
+    for i in single_idx:
+        results[i] = _wrap_single_job(results[i])
     return results
 
 

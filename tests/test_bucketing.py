@@ -2,8 +2,7 @@
 
 Two invariants: (1) sweeps whose (points, replicas, step-budget)
 signatures fall in the same power-of-two bucket share exactly one
-compiled XLA program (the compile-count regression guard, also run by
-``scripts/ci.sh`` via ``benchmarks/engine_perf.py --smoke``); (2) the
+compiled XLA program (the compile-count regression guard); (2) the
 inert phase-DONE padding rows never leak — real rows are bit-identical
 to the unbucketed path.
 """

@@ -212,6 +212,22 @@ def test_golden_section_bracket_contracts():
     assert res2.objective == res.objective
 
 
+def test_optimizer_interval_stays_inside_its_search_bounds():
+    """A micro-run of the golden-section search on a fast-failing fleet:
+    the interval it returns lies inside its own grid, after one
+    evaluation per grid point and two per refinement step."""
+    fast = Params(job_size=16, working_pool_size=32, spare_pool_size=4,
+                  warm_standbys=2, job_length=0.2 * DAY,
+                  random_failure_rate=2.0 / DAY, recovery_time=5.0,
+                  auto_repair_time=30.0, manual_repair_time=60.0, seed=0,
+                  checkpoint_interval=20.0, checkpoint_cost=2.0)
+    res = optimize_checkpoint_interval(fast, n_replicas=16, n_grid=4,
+                                       refine_iters=2, engine="ctmc")
+    assert min(res.grid) <= res.interval <= max(res.grid)
+    assert len(res.grid) == 4 and res.history
+    assert res.n_evals == 4 + 2 * len(res.history)
+
+
 def test_default_interval_bounds_bracket_young_daly():
     lo, hi = default_interval_bounds(BASE)
     lam = cluster_failure_rate(BASE)

@@ -31,7 +31,7 @@ import numpy as np
 import pytest
 
 from repro.core import (Campaign, CampaignEvent, FaultTopology, OneWaySweep,
-                        Params, Tracer, faultdomains, hazards,
+                        Params, Tracer, faultdomains,
                         resolve_engine, run_replications, simulate,
                         simulate_one)
 from repro.core import vectorized as vz
@@ -536,9 +536,8 @@ def test_deferred_shock_add_is_bit_identical_to_adding_every_step(
     budget = 6 * chunk + 9                         # a remainder chunk
     init = vz._initial_state(p, R, None)
     args = (vz._params_vector(p), jax.random.PRNGKey(7), 1, R, chunk,
-            np.int32(budget // chunk), budget % chunk, None, early_exit,
-            hazards.hazard_kind(p), hazards.repair_kind(p),
-            vz._hist_channels([p]), faultdomains.scenario_key(p))
+            np.int32(budget // chunk), budget % chunk, early_exit,
+            vz._single_job_step(p))
 
     def run(state):
         # a fresh jit each time: the patched step is traced anew

@@ -5,8 +5,8 @@ the scan stacks the records, and one add per chunk folds them into
 ``hist`` (``vectorized._hist_flush``).  These tests pin that this is the
 same, bit for bit, as adding every step's counts at once: one block of
 records against the numpy bin rule and against single-step adds, and a
-whole chunk loop against a scan whose step adds at once.  CPU, tiny
-sizes.
+whole chunk loop against a scan whose step adds at once, on both
+engines.  CPU, tiny sizes.
 """
 
 import json
@@ -17,8 +17,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import Params, hazards
+from repro.core import JobSpec, Params
 from repro.core import vectorized as vz
+from repro.core import vectorized_multijob as vmj
 
 CONFIGS = Path(__file__).resolve().parents[1] / "bench" / "configs"
 CHUNK = vz.DEFAULT_CHUNK_STEPS
@@ -111,9 +112,8 @@ def test_deferred_flush_is_bit_identical_to_adding_every_step(
     budget = 2 * CHUNK + 9                        # a remainder chunk
     init = vz._initial_state(p, R, None)
     args = (vz._params_vector(p), jax.random.PRNGKey(7), 1, R, CHUNK,
-            np.int32(budget // CHUNK), budget % CHUNK, None, early_exit,
-            hazards.hazard_kind(p), hazards.repair_kind(p),
-            vz._hist_channels([p]), None)
+            np.int32(budget // CHUNK), budget % CHUNK, early_exit,
+            vz._single_job_step(p))
 
     def run(state):
         # a fresh jit each time: the patched step is traced anew
@@ -139,3 +139,54 @@ def test_deferred_flush_is_bit_identical_to_adding_every_step(
         assert steps == int(deferred["chunks_run"]) * CHUNK < budget
     else:
         assert steps == budget
+
+
+#: two mixed-size jobs contending for one pool and a finite shop
+MJ_CLUSTER = Params(working_pool_size=110, spare_pool_size=16, job_size=16,
+                    job_length=4000.0, random_failure_rate=0.001,
+                    systematic_failure_rate=0.005, auto_repair_time=180.0,
+                    manual_repair_time=480.0, repair_servers=6)
+MJ_JOBS = (JobSpec(32, 4000.0, warm_standbys=2),
+           JobSpec(16, 6000.0, warm_standbys=1))
+
+
+@pytest.mark.parametrize("early_exit", [True, False],
+                         ids=["early_exit", "full_budget"])
+def test_multijob_deferred_flush_is_bit_identical_to_adding_every_step(
+        early_exit, monkeypatch):
+    """The J = 2 multi-job step through the same chunk loop: its
+    (B, J * S, n_counts) histogram, added once a chunk, equals adding
+    every step's counts at once."""
+    R, J = 12, len(MJ_JOBS)
+    budget = 2 * CHUNK + 9                        # a remainder chunk
+    pv = np.repeat(np.asarray(vmj._mj_params_vector(MJ_CLUSTER, MJ_JOBS))
+                   [None], R, axis=0)
+    init = vmj._mj_initial_state_batch([(MJ_CLUSTER, MJ_JOBS)], R, 8)
+    step = vmj._MultiJobStep(J, None, vz._hist_channels([MJ_CLUSTER]))
+    assert init["hist"].shape == (R, J * len(step.hist_channels),
+                                  MJ_CLUSTER.histogram.n_counts)
+    args = (jnp.asarray(pv), jax.random.PRNGKey(7), 1, R, CHUNK,
+            np.int32(budget // CHUNK), budget % CHUNK, early_exit, step)
+
+    def run(state):
+        # a fresh jit each time: the patched step is traced anew
+        return jax.jit(lambda st: vz._chunk_loop(*args, st))(state)
+
+    deferred = run(init)
+    monkeypatch.setattr(vmj, "_mj_step_u",
+                        adding_every_step(vmj._mj_step_u))
+    at_once = run(dict(init, hist_now=init["hist"]))
+    np.testing.assert_array_equal(at_once.pop("hist"), init["hist"])
+    at_once["hist"] = at_once.pop("hist_now")
+
+    assert set(deferred) == set(at_once)
+    assert "hist_bins" not in deferred
+    for k, v in at_once.items():
+        got, want = np.asarray(deferred[k]), np.asarray(v)
+        assert got.dtype == want.dtype and got.shape == want.shape, k
+        assert got.tobytes() == want.tobytes(), k
+    hist = np.asarray(deferred["hist"])
+    assert hist.sum() > 0
+    steps = int(deferred["steps_run"])
+    assert steps == budget or (early_exit and steps < budget)
+    assert int(deferred["hist_flushes"]) == -(-steps // CHUNK)

@@ -34,10 +34,10 @@ import pytest
 
 from repro.core import (JobSpec, Params, aggregate_multijob_arrays,
                         pool_histograms, resolve_engine_multijob,
-                        run_replications_multijob, simulate_multijob,
-                        simulate_multijob_ctmc_sweep, supports_multijob)
+                        run_multijob_batch, run_replications_multijob,
+                        simulate_multijob, simulate_multijob_ctmc_sweep,
+                        supports_multijob)
 from repro.core import vectorized as vz
-from repro.core import vectorized_multijob as vmj
 from repro.core.multijob import MultiJobSimulation
 
 Z_MAX = 3.5
@@ -248,13 +248,11 @@ def test_one_job_reduction_bit_identical_and_no_new_compiles():
     ref = vz.simulate_ctmc_sweep([sj], n_replicas=64, seed=13)[0]
 
     c_sj = vz.compile_cache_size()
-    c_mj = vmj.compile_cache_size()
     out = simulate_multijob_ctmc_sweep([(single, (spec,))],
                                        n_replicas=64, seed=13)[0]
-    # same compile-cache key class: the 1-job sweep reuses the warm
+    # one driver for both engines: the 1-job sweep reuses the warm
     # single-job program and never builds a multi-job one
     assert vz.compile_cache_size() == c_sj
-    assert vmj.compile_cache_size() == c_mj
 
     assert len(out["per_job"]) == 1
     arrays = out["per_job"][0]
@@ -266,6 +264,29 @@ def test_one_job_reduction_bit_identical_and_no_new_compiles():
                                   np.asarray(ref["total_time"]))
     assert float(np.max(out["conservation_err"])) == 0.0
     assert float(np.max(out["n_shop_queued"])) == 0.0
+
+
+def test_mixed_size_capacity_grid_compiles_once():
+    """A mixed-size capacity grid — spare pool x repair servers, jobs of
+    different sizes and lengths — is one compiled program (J is the only
+    static key), and a second grid in the same bucket reuses it."""
+    day = 1440.0
+    cluster = Params(job_size=12, working_pool_size=40, spare_pool_size=4,
+                     job_length=0.1 * day, random_failure_rate=2.0 / day,
+                     recovery_time=5.0, auto_repair_time=30.0,
+                     manual_repair_time=60.0, repair_servers=2, seed=0,
+                     max_run_records=13)   # shapes no other test compiles
+    jobs = (JobSpec(12, 0.1 * day, warm_standbys=1),
+            JobSpec(8, 0.15 * day, warm_standbys=1))
+    grid = [(cluster.replace(spare_pool_size=s, repair_servers=r), jobs)
+            for s in (3, 4) for r in (2, 3)]
+    c0 = vz.compile_cache_size()
+    reps = run_multijob_batch(grid, 24, engine="ctmc", base_seed=0)
+    assert vz.compile_cache_size() - c0 == 1
+    assert [r.engine for r in reps] == ["ctmc"] * len(grid)
+    assert all(r.fleet["completed"].mean == 1.0 for r in reps)
+    run_multijob_batch(grid[:3], 20, engine="ctmc", base_seed=1)
+    assert vz.compile_cache_size() - c0 == 1
 
 
 def test_infinite_pool_and_shop_factorizes():

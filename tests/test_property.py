@@ -130,8 +130,8 @@ def _ctmc_base(job: int, spare: int, warm: int) -> Params:
 def test_padded_sweep_matches_unpadded_mixed_grid(job, spare, warm, dsize,
                                                   seed):
     """A mixed-structure grid through the single-compilation padded path
-    must reproduce the legacy one-program-per-structure results per point
-    (same seed -> same per-replica-column uniforms on both paths)."""
+    must reproduce each point run alone (same seed -> same
+    per-replica-column uniforms on both paths)."""
     from repro.core.vectorized import simulate_ctmc_sweep
 
     base = _ctmc_base(job, spare, warm)
@@ -139,10 +139,9 @@ def test_padded_sweep_matches_unpadded_mixed_grid(job, spare, warm, dsize,
             base.replace(job_size=job + dsize,
                          working_pool_size=job + dsize + warm + 4),
             base.replace(spare_pool_size=spare + 2)]
-    pad = simulate_ctmc_sweep(grid, n_replicas=16, seed=seed, max_steps=256,
-                              padded=True)
-    ref = simulate_ctmc_sweep(grid, n_replicas=16, seed=seed, max_steps=256,
-                              padded=False)
+    pad = simulate_ctmc_sweep(grid, n_replicas=16, seed=seed, max_steps=256)
+    ref = [simulate_ctmc_sweep([p], n_replicas=16, seed=seed,
+                               max_steps=256)[0] for p in grid]
     for i, (a, b) in enumerate(zip(pad, ref)):
         assert set(a) == set(b)
         for k in a:
@@ -155,16 +154,15 @@ def test_padded_sweep_matches_unpadded_mixed_grid(job, spare, warm, dsize,
 @given(st.integers(4, 8), st.integers(0, 2), st.integers(0, 1000))
 def test_padded_sweep_bit_identical_same_structure(job, warm, seed):
     """Non-structural grids (rates/times only differ) must be bit-identical
-    between the padded and per-structure paths — same program semantics,
-    same random stream."""
+    between the batched grid and each point run alone — same program
+    semantics, same random stream."""
     from repro.core.vectorized import simulate_ctmc_sweep
 
     base = _ctmc_base(job, 2, warm)
     grid = [base.replace(recovery_time=v) for v in (5.0, 15.0)]
-    pad = simulate_ctmc_sweep(grid, n_replicas=16, seed=seed, max_steps=256,
-                              padded=True)
-    ref = simulate_ctmc_sweep(grid, n_replicas=16, seed=seed, max_steps=256,
-                              padded=False)
+    pad = simulate_ctmc_sweep(grid, n_replicas=16, seed=seed, max_steps=256)
+    ref = [simulate_ctmc_sweep([p], n_replicas=16, seed=seed,
+                               max_steps=256)[0] for p in grid]
     for i, (a, b) in enumerate(zip(pad, ref)):
         for k in a:
             np.testing.assert_array_equal(a[k], b[k],

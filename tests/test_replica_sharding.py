@@ -24,10 +24,9 @@ import pytest
 
 import repro.core.vectorized as vz
 import repro.core.vectorized_multijob as mj
-from repro.core import faultdomains, hazards
+from repro.core import replicas
 from repro.core.multijob import JobSpec
 from repro.core.params import Params
-from repro.parallel import sharding as rsharding
 
 N_DEV = jax.device_count()
 
@@ -62,14 +61,14 @@ def assert_same(a, b, path=""):
 
 def test_shard_keys_mesh1_is_base_key():
     key = jax.random.PRNGKey(3)
-    keys = rsharding.shard_keys(key, 1)
+    keys = replicas.shard_keys(key, 1)
     assert keys.shape == (1,) + key.shape
     assert np.array_equal(np.asarray(keys[0]), np.asarray(key))
 
 
 def test_shard_keys_are_folded_and_distinct():
     key = jax.random.PRNGKey(3)
-    keys = rsharding.shard_keys(key, 4)
+    keys = replicas.shard_keys(key, 4)
     assert keys.shape == (4,) + key.shape
     rows = {tuple(np.asarray(k).tolist()) for k in keys}
     assert len(rows) == 4
@@ -80,7 +79,7 @@ def test_shard_keys_are_folded_and_distinct():
 
 def test_replica_mesh_too_many_devices_refused():
     with pytest.raises(ValueError, match="host_platform_device_count"):
-        rsharding.replica_mesh(10 ** 6)
+        replicas.replica_mesh(10 ** 6)
 
 
 # ---------------------------------------------------------------------------
@@ -187,10 +186,8 @@ def _reference_shard(p, key_s, R_loc, max_steps, max_runs=None):
     init_state = vz._initial_state(p, R_loc, max_runs)
     out = vz._run_chunked(
         vz._params_vector(p), key_s, 1, R_loc, chunk,
-        jnp.int32(max_steps // chunk), max_steps % chunk, None, True,
-        vz._struct_key(p), hazards.hazard_kind(p), hazards.repair_kind(p),
-        channels, faultdomains.scenario_key(p), init_state,
-        hazards.hazard_segment_count(p), hazards.repair_segment_count(p))
+        jnp.int32(max_steps // chunk), max_steps % chunk, True,
+        vz._single_job_step(p), init_state)
     return vz._extract(out, channels=channels)
 
 
@@ -204,7 +201,7 @@ def test_per_shard_independence_exact(n_shards):
     R_loc = R // n_shards
     sharded = vz.simulate_ctmc(p, n_replicas=R, seed=3, max_steps=steps,
                                shards=n_shards)
-    keys = rsharding.shard_keys(jax.random.PRNGKey(3), n_shards)
+    keys = replicas.shard_keys(jax.random.PRNGKey(3), n_shards)
     for s in range(n_shards):
         ref = _reference_shard(p, keys[s], R_loc, steps)
         rows = slice(s * R_loc, (s + 1) * R_loc)
@@ -223,7 +220,7 @@ def test_histogram_merge_exact_across_devices():
     R, steps = 64, 512
     sharded = vz.simulate_ctmc(p, n_replicas=R, seed=11, max_steps=steps,
                                shards=4)
-    keys = rsharding.shard_keys(jax.random.PRNGKey(11), 4)
+    keys = replicas.shard_keys(jax.random.PRNGKey(11), 4)
     hist_keys = [k for k in sharded if k.startswith("hist_")
                  and k != "hist_edges"]
     assert hist_keys, "default HistogramSpec should emit channels"

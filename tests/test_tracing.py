@@ -18,9 +18,11 @@ import jax
 import numpy as np
 import pytest
 
-from repro.core import FaultTopology, Params, faultdomains, hazards
-from repro.core import run_replications, run_replications_batch, tracing
+from repro.core import FaultTopology, JobSpec, Params
+from repro.core import run_multijob_batch, run_replications
+from repro.core import run_replications_batch, tracing
 from repro.core import vectorized as vz
+from repro.core import vectorized_multijob as vmj
 
 BASE = Params(job_size=48, working_pool_size=56, spare_pool_size=8,
               warm_standbys=2, job_length=2000.0)
@@ -35,6 +37,15 @@ SCOPES = tuple(n for n in tracing.NAMES[5:]
 TOPO = FaultTopology(n_racks=16, racks_per_pod=4, rack_shock_rate=4e-4,
                      pod_shock_rate=2e-4)
 SCEN_GRID = [p.replace(fault_domains=TOPO) for p in GRID]
+#: two mixed-size jobs sharing one pool and a finite shop, on two pools
+MJ_CLUSTER = Params(working_pool_size=110, spare_pool_size=16, job_size=16,
+                    job_length=4000.0, random_failure_rate=0.001,
+                    systematic_failure_rate=0.005, auto_repair_time=180.0,
+                    manual_repair_time=480.0, repair_servers=6)
+MJ_JOBS = (JobSpec(32, 4000.0, warm_standbys=2),
+           JobSpec(16, 6000.0, warm_standbys=1))
+MJ_GRID = [(MJ_CLUSTER, MJ_JOBS),
+           (MJ_CLUSTER.replace(spare_pool_size=8), MJ_JOBS)]
 
 
 def profiled(tmp_path, fn):
@@ -123,6 +134,35 @@ def test_batch_records_one_aggregate_per_point(tmp_path):
     assert min(pooled) > 0
 
 
+def test_multijob_batch_records_each_span(tmp_path):
+    reps, spans = profiled(tmp_path, lambda: run_multijob_batch(
+        MJ_GRID, 12, engine="ctmc", base_seed=4))
+    assert counted(spans) == {tracing.STUDY: 1, tracing.PREPARE: 1,
+                              tracing.WAIT: 1, tracing.TRANSFER: 1,
+                              tracing.AGGREGATE: 2}
+    assert_nested(spans)
+    args = {n: a for n, _, _, a in spans}
+    assert args[tracing.STUDY]["points"] == 2
+    # bucketing pads 12 replicas a point to 16 rows
+    assert args[tracing.PREPARE]["rows"] == 32
+    assert args[tracing.PREPARE]["real_rows"] == 24
+    assert args[tracing.TRANSFER]["real_rows"] == 24
+    assert [n for n, _, _, _ in spans][:4] == list(HOST_SPANS[:4])
+    assert [r.engine for r in reps] == ["ctmc", "ctmc"]
+
+
+def test_multijob_batch_adds_its_histogram_once_per_chunk(tmp_path):
+    _, spans = profiled(tmp_path, lambda: run_multijob_batch(
+        MJ_GRID, 12, engine="ctmc", base_seed=5))
+    [tr] = [a for n, _, _, a in spans if n == tracing.TRANSFER]
+    assert tr["chunk"] == vz.DEFAULT_CHUNK_STEPS
+    assert tr["hist_flushes"] > 0
+    assert tr["steps_run"] / tr["hist_flushes"] == 64
+    # a row is active while any of its jobs runs; never a padding row
+    assert 0 < tr["active_row_chunks"] <= tr["chunks_run"] * 24
+    assert tr["domain_shocks_total"] == tr["shock_killed_total"] == 0
+
+
 def test_each_study_gets_its_own_number(tmp_path):
     def two():
         run_replications(BASE, 8, engine="ctmc", base_seed=1)
@@ -152,11 +192,8 @@ def _counters(max_steps, early_exit, R=16, p=BASE):
 def _loop_args(max_steps, early_exit, R, p):
     chunk = min(vz.DEFAULT_CHUNK_STEPS, max_steps)
     return (vz._params_vector(p), jax.random.PRNGKey(5), 1, R, chunk,
-            np.int32(max_steps // chunk), max_steps % chunk, None,
-            early_exit, vz._struct_key(p), hazards.hazard_kind(p),
-            hazards.repair_kind(p), vz._hist_channels([p]),
-            faultdomains.scenario_key(p), vz._initial_state(p, R, None),
-            0, 0)
+            np.int32(max_steps // chunk), max_steps % chunk, early_exit,
+            vz._single_job_step(p), vz._initial_state(p, R, None))
 
 
 @pytest.mark.parametrize("max_steps", [150, 128, 40])
@@ -251,6 +288,20 @@ def test_every_scope_reaches_the_compiled_program():
     [(_, _, run, args, kw)] = vz.sweep_programs(grid, 8, seed=0)
     text = run.lower(*args, **kw).as_text(debug_info=True)
     for scope in SCOPES:
+        assert scope in text, scope
+    assert tracing.SHOCK not in text
+
+
+def test_multijob_scopes_reach_the_compiled_program():
+    step = vmj._MultiJobStep(2, None, vz._hist_channels([MJ_CLUSTER]))
+    [(_, _, run, args, kw)] = vz._build_programs(
+        [([0, 1], step, MJ_GRID)], 8, 0, None, None, True, True, 0,
+        lambda pt: vmj.default_max_steps_multijob(*pt),
+        lambda pt: vmj._mj_params_vector(*pt),
+        lambda pts, R, _: vmj._mj_initial_state_batch(pts, R, 8))
+    text = run.lower(*args, **kw).as_text(debug_info=True)
+    # no repair-slot lane and no fault domains on the multi-job engine
+    for scope in set(SCOPES) - {tracing.REPAIR_LANE}:
         assert scope in text, scope
     assert tracing.SHOCK not in text
 
